@@ -1,10 +1,13 @@
 """ctypes binding to the native analysis library (native/analysis.cpp).
 
 The build environment has no pybind11; the C ABI + ctypes keeps the
-Python↔C++ boundary dependency-free. The library is compiled on first use
-via the Makefile (g++); any failure — no compiler, build error, load error
-— degrades silently to the pure-Python tokenizer, so the native path is a
-strict accelerator, never a requirement.
+Python↔C++ boundary dependency-free. `make -C native` runs at first use —
+a no-op when the library is newer than analysis.cpp, a rebuild when it is
+not — so a checkout that never built it gets it and a stale binary is never
+trusted. With no compiler (or a failed build or load) the pure-Python
+tokenizer serves: the native path is a strict accelerator, never a
+requirement, and `native_available()` (on `_nodes/stats` as
+`analysis.native_tokenizer`) says which one is live.
 
 ASCII-only fast path: the C++ tokenizer matches the Python regex exactly
 for ASCII text; any input with a byte >= 0x80 routes to Python so behavior
@@ -29,12 +32,11 @@ _load_attempted = False
 
 
 def _build_and_load() -> Optional[ctypes.CDLL]:
-    if not os.path.exists(_LIB_PATH):
-        try:
-            subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
-                           capture_output=True, timeout=120)
-        except (subprocess.SubprocessError, FileNotFoundError, OSError):
-            return None
+    try:
+        subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
+                       capture_output=True, timeout=120)
+    except (subprocess.SubprocessError, OSError):
+        return None
     try:
         lib = ctypes.CDLL(_LIB_PATH)
     except OSError:

@@ -6,9 +6,10 @@ NoShardAvailableActionException et al.); here the analogous transient
 surface is device dispatch, request-cache IO and warmup replay. Policy:
 
   - retry ONLY `TransientFault` (the designated retryable class in
-    common/errors.py) plus the JAX runtime-error allowlist — transient
-    gRPC/XLA statuses a tunneled device emits under load. Typed client
-    errors (400s), cancellations and arbitrary exceptions never retry.
+    common/errors.py). Typed client errors (400s), cancellations and
+    arbitrary exceptions never retry — and neither do JAX runtime
+    errors: on an attached chip RESOURCE_EXHAUSTED is an HBM allocation
+    that fails the same way every time, not a blip.
   - bounded (default 2 retries = 3 attempts total) with exponential
     backoff and full jitter so concurrent retriers don't re-stampede
     the device in lockstep.
@@ -32,23 +33,10 @@ DEFAULT_RETRIES = 2
 BASE_DELAY_MS = 2.0
 MAX_DELAY_MS = 50.0
 
-# transient-status markers in JAX/XLA runtime errors (gRPC status names
-# a tunneled backend surfaces for recoverable conditions). INTERNAL and
-# INVALID_ARGUMENT are deliberately absent: those are bugs, not blips.
-_JAX_ERROR_TYPES = ("XlaRuntimeError", "JaxRuntimeError")
-_JAX_TRANSIENT_MARKERS = ("UNAVAILABLE", "RESOURCE_EXHAUSTED", "ABORTED",
-                          "DEADLINE_EXCEEDED", "CANCELLED")
-
 
 def is_transient(exc: BaseException) -> bool:
-    """True only for the designated retryable class + the JAX runtime
-    allowlist."""
-    if isinstance(exc, TransientFault):
-        return True
-    if type(exc).__name__ in _JAX_ERROR_TYPES:
-        msg = str(exc)
-        return any(m in msg for m in _JAX_TRANSIENT_MARKERS)
-    return False
+    """True only for the designated retryable class."""
+    return isinstance(exc, TransientFault)
 
 
 def call_with_retry(fn: Callable[[], Any], label: str = "",

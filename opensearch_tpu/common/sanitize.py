@@ -8,7 +8,7 @@ where present) is wrapped so that any call made from inside the
 the calling thread raises `UnattributedSyncError` instead of silently
 moving bytes. "Attributed region" is the transfer ledger's thread-local
 marker (`TransferLedger.attributed` / `ambient` / `tagged` — see
-telemetry/ledger.py): exactly the regions whose transfers the PROFILE.md
+telemetry/ledger.py): exactly the regions whose transfers a profile's
 decomposition can explain. Calls from tests, tools and bench probes are
 exempt — the contract binds the serving code, not its harnesses.
 

@@ -190,8 +190,8 @@ def _eval_filter_mask(plan, arrays) -> np.ndarray:
     host. Jitted per plan signature, like the executor's query runners.
     The mask pull is a real query-path transfer (a cache fill riding the
     triggering request), so it is ledger-attributed on its own channel —
-    before this it was an invisible sync the PROFILE.md decomposition
-    could not explain."""
+    before this it was an invisible sync no profile's decomposition
+    could explain."""
     import time
 
     import jax

@@ -89,11 +89,17 @@ def bootstrap_checks(settings: Dict) -> list:
     except (ImportError, ValueError):
         pass
 
+    # the device the node will serve from, said once and loudly: a node
+    # that came up on CPU reads `platform=cpu` in its start-up log
     try:
-        import jax  # noqa: F401
-        checks.append(("jax importable", True, jax.__version__))
-    except Exception as e:  # pragma: no cover - env dependent
-        checks.append(("jax importable", False, str(e)))
+        import jax
+        dev = jax.devices()[0]
+        checks.append(("jax device present", True,
+                       f"jax {jax.__version__} platform={dev.platform} "
+                       f"device_kind={dev.device_kind} "
+                       f"devices={jax.device_count()}"))
+    except (ImportError, RuntimeError) as e:
+        checks.append(("jax device present", False, str(e)))
     return checks
 
 

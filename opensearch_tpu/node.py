@@ -140,13 +140,16 @@ class Node:
                 self.apply_admission_settings()
             if loaded and loaded.get("search_pipelines"):
                 self.search_pipelines.load(loaded["search_pipelines"])
-        # executable warmup (search/warmup.py): load the persisted
-        # (plan-struct, shape-bucket) registry from the data dir, point
-        # jax's persistent compilation cache under it, and AOT-compile the
-        # registered executables for any gateway-restored indices BEFORE
-        # the first query can hit the cold-compile cliff
+        # executable warmup (search/warmup.py): every node keeps XLA's
+        # persistent compilation cache at one fixed place; a node with a
+        # data dir also loads its persisted (plan-struct, shape-bucket)
+        # registry and AOT-compiles the registered executables for any
+        # gateway-restored indices BEFORE the first query can hit the
+        # cold-compile cliff
+        from opensearch_tpu.search.warmup import (WARMUP,
+                                                  configure_compile_cache)
+        configure_compile_cache()
         if data_path is not None:
-            from opensearch_tpu.search.warmup import WARMUP
             WARMUP.configure(data_path)
             WARMUP.default_budget_s = float(self.settings.get(
                 "search.warmup.budget_ms", 10000)) / 1000.0

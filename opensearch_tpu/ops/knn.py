@@ -27,6 +27,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from opensearch_tpu.ops import F32_MATMUL
+
 SPACES = ("l2", "cosinesimil", "innerproduct")
 
 
@@ -38,7 +40,7 @@ def _check_space(space: str):
 def raw_similarity(vectors: jnp.ndarray, query: jnp.ndarray,
                    space: str) -> jnp.ndarray:
     """Higher-is-closer raw similarity per doc ([D, dims] × [dims] → [D])."""
-    dots = vectors @ query                       # MXU matvec
+    dots = jnp.matmul(vectors, query, precision=F32_MATMUL)  # MXU matvec
     if space == "l2":
         dn = jnp.sum(vectors * vectors, axis=1)
         qn = jnp.sum(query * query)
@@ -119,13 +121,13 @@ def _kmeans(vectors: np.ndarray, nlist: int, iters: int = 10,
     @jax.jit
     def step(data, centroids):
         # assign: [n, nlist] distances via the same matmul expansion
-        dots = data @ centroids.T
+        dots = jnp.matmul(data, centroids.T, precision=F32_MATMUL)
         dn = jnp.sum(data * data, axis=1, keepdims=True)
         cn = jnp.sum(centroids * centroids, axis=1)
         assign = jnp.argmin(dn - 2 * dots + cn, axis=1)
         # update: segment mean
         one_hot = jax.nn.one_hot(assign, nlist, dtype=jnp.float32)
-        sums = one_hot.T @ data
+        sums = jnp.matmul(one_hot.T, data, precision=F32_MATMUL)
         counts = one_hot.sum(axis=0)[:, None]
         return jnp.where(counts > 0, sums / jnp.maximum(counts, 1), centroids)
 
@@ -214,7 +216,8 @@ def ivf_knn_scores(packed_vecs: jnp.ndarray, packed_ids: jnp.ndarray,
     # centroid ranking always by L2 (clusters were built in L2 space); for
     # innerproduct/cosine the probe order still correlates (faiss does the
     # same for IVF+IP via L2-clustered coarse quantizers)
-    cd = jnp.sum(centroids * centroids, axis=1) - 2.0 * (centroids @ query)
+    cd = jnp.sum(centroids * centroids, axis=1) \
+        - 2.0 * jnp.matmul(centroids, query, precision=F32_MATMUL)
     nlist = int(centroids.shape[0])
     n_blocks = int(block_centroid.shape[0])
     nprobe_eff = min(int(nprobe), nlist)

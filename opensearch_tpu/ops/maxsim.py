@@ -44,6 +44,8 @@ from typing import Tuple
 import numpy as np
 import jax.numpy as jnp
 
+from opensearch_tpu.ops import F32_MATMUL
+
 # dims-axis tile width for the exact kernel: one VPU/MXU lane group
 # (the last-axis native lane width); dims smaller than a tile take one
 # partial step
@@ -68,7 +70,8 @@ def _tiled_token_dots(tokens2d: jnp.ndarray, query: jnp.ndarray) -> jnp.ndarray:
     acc = None
     for lo in range(0, dims, DIM_TILE):
         hi = min(lo + DIM_TILE, dims)
-        part = tokens2d[:, lo:hi] @ query[:, lo:hi].T
+        part = jnp.matmul(tokens2d[:, lo:hi], query[:, lo:hi].T,
+                          precision=F32_MATMUL)
         acc = part if acc is None else acc + part
     return acc
 
@@ -104,7 +107,8 @@ def pq_lut(codebook: jnp.ndarray, query: jnp.ndarray) -> jnp.ndarray:
     m, codes, dsub = codebook.shape
     tq = query.shape[0]
     qsub = query.reshape(tq, m, dsub)
-    return jnp.einsum("mcd,tmd->tmc", codebook, qsub)
+    return jnp.einsum("mcd,tmd->tmc", codebook, qsub,
+                      precision=F32_MATMUL)
 
 
 def pq_maxsim_scores(codes: jnp.ndarray, codebook: jnp.ndarray,

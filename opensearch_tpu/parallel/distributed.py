@@ -27,24 +27,18 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map as _shard_map_impl
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=False)
-except ImportError:  # older jax: jax.experimental + check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
+from jax import shard_map as _shard_map_impl
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import dataclasses
 
+
+def _shard_map(f, mesh, in_specs, out_specs):
+    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
+
 # host→device transfer accounting (bytes), for tests/benchmarks asserting
-# that segments are NOT re-uploaded per query (VERDICT round-1 weak #4):
+# that segments are NOT re-uploaded per query:
 # every explicit upload in this module increments it
 TRANSFER_BYTES = [0]    # shared-state-ok: test-only accounting slot; the int write is GIL-atomic and tests serialize
 

@@ -1622,12 +1622,19 @@ def register_cluster_actions(node, c):
                 "persistent": node.cluster_settings["persistent"],
                 "transient": node.cluster_settings["transient"]}
 
+    def _device_info() -> dict:
+        """The accelerator as jax reports it — the chip smoke and the
+        start-up log read the same three facts."""
+        import jax
+        dev = jax.devices()[0]
+        return {"platform": dev.platform, "device_kind": dev.device_kind,
+                "count": jax.device_count()}
+
     def do_cluster_stats(req):
         total_docs = sum(svc.stats()["docs"]["count"]
                          for svc in node.indices.indices.values())
         total_shards = sum(svc.num_shards
                            for svc in node.indices.indices.values())
-        import jax
         return {
             "cluster_name": node.cluster_name,
             "status": "green",
@@ -1639,8 +1646,7 @@ def register_cluster_actions(node, c):
             "nodes": {
                 "count": {"total": 1, "data": 1, "cluster_manager": 1},
                 "versions": [node.root_info()["version"]["number"]],
-                "devices": {"count": jax.device_count(),
-                            "platform": jax.devices()[0].platform},
+                "devices": _device_info(),
             },
         }
 
@@ -1663,7 +1669,6 @@ def register_cluster_actions(node, c):
         }
 
     def do_nodes_info(req):
-        import jax
         return {
             "_nodes": {"total": 1, "successful": 1, "failed": 0},
             "cluster_name": node.cluster_name,
@@ -1671,8 +1676,7 @@ def register_cluster_actions(node, c):
                 "name": node.node_name,
                 "version": node.root_info()["version"]["number"],
                 "roles": ["cluster_manager", "data", "ingest"],
-                "tpu": {"devices": jax.device_count(),
-                        "platform": jax.devices()[0].platform},
+                "tpu": _device_info(),
             }},
         }
 
@@ -1681,6 +1685,7 @@ def register_cluster_actions(node, c):
         from opensearch_tpu.indices.request_cache import REQUEST_CACHE
         from opensearch_tpu.monitor import (os_probe as _os_probe,
                                             process_probe as _process_probe)
+        from opensearch_tpu.analysis.native import native_available
         from opensearch_tpu.search.warmup import WARMUP
         idx_stats = {n: svc.stats()
                      for n, svc in node.indices.indices.items()}
@@ -1702,6 +1707,7 @@ def register_cluster_actions(node, c):
                     "query_cache": QUERY_CACHE.stats(),
                 },
                 "search_warmup": WARMUP.stats(),
+                "analysis": {"native_tokenizer": native_available()},
                 "telemetry": TELEMETRY.stats(),
                 "breakers": node.breaker_service.stats(),
                 "indexing_pressure": node.indexing_pressure.stats(),

@@ -45,6 +45,7 @@ from opensearch_tpu.common.errors import (
     IllegalArgumentError, ParsingError, QueryShardError)
 from opensearch_tpu.index.mapper import MapperService, format_date_millis, parse_date_millis
 from opensearch_tpu.index.segment import Segment, ident_pairs, pad_bucket
+from opensearch_tpu.ops import F32_MATMUL
 from opensearch_tpu.search import dsl
 from opensearch_tpu.search.aggs.parse import AggNode
 from opensearch_tpu.search.compile import Compiler, Plan, _resolve_date_math
@@ -1124,7 +1125,8 @@ def _binned_sums(bin_lanes, total: int, contribs, static_bins: bool):
       ~1/20th the ops of the one-hot matmul; pure VPU/AVX work.
     - float contribs with static bins: ONE [n, total] one-hot serves
       every query of a vmapped batch, reduced as a [B, n] × [n, total]
-      matmul (the MXU path). f32 accumulation exact below 2^24.
+      matmul (the MXU path) at full f32 operand precision
+      (ops.F32_MATMUL). f32 accumulation exact below 2^24.
     - dynamic bins or many bins: scatter-add.
     """
     n = bin_lanes.shape[0]
@@ -1149,7 +1151,8 @@ def _binned_sums(bin_lanes, total: int, contribs, static_bins: bool):
                 jnp.float32)
             for i in rest:
                 v, dt = contribs[i]
-                s = v.astype(jnp.float32) @ onehot
+                s = jnp.matmul(v.astype(jnp.float32), onehot,
+                               precision=F32_MATMUL)
                 out[i] = s.astype(dt)
             return out
         if not rest:

@@ -1,7 +1,7 @@
 """Executable warmup: ahead-of-time compile registered query shapes.
 
-The agg-config p99 cliff (VERDICT round 5: 557.9 / 384.2 ms p99 against
-~2.5 ms p50) is the first-(plan-struct, shape-bucket) XLA compile landing
+The agg-config p99 cliff (hundreds of ms of p99 against a p50 of a few)
+is the first-(plan-struct, shape-bucket) XLA compile landing
 inside the serving path — the msearch envelope caches executables per
 (plan structure, input shapes, batch bucket), so every NEW combination
 pays a full compile on the query that first exhibits it.  The reference
@@ -18,7 +18,8 @@ executable-level:
   batch bucket — through the normal msearch path with the request cache
   bypassed, compiling exactly the executables production traffic will hit;
 - the XLA compiles themselves go through jax's persistent compilation
-  cache (configure() points it under the data dir), so a replayed compile
+  cache (configure_compile_cache(): wherever JAX_COMPILATION_CACHE_DIR
+  says, else a fixed directory in the checkout), so a replayed compile
   after restart is a disk hit, not a fresh HLO build.
 
 Warmup stats surface on _nodes/stats (rest/actions.py) and bench.py
@@ -50,6 +51,32 @@ MAX_ENTRIES = 256
 _PERSIST_INTERVAL_S = 5.0
 
 
+# the XLA cache's default home: a FIXED path in the checkout. The path is
+# part of what makes a later process find the entries again, so it never
+# sits under path.data, a temporary directory, a pid or a time.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def configure_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache for this process and
+    return the directory in force. Where JAX_COMPILATION_CACHE_DIR is set
+    the caller placed the cache and jax reads the variable itself — no
+    directory is set in code; otherwise the cache lives at
+    DEFAULT_COMPILE_CACHE_DIR. Every executable is kept (no compile-time
+    or size floor): the serving path's programs are many and small."""
+    import jax
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = DEFAULT_COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    WARMUP.stats_["compile_cache_dir"] = cache_dir
+    return cache_dir
+
+
 class WarmupRegistry:
     """Node-wide registry of compiled-executable signatures + replay."""
 
@@ -74,15 +101,12 @@ class WarmupRegistry:
 
     # ------------------------------------------------------------ configure
 
-    def configure(self, data_path: Optional[str],
-                  compile_cache: bool = True,
-                  min_compile_secs: float = 0.0) -> None:
-        """Bind the registry to a node data dir: load persisted entries and
-        point jax's persistent compilation cache under it, so executables
-        survive process restarts (first compile after restart = disk read).
-        Both artifacts live under the gateway's _state dir — top-level
+    def configure(self, data_path: Optional[str]) -> None:
+        """Bind the registry to a node data dir and load persisted entries.
+        The registry lives under the gateway's _state dir — top-level
         directories in the data path are index data and would be reported
-        as dangling indices."""
+        as dangling indices. (The XLA compile cache is NOT placed here:
+        see configure_compile_cache.)"""
         if data_path is None:
             return
         state_dir = os.path.join(data_path, "_state")
@@ -100,29 +124,6 @@ class WarmupRegistry:
             import atexit
             atexit.register(self.flush)
             self._atexit_registered = True
-        if compile_cache:
-            self.enable_compile_cache(os.path.join(state_dir, "xla_cache"),
-                                      min_compile_secs)
-
-    def enable_compile_cache(self, cache_dir: str,
-                             min_compile_secs: float = 0.0) -> None:
-        """jax persistent compilation cache (works on the CPU backend too).
-        Guarded per-flag: absent config names on older jax are skipped."""
-        import jax
-        try:
-            os.makedirs(cache_dir, exist_ok=True)
-        except OSError:
-            return
-        for name, value in (
-                ("jax_compilation_cache_dir", cache_dir),
-                ("jax_persistent_cache_min_compile_time_secs",
-                 min_compile_secs),
-                ("jax_persistent_cache_min_entry_size_bytes", -1)):
-            try:
-                jax.config.update(name, value)
-            except Exception:   # except-ok: jax-version compatibility -- absent config names on older jax are skipped
-                pass
-        self.stats_["compile_cache_dir"] = cache_dir
 
     # -------------------------------------------------------------- record
 

@@ -37,10 +37,10 @@ layer, in three parts:
    compute, the collect only the part no host work hid.
 
 3. **Roofline classification.** Arithmetic intensity flops/bytes vs the
-   configurable `telemetry.kernels.peak_flops` / `peak_bw` ridge marks
-   each family compute- vs memory-bound — the first table a TPU tuning
-   session reads (ROADMAP item 1) and the device-ms price list the
-   insight-driven adaptive loop (item 5) needs per executable.
+   ridge of the attached device's published peaks (`DEVICE_PEAKS`, keyed
+   by `device_kind`; `telemetry.kernels.peak_flops` / `peak_bw` override)
+   marks each family compute- vs memory-bound. A device that is not in
+   the table is not classified.
 
 Kernel-family vocabulary (the label every census/timing row carries):
 ``bm25_candidate`` / ``bm25_dense`` (the two envelope kernels),
@@ -73,11 +73,14 @@ KERNEL_FAMILIES = ("bm25_candidate", "bm25_dense", "agg_env",
 # hundreds of executables, not thousands; overflow counts, not crashes
 MAX_CENSUS_ENTRIES = 2048
 
-# default roofline peaks (overridable via telemetry.kernels.peak_flops /
-# telemetry.kernels.peak_bw node settings): deliberately round numbers a
-# CPU-backend dev box roughly matches — the TPU session sets real ones
-DEFAULT_PEAK_FLOPS = 1.0e12     # 1 TFLOP/s
-DEFAULT_PEAK_BW = 1.0e11        # 100 GB/s
+# published per-chip roofline peaks (flop/s, bytes/s) keyed by the
+# `device_kind` jax reports. Source: Google Cloud documentation, "TPU
+# v5e" — 197 TFLOP/s bf16, 819 GB/s HBM. A device that is not listed
+# gets NO classification (`bound: null`), never a guess; the
+# telemetry.kernels.peak_flops / .peak_bw node settings override.
+DEVICE_PEAKS: Dict[str, Tuple[float, float]] = {
+    "TPU v5 lite": (197.0e12, 819.0e9),
+}
 DEFAULT_SAMPLE_EVERY = 16
 
 
@@ -204,8 +207,10 @@ class KernelProfiler:
     def __init__(self):
         self.enabled = False
         self.sample_every = DEFAULT_SAMPLE_EVERY
-        self.peak_flops = DEFAULT_PEAK_FLOPS
-        self.peak_bw = DEFAULT_PEAK_BW
+        # None = take the device's row of DEVICE_PEAKS; a float is the
+        # operator's override (telemetry.kernels.peak_* settings)
+        self.peak_flops: Optional[float] = None
+        self.peak_bw: Optional[float] = None
         self._census_lock = threading.Lock()
         self._census: List[dict] = []
         self._census_dropped = 0
@@ -317,14 +322,29 @@ class KernelProfiler:
                 agg["cost_known"] += 1
         return out
 
-    def _roofline(self, flops: Optional[float],
-                  nbytes: Optional[float]) -> Tuple[Optional[float], str]:
-        """(arithmetic intensity, bound class) against the configured
-        ridge point peak_flops/peak_bw."""
+    def peaks(self) -> Tuple[Optional[float], Optional[float]]:
+        """(peak flop/s, peak bytes/s) in force: the override where one
+        is set, else the attached device's DEVICE_PEAKS row, else None."""
+        flops, bw = self.peak_flops, self.peak_bw
+        if flops is None or bw is None:
+            import jax
+            row = DEVICE_PEAKS.get(jax.devices()[0].device_kind,
+                                   (None, None))
+            flops = row[0] if flops is None else flops
+            bw = row[1] if bw is None else bw
+        return flops, bw
+
+    @staticmethod
+    def _roofline(flops: Optional[float], nbytes: Optional[float],
+                  ridge: Optional[float]
+                  ) -> Tuple[Optional[float], Optional[str]]:
+        """(arithmetic intensity, bound class) against the ridge point
+        peak_flops/peak_bw; no ridge (unlisted device) = no class."""
         if not flops or not nbytes:
             return None, "unknown"
         ai = flops / nbytes
-        ridge = self.peak_flops / max(self.peak_bw, 1.0)
+        if ridge is None:
+            return ai, None
         return ai, ("compute" if ai >= ridge else "memory")
 
     def snapshot(self, census: bool = True) -> dict:
@@ -333,6 +353,9 @@ class KernelProfiler:
         + roofline verdicts + (when timing ran) sampled device walls
         with the scaled total estimate."""
         by_fam = self._census_by_family()
+        peak_flops, peak_bw = self.peaks()
+        ridge = peak_flops / max(peak_bw, 1.0) \
+            if peak_flops is not None and peak_bw is not None else None
         with self._exec_lock:
             fams = {f: {"calls": r["calls"], "sampled": r["sampled"],
                         "sampled_ms": r["sampled_ms"],
@@ -346,7 +369,7 @@ class KernelProfiler:
             run = fams.get(fam)
             flops = agg["flops"] if agg else None
             nbytes = agg["bytes"] if agg else None
-            ai, bound = self._roofline(flops, nbytes)
+            ai, bound = self._roofline(flops, nbytes, ridge)
             row = {"compiles": agg["compiles"] if agg else 0,
                    "compile_ms": round(agg["compile_ms"], 3)
                    if agg else 0.0,
@@ -381,9 +404,8 @@ class KernelProfiler:
             dump = list(self._census) if census else None
         out = {"enabled": self.enabled,
                "sample_every": self.sample_every,
-               "peak_flops": self.peak_flops, "peak_bw": self.peak_bw,
-               "ridge_intensity": round(
-                   self.peak_flops / max(self.peak_bw, 1.0), 4),
+               "peak_flops": peak_flops, "peak_bw": peak_bw,
+               "ridge_intensity": _round(ridge),
                "census": {"entries": n_census, "dropped": dropped,
                           "compile_ms_total": round(compile_total, 3)},
                "families": families}

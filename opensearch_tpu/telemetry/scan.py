@@ -2,14 +2,15 @@
 
 ROADMAP item 4 defers block-max (WAND) pruning behind a measured
 trigger: "add on-device block-max skipping once scanned-bytes/query
-starts dominating" (BM25S, arxiv 2407.03618). SCALING.md computed that
-number OFFLINE, once, at three corpus sizes — this module is the LIVE
+starts dominating" (BM25S, arxiv 2407.03618). tools/scaling_bench.py
+computed that number OFFLINE, once, at three corpus sizes
+(SCALING_raw.json) — this module is the LIVE
 version: per-query counters for the bytes each kernel class touches,
 aggregated into a per-shard/per-segment heat map on `_nodes/stats`
 (`telemetry.scan`), so the go/no-go trigger is a standing dashboard
 number instead of an archaeology exercise.
 
-Two byte classes, matching SCALING.md's columns exactly (the committed
+Two byte classes, matching the offline columns exactly (the committed
 acceptance: the live p50 at 100K docs must agree with the offline
 3.1 KB within 10%):
 
@@ -21,8 +22,7 @@ acceptance: the live p50 at 100K docs must agree with the offline
   no per-lane work, no device sync.
 - **dense-lane bytes** (dense kernel): `d_pad × 9 B` per clause
   evaluation — score f32 + hit i32 + live bool per doc lane, the
-  "~9 bytes/doc-lane" O(d_pad) HBM traffic SCALING.md's dense-kernel
-  refutation priced.
+  "~9 bytes/doc-lane" O(d_pad) HBM traffic of a dense scan.
 
 Always-on discipline: this is NOT a gated subsystem — the counters are
 the trigger metric for a capacity decision, so they must be live on
@@ -101,7 +101,7 @@ class ScanAccounting:
         self.pruned_bytes_total = 0
         self.pruned_queries = 0
         # per-query posting-bytes distribution — THE trigger metric
-        # (SCALING.md's scanned-bytes/query column, live)
+        # (the offline scanned-bytes/query column, live)
         self.per_query_posting = RollingEstimator()
         self.per_query_dense = RollingEstimator()
         # per-query EFFECTIVE posting bytes (static - pruned), fed only

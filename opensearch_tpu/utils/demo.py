@@ -104,6 +104,7 @@ def build_shards_fast(n_docs: int, n_shards: int = 1,
                       burst_window: int = 0,
                       burst_regions: int = 1,
                       doc_len_cv: float = 0.0,
+                      columns: bool = False,
                       mapper: Optional[MapperService] = None,
                       ) -> Tuple[MapperService, List["Segment"], List[str]]:
     """Sealed segments at 10M-doc scale without the per-doc parse loop.
@@ -134,11 +135,18 @@ def build_shards_fast(n_docs: int, n_shards: int = 1,
     corpora cluster by crawl/time locality) produce. `doc_len_cv` adds
     lognormal doc-length variance on top of the Poisson baseline.
 
+    `columns=True` also gives every doc `synth_docs`' structured fields —
+    `tag` (keyword: postings + ordinals), `views` and `ts` (numeric doc
+    values) — drawn after the postings, so the text side of a corpus is
+    the same with and without them. Without it the corpus has no
+    doc-values columns at all.
+
     Returns (mapper, segments, terms) with docs round-robined over shards
     (global _id "d{ord}" matches build_shards' layout).
     """
-    from opensearch_tpu.index.segment import (FieldStats, Segment,
-                                              TermMeta, _pad_to)
+    from opensearch_tpu.index.segment import (DocValuesColumn, FieldStats,
+                                              OrdinalsColumn, Segment,
+                                              TermMeta, _hash64, _pad_to)
     mapper = mapper or MapperService(DEMO_MAPPING)
     ranks_all = np.arange(1, vocab_size + 1, dtype=np.float64)
     h_v = float(np.sum(1.0 / ranks_all))
@@ -164,6 +172,24 @@ def build_shards_fast(n_docs: int, n_shards: int = 1,
         rows_tf: List[np.ndarray] = []
         next_block = 0
         sum_df = 0
+
+        def emit(field, term, ords, tf):
+            """Append one term's postings as 128-lane blocks (-1/0
+            padded), in seal()'s layout."""
+            nonlocal next_block
+            padded = _pad_to(ords.size, 128)
+            docs_p = np.full(padded, -1, dtype=np.int32)
+            tfs_p = np.zeros(padded, dtype=np.float32)
+            docs_p[:ords.size] = ords
+            tfs_p[:ords.size] = tf
+            nb = padded // 128
+            rows_docs.append(docs_p.reshape(nb, 128))
+            rows_tf.append(tfs_p.reshape(nb, 128))
+            term_dict[(field, term)] = TermMeta(
+                doc_freq=int(ords.size), total_term_freq=int(tf.sum()),
+                start_block=next_block, num_blocks=nb)
+            next_block += nb
+
         # seal() sorts (field, term); zero-padded w-terms sort by rank
         for rank, term in zip(term_ranks, terms):
             p = (1.0 / float(rank)) / h_v
@@ -185,36 +211,48 @@ def build_shards_fast(n_docs: int, n_shards: int = 1,
                 # flat — no impact skew, nothing for phase A to separate
                 tf = np.where(
                     in_w, tf + rng.poisson(burst_tf, ords.size), tf)
-            df = int(ords.size)
-            if df == 0:
+            if ords.size == 0:
                 continue
-            padded = _pad_to(df, 128)
-            docs_p = np.full(padded, -1, dtype=np.int32)
-            tfs_p = np.zeros(padded, dtype=np.float32)
-            docs_p[:df] = ords
-            tfs_p[:df] = tf
-            nb = padded // 128
-            rows_docs.append(docs_p.reshape(nb, 128))
-            rows_tf.append(tfs_p.reshape(nb, 128))
-            term_dict[("body", term)] = TermMeta(
-                doc_freq=df, total_term_freq=int(tf.sum()),
-                start_block=next_block, num_blocks=nb)
-            next_block += nb
-            sum_df += df
-        post_docs = np.concatenate(rows_docs, axis=0) if rows_docs \
-            else np.full((1, 128), -1, dtype=np.int32)
-        post_tf = np.concatenate(rows_tf, axis=0) if rows_tf \
-            else np.zeros((1, 128), dtype=np.float32)
+            emit("body", term, ords, tf)
+            sum_df += int(ords.size)
 
         lengths = np.minimum(lengths, _SF_MAX_LEN - 1)
         norms = {"body": sf[lengths]}
         stats = {"body": FieldStats(
             doc_count=n, sum_total_term_freq=int(lengths.sum()),
             sum_doc_freq=sum_df)}
+        numeric_dv, ordinal_dv = {}, {}
+        if columns:
+            tags = sorted(f"cat{i}" for i in range(16))
+            tag_ord = rng.integers(0, len(tags), n).astype(np.int32)
+            every = np.arange(n, dtype=np.int32)
+            for t_i, tag in enumerate(tags):    # sorted: ("tag", t) keys
+                ords = np.nonzero(tag_ord == t_i)[0].astype(np.int32)
+                if ords.size:
+                    emit("tag", tag, ords, np.ones(ords.size, np.float32))
+            stats["tag"] = FieldStats(doc_count=n, sum_total_term_freq=n,
+                                      sum_doc_freq=n)
+            ordinal_dv["tag"] = OrdinalsColumn(
+                every, tag_ord, np.ones(n, dtype=bool), tags,
+                np.array([_hash64(t) for t in tags], dtype=np.uint64))
+            for field, values in (
+                    ("views", rng.integers(0, 10000, n)),
+                    ("ts", 1700000000000
+                     + rng.integers(0, 90 * 86400_000, n))):
+                unique, value_ords = np.unique(
+                    values.astype(np.float64), return_inverse=True)
+                numeric_dv[field] = DocValuesColumn(
+                    every, values.astype(np.float64),
+                    np.ones(n, dtype=bool), np.ones(n, dtype=np.int32),
+                    value_ords.astype(np.int32), unique)
+        post_docs = np.concatenate(rows_docs, axis=0) if rows_docs \
+            else np.full((1, 128), -1, dtype=np.int32)
+        post_tf = np.concatenate(rows_tf, axis=0) if rows_tf \
+            else np.zeros((1, 128), dtype=np.float32)
         doc_ids = [f"d{s + i * n_shards}" for i in range(n)]
         segments.append(Segment(
             f"s{s}", n, doc_ids, [None] * n, term_dict,
-            post_docs, post_tf, norms, stats, {}, {}, {}))
+            post_docs, post_tf, norms, stats, numeric_dv, ordinal_dv, {}))
     return mapper, segments, terms
 
 

@@ -1,6 +1,6 @@
 """Multi-node cluster integration: the round-2 "assemble the islands" test.
 
-The VERDICT round-1 acceptance scenario (modeled on the reference's
+The round-1 acceptance scenario (modeled on the reference's
 InternalTestCluster suites — test/framework/.../test/InternalTestCluster
 .java:195 — which boot real Nodes with real loopback transports in one
 process): boot 3 ClusterNodes on loopback, create an index (2 shards,
@@ -156,7 +156,7 @@ class TestClusterDataPath:
 
 class TestClusterFailover:
     def test_kill_primary_node_promote_and_search(self):
-        """The VERDICT acceptance test: 3 nodes, 2 shards, 1 replica;
+        """The acceptance test: 3 nodes, 2 shards, 1 replica;
         bulk over real HTTP; kill the node holding a primary; verify
         re-election (if leader died), promotion, and correct results."""
         from opensearch_tpu.rest.http import HttpServer

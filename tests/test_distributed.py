@@ -101,7 +101,7 @@ def test_spmd_matches_host_merge(corpus, mesh, query):
 
 
 def test_hbm_resident_segments_not_reuploaded_per_query(corpus, mesh):
-    """Regression (round-1 VERDICT weak #4): segments upload to HBM once;
+    """Regression (round 1): segments upload to HBM once;
     subsequent queries move only flat plan inputs. Asserts via the
     module's transfer accounting that the second query's host→device
     traffic is a small fraction of the segment bytes."""
@@ -289,7 +289,7 @@ def test_graft_entry_compiles():
 
 
 class TestSpmdServingPath:
-    """VERDICT round-3 next-step 2: the SPMD program must BE the serving
+    """Round 3: the SPMD program must BE the serving
     path — a REST _search against a multi-shard index executes the
     shard_map program, with HBM residency across queries."""
 

@@ -37,9 +37,8 @@ import opensearch_tpu.telemetry.kernels as kernels_mod
 from opensearch_tpu.search.executor import SearchExecutor, ShardReader
 from opensearch_tpu.telemetry import TELEMETRY
 from opensearch_tpu.telemetry.kernels import (
-    DEFAULT_PEAK_BW, DEFAULT_PEAK_FLOPS, DEFAULT_SAMPLE_EVERY,
-    KERNEL_FAMILIES, KERNELS, KernelProfiler, fingerprint,
-    timed_first_call)
+    DEFAULT_SAMPLE_EVERY, DEVICE_PEAKS, KERNEL_FAMILIES, KERNELS,
+    KernelProfiler, fingerprint, timed_first_call)
 from opensearch_tpu.utils.demo import build_shards, query_terms
 
 
@@ -198,6 +197,23 @@ class TestCensus:
         assert fams["knn"]["bound"] == "compute"
         assert fams["expand"]["bound"] == "memory"
         assert fams["knn"]["arithmetic_intensity"] == 100.0
+
+    def test_unlisted_device_is_not_classified(self):
+        # the test backend's device_kind ("cpu") has no DEVICE_PEAKS row:
+        # no ridge, `bound: null` — never a guessed default
+        import jax
+        assert jax.devices()[0].device_kind not in DEVICE_PEAKS
+        p = KernelProfiler()
+        p.census_note(None, (), "knn", "hot", "a" * 8, 1.0,
+                      (1000.0, 10.0))
+        snap = p.snapshot()
+        assert snap["peak_flops"] is None and snap["peak_bw"] is None
+        assert snap["ridge_intensity"] is None
+        assert snap["families"]["knn"]["bound"] is None
+        assert snap["families"]["knn"]["arithmetic_intensity"] == 100.0
+
+    def test_v5e_row_is_the_published_peak(self):
+        assert DEVICE_PEAKS["TPU v5 lite"] == (197.0e12, 819.0e9)
 
 
 # ------------------------------------------------------------- timing
@@ -436,8 +452,8 @@ class TestRestFace:
         finally:
             KERNELS.enabled = False
             KERNELS.sample_every = DEFAULT_SAMPLE_EVERY
-            KERNELS.peak_flops = DEFAULT_PEAK_FLOPS
-            KERNELS.peak_bw = DEFAULT_PEAK_BW
+            KERNELS.peak_flops = None
+            KERNELS.peak_bw = None
             KERNELS.clear()
             Node()      # re-configure the singleton back to defaults
 

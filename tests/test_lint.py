@@ -358,6 +358,17 @@ def test_repo_is_lint_clean():
     assert vs == [], "\n".join(str(v) for v in vs)
 
 
+def test_entry_scripts_never_select_a_platform():
+    """bench.py and __graft_entry__.py run on the device jax gives them:
+    no `jax_platforms` write, no FORCE_CPU switch. A run that finds no
+    accelerator must fail, never quietly become a CPU number."""
+    for name in ("bench.py", "__graft_entry__.py", "chip_smoke.py"):
+        with open(os.path.join(REPO, name)) as f:
+            src = f.read()
+        for needle in ("jax_platforms", "FORCE_CPU"):
+            assert needle not in src, f"{name} mentions {needle}"
+
+
 def test_runner_cli_json_exit_zero():
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "lint.py"),

@@ -151,3 +151,57 @@ def test_parse_duration_ms_forms():
     assert _parse_duration_ms("3h") == 3 * 3600_000
     assert _parse_duration_ms("-45m") == -45 * 60_000
     assert _parse_duration_ms(250) == 250
+
+
+# ------------------------------------------------ compile-cache placement
+
+def test_compile_cache_is_fixed_in_the_checkout(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR unset the XLA cache sits at one
+    fixed place in the checkout — never under path.data — so two nodes
+    with different (temporary) data paths share it and a later process
+    finds it again."""
+    import os
+
+    import jax
+
+    from opensearch_tpu.node import Node
+    from opensearch_tpu.search.warmup import DEFAULT_COMPILE_CACHE_DIR
+    assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert DEFAULT_COMPILE_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    seen = []
+    for sub in ("a", "b", None):
+        node = Node(data_path=str(tmp_path / sub) if sub else None)
+        stats = node.request("GET", "/_nodes/stats")
+        seen.append(next(iter(stats["nodes"].values()))[
+            "search_warmup"]["compile_cache_dir"])
+        assert jax.config.jax_compilation_cache_dir == seen[-1]
+    assert seen == [DEFAULT_COMPILE_CACHE_DIR] * 3
+    assert not (tmp_path / "a" / "_state" / "xla_cache").exists()
+
+
+def test_compile_cache_env_var_is_left_to_jax(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: the caller placed the cache; the
+    program sets no directory in code and reports the caller's."""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    placed = str(tmp_path / "placed")
+    code = (
+        "import jax\n"
+        "from opensearch_tpu.node import Node\n"
+        "from opensearch_tpu.search import warmup\n"
+        "calls = []\n"
+        "orig = jax.config.update\n"
+        "jax.config.update = lambda k, v: (calls.append(k), orig(k, v))\n"
+        "node = Node()\n"
+        "assert 'jax_compilation_cache_dir' not in calls, calls\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+        "print(warmup.WARMUP.stats()['compile_cache_dir'])\n")
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=repo, capture_output=True,
+        text=True, timeout=300,
+        env={**os.environ, "JAX_COMPILATION_CACHE_DIR": placed})
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.split() == [placed, placed]

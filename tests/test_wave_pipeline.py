@@ -1,5 +1,5 @@
 """Differential parity + chaos suite for the overlapped multi-wave
-msearch pipeline (ROADMAP item 1, PROFILE.md round 10).
+msearch pipeline (ROADMAP item 1).
 
 Contract under test: splitting an envelope into W waves — wave N+1's
 host work and async dispatch overlapping wave N's device_get on the

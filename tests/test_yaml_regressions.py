@@ -1,5 +1,5 @@
 """Pinned regressions for the three 500-class crashes the round-5 YAML
-sweep surfaced (VERDICT.md §weak-4). The reference checkout isn't present
+sweep surfaced. The reference checkout isn't present
 in CI, so each failing suite's do-steps are reproduced in-process with
 the reference's expected results asserted — these must stay green even
 when /root/reference is absent (tools/sweep_delta.py re-runs the real

@@ -20,7 +20,7 @@ p99 comes from "warm_p99_ms"; open-loop records (identified by their
 Configs present in only one file are reported but never fail (bench
 sets grow PR over PR); configs without a p99 field skip the p99 gate.
 
-    python tools/bench_compare.py BENCH_r05.json BENCH_r06.json
+    python tools/bench_compare.py BENCH_old.json BENCH_new.json
     python tools/bench_compare.py --threshold 15 old.json new.json
     python tools/bench_compare.py BENCH_CONC_r01.json BENCH_CONC_r02.json
 """

@@ -259,8 +259,6 @@ def _rule(site, kind):
 def run_sweep(fast: bool = False):
     """Returns (table rows, violations). Each row is
     (site, kind, workload, outcome)."""
-    import jax
-    jax.config.update("jax_platforms", "cpu")
     from opensearch_tpu.common import faults
 
     faults.clear()
@@ -576,6 +574,8 @@ def run_chaos_concurrent(clients: int = 4, n_requests: int = 96,
 
 
 def main():
+    # a correctness sweep: pinned to the CPU backend, before jax loads
+    os.environ["JAX_PLATFORMS"] = "cpu"
     fast = "--fast" in sys.argv
     if "--concurrency" in sys.argv:
         summary, violations = run_chaos_concurrent()

@@ -80,7 +80,7 @@ def load_snapshot(path: str) -> Optional[dict]:
                 "compile_ms": r.get("compile_ms", 0.0),
                 "flops": r.get("flops"), "bytes": r.get("bytes"),
                 "arithmetic_intensity": r.get("arithmetic_intensity"),
-                "bound": r.get("bound", "unknown"),
+                "bound": r.get("bound") or "unclassified",
             }
         return {"families": families, "census": {}}
     return None
@@ -100,7 +100,7 @@ def family_rows(snap: dict) -> List[dict]:
             "p99_ms": r.get("p99_ms"),
             "compiles": r.get("compiles", 0),
             "compile_ms": r.get("compile_ms", 0.0),
-            "bound": r.get("bound", "unknown"),
+            "bound": r.get("bound") or "unclassified",
         })
     rows.sort(key=lambda r: (-float(r["device_ms"] or 0.0),
                              -float(r["compile_ms"] or 0.0),
@@ -127,7 +127,7 @@ def roofline_rows(snap: dict) -> List[dict]:
             "flops": r.get("flops"),
             "bytes": r.get("bytes"),
             "intensity": ai,
-            "bound": r.get("bound", "unknown"),
+            "bound": r.get("bound") or "unclassified",
         })
     rows.sort(key=lambda r: (-float(r["intensity"] or 0.0), r["family"]))
     return rows

@@ -5,8 +5,8 @@ lexically inside a LedgerScope-carrying function or carry an explicit
 Unattributed syncs are exactly what re-opened the bytes_to_device=0 gap
 PR 7 closed: a `jax.device_get` (or an implicit sync — device-array
 `.tolist()`, `np.asarray` on a device value, `.block_until_ready()`)
-that no LedgerScope sees is a transfer the PROFILE.md decomposition
-cannot explain, and a wall the ROADMAP item-1 rewrite cannot budget.
+that no LedgerScope sees is a transfer no profile's decomposition
+can explain, and a wall the ROADMAP item-1 rewrite cannot budget.
 
 A function is "LedgerScope-carrying" when it demonstrably participates
 in ledger attribution:

@@ -4,7 +4,7 @@ Round-6 counterpart of profile_bench.py for the aggregation path: runs the
 bench workload through the msearch envelope, reports the telemetry
 `msearch.phase.*` histograms per config plus an ablation (query-only / each agg alone / both), and times
 the executable-warmup subsystem (cold compile vs post-warmup replay).
-Writes PROFILE_AGGS_RUN.md; PROFILE.md holds the curated analysis.
+Writes PROFILE_AGGS_RUN.md.
 
 Usage: python tools/profile_aggs.py   [BENCH_DOCS=50000 BENCH_AGG_QUERIES=32]
 """
@@ -30,10 +30,7 @@ def log(name, ms, note=""):
 def main():
     os.environ.setdefault("BENCH_DOCS", "50000")
     import bench
-    bench.ensure_backend()
-    import jax
-
-    platform = jax.devices()[0].platform
+    platform = bench.require_device().platform
     print(f"platform: {platform}")
     executor, seg = bench.build_index()
     n_q = int(os.environ.get("BENCH_AGG_QUERIES", "32"))

@@ -21,8 +21,6 @@ import sys
 import time
 
 import jax
-if os.environ.get("SCALE_FORCE_CPU") == "1":
-    jax.config.update("jax_platforms", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np  # noqa: E402

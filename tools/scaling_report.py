@@ -6,7 +6,7 @@ One row per device count D: serving QPS on the real segment-sharded
 SPMD path, per-chip scaling efficiency QPS(D)/(D·QPS(1)), straggler
 skew (max−median per-chip wall), analytic collective bytes/query over
 the ICI, and the live scanned-bytes counter (the block-max trigger
-metric — SCALING.md's offline column, live). A per-device section
+metric — tools/scaling_bench.py's offline column, live). A per-device section
 breaks each point down by chip: partial wall, straggler hits, h2d
 bytes.
 

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Per-phase latency report from a telemetry trace dump.
 
-The successor to the ad-hoc profiling runs in PROFILE.md: instead of
+The successor to ad-hoc profiling runs: instead of
 hand-instrumented one-off scripts, point this at the tracer's output and
 get the per-phase latency distribution of real traffic.
 

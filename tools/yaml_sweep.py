@@ -6,8 +6,8 @@ import os
 import sys
 import traceback
 
-import jax
-jax.config.update("jax_platforms", "cpu")
+# a correctness sweep: pinned to the CPU backend, before jax loads
+os.environ["JAX_PLATFORMS"] = "cpu"
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.join(REPO, "tests"))
