@@ -1,0 +1,54 @@
+"""What several metric readers share: deltas of the node's always-on
+histograms and counters over the window (`_nodes/stats` before and after;
+only sum and count are read, never the bucket-interpolated percentiles),
+and which queries the traced slice covers."""
+
+from __future__ import annotations
+
+from typing import Optional
+
+
+def _metrics(run, when: str) -> dict:
+    return run.stats[when]["telemetry"]["metrics"]
+
+
+def hist_delta(run, name: str):
+    """(delta count, delta sum in ms) of one histogram over the window."""
+    h0 = _metrics(run, "before")["histograms"].get(name, {})
+    h1 = _metrics(run, "after")["histograms"].get(name)
+    if h1 is None:
+        return 0, 0.0
+    return (h1["count"] - h0.get("count", 0),
+            h1["sum_ms"] - h0.get("sum_ms", 0.0))
+
+
+def hist_delta_mean(run, name: str) -> Optional[float]:
+    count, total = hist_delta(run, name)
+    return total / count if count > 0 else None
+
+
+def counter_delta(run, name: str) -> int:
+    c0 = _metrics(run, "before")["counters"].get(name, 0)
+    c1 = _metrics(run, "after")["counters"].get(name, 0)
+    return c1 - c0
+
+
+def slice_shares(run):
+    """[(sample, share)] for every request that overlaps the traced
+    slice: the share of its service interval inside the slice. Summed,
+    the shares count the requests the traced device time belongs to
+    without an edge error of a whole request at either end."""
+    if run.trace_slice is None:
+        return []
+    a, b = run.trace_slice
+    out = []
+    for s in run.all_samples:
+        lo, hi = max(s.sent, a), min(s.done, b)
+        if hi > lo and s.done > s.sent:
+            out.append((s, (hi - lo) / (s.done - s.sent)))
+    return out
+
+
+def queries_in_slice(run) -> float:
+    return sum(share * len(run.requests[s.index])
+               for s, share in slice_shares(run))
