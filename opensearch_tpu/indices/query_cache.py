@@ -203,11 +203,11 @@ def _eval_filter_mask(plan, arrays) -> np.ndarray:
     sig = ("filter_mask", plan.sig())
     fn = _MASK_JIT.get(sig)
     if fn is None:
-        def run(seg, flat_inputs, _plan=plan):
+        def filter_mask(seg, flat_inputs, _plan=plan):  # jit_filter_mask
             cursor = [0]
             _, matches = _eval_plan(_plan, seg, flat_inputs, cursor)
             return matches
-        fn = _MASK_JIT[sig] = jax.jit(run)  # shared-state-ok: benign double-jit race; dict slot write is GIL-atomic
+        fn = _MASK_JIT[sig] = jax.jit(filter_mask)  # shared-state-ok: benign double-jit race; dict slot write is GIL-atomic
     flat = jax.tree_util.tree_map(jnp.asarray, plan.flatten_inputs([]))
     ledger = TELEMETRY.ledger
     scope = ledger.current()

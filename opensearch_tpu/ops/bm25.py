@@ -23,6 +23,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from opensearch_tpu.telemetry.kernels import stage
+
 # Block-max pruning (ISSUE 20, ROADMAP item 4): skip posting blocks whose
 # seal-time score upper bound cannot reach the query's competitive top-k
 # threshold — the BMW/BM25S family of impact-bounded skipping, rank-exact
@@ -52,7 +54,8 @@ def idf(doc_count: int, doc_freq: int) -> float:
 
 
 def blockmax_keep_mask(seg, blk, k1, n_terms, k, min_score=None):
-    """Phase A of the two-phase block-max kernel: per-block keep mask.
+    """Phase A of the two-phase block-max kernel: per-block keep mask
+    (the `blockmax_mask` stage of a device trace).
 
     seg must carry the seal-time `post_bound` leaf (f32 [NBp]: per-block
     max(tf/(tf+k1_seal*norm))). blk carries, beyond score_text_clause's
@@ -75,6 +78,11 @@ def blockmax_keep_mask(seg, blk, k1, n_terms, k, min_score=None):
 
     Returns (keep bool [QB], pruned int32 scalar — real lanes masked off).
     """
+    with stage("blockmax_mask"):
+        return _blockmax_keep_mask(seg, blk, k1, n_terms, k, min_score)
+
+
+def _blockmax_keep_mask(seg, blk, k1, n_terms, k, min_score):
     lane_real = blk["ids"] >= 0                            # [QB]
     safe_ids = jnp.where(lane_real, blk["ids"], 0)
     safe_tid = jnp.where(lane_real, blk["tid"], 0)
@@ -159,28 +167,32 @@ def score_text_clause(seg, blk, k1, block_keep=None):
     clause terms per doc, powering operator=and / minimum_should_match.
     """
     d_pad = seg["live"].shape[0]
-    lane_real = blk["ids"] >= 0                  # [QB]
-    if block_keep is not None:
-        lane_real = lane_real & block_keep
-    safe_ids = jnp.where(lane_real, blk["ids"], 0)
-    docs = seg["post_docs"][safe_ids]            # [QB, 128]
-    tfs = seg["post_tf"][safe_ids]               # [QB, 128]
-    valid = docs >= 0
-    safe_docs = jnp.where(valid, docs, 0)
-    norm_bytes = seg["norms"][blk["row"]][safe_docs]              # [QB, 128]
-    dl = seg["length_table"][norm_bytes]
-    b = blk["b"]
-    denom = tfs + k1 * (1.0 - b + b * dl / blk["avgdl"])
-    partial = blk["w"][:, None] * tfs * (k1 + 1.0) / denom
-    real = valid & lane_real[:, None]
-    partial = jnp.where(real, partial, 0.0)
-    ones = jnp.where(real, 1, 0).astype(jnp.int32)
-    # padding lanes scatter to index d_pad which is dropped (out of bounds)
-    scatter_idx = jnp.where(real, docs, d_pad).ravel()
-    scores = jnp.zeros(d_pad, jnp.float32).at[scatter_idx].add(
-        partial.ravel(), mode="drop")
-    hits = jnp.zeros(d_pad, jnp.int32).at[scatter_idx].add(
-        ones.ravel(), mode="drop")
+    with stage("postings_gather"):
+        lane_real = blk["ids"] >= 0                  # [QB]
+        if block_keep is not None:
+            lane_real = lane_real & block_keep
+        safe_ids = jnp.where(lane_real, blk["ids"], 0)
+        docs = seg["post_docs"][safe_ids]            # [QB, 128]
+        tfs = seg["post_tf"][safe_ids]               # [QB, 128]
+        valid = docs >= 0
+        safe_docs = jnp.where(valid, docs, 0)
+        norm_bytes = seg["norms"][blk["row"]][safe_docs]          # [QB, 128]
+        dl = seg["length_table"][norm_bytes]
+    with stage("bm25_score"):
+        b = blk["b"]
+        denom = tfs + k1 * (1.0 - b + b * dl / blk["avgdl"])
+        partial = blk["w"][:, None] * tfs * (k1 + 1.0) / denom
+        real = valid & lane_real[:, None]
+        partial = jnp.where(real, partial, 0.0)
+        ones = jnp.where(real, 1, 0).astype(jnp.int32)
+    with stage("scatter"):
+        # padding lanes scatter to index d_pad which is dropped (out of
+        # bounds)
+        scatter_idx = jnp.where(real, docs, d_pad).ravel()
+        scores = jnp.zeros(d_pad, jnp.float32).at[scatter_idx].add(
+            partial.ravel(), mode="drop")
+        hits = jnp.zeros(d_pad, jnp.int32).at[scatter_idx].add(
+            ones.ravel(), mode="drop")
     return scores, hits
 
 

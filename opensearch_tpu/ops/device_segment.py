@@ -332,9 +332,10 @@ def _expand_fn(compact_shape: tuple, full_shape: tuple, fill, dtype_str: str):
         out = jnp.full(full_shape, fill, dtype=dtype_str)
         return out.at[tuple(slice(0, s) for s in compact_shape)].set(x)
 
-    fn = jax.jit(expand)
+    from opensearch_tpu.telemetry.kernels import (jit_family,
+                                                  timed_first_call)
+    fn = jit_family(expand, "expand")
     _EXPAND_CACHE[key] = fn  # shared-state-ok: benign double-jit race; dict slot write is GIL-atomic
-    from opensearch_tpu.telemetry.kernels import timed_first_call
     nbytes = float(np.prod(full_shape)) * np.dtype(dtype_str).itemsize \
         if full_shape else float(np.dtype(dtype_str).itemsize)
     return timed_first_call(

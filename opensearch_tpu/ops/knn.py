@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from opensearch_tpu.ops import F32_MATMUL
+from opensearch_tpu.telemetry.kernels import stage
 
 SPACES = ("l2", "cosinesimil", "innerproduct")
 
@@ -64,7 +65,8 @@ def space_score(raw: jnp.ndarray, space: str) -> jnp.ndarray:
 def exact_knn_scores(vectors: jnp.ndarray, query: jnp.ndarray,
                      space: str) -> jnp.ndarray:
     _check_space(space)
-    return space_score(raw_similarity(vectors, query, space), space)
+    with stage("distance"):
+        return space_score(raw_similarity(vectors, query, space), space)
 
 
 def knn_match_topk(scores: jnp.ndarray, eligible: jnp.ndarray,
@@ -74,16 +76,17 @@ def knn_match_topk(scores: jnp.ndarray, eligible: jnp.ndarray,
     Returns (scores, matches): matches true only for the k best eligible
     docs (score-desc, doc-asc tie-break via top_k's lowest-index rule)."""
     d = scores.shape[0]
-    masked = jnp.where(eligible, scores, -jnp.inf)
-    k_eff = min(int(k), int(d))
-    top_vals, top_idx = jax.lax.top_k(masked, k_eff)
-    valid = top_vals > -jnp.inf
-    # invalid slots scatter out of bounds and are dropped — routing them to
-    # index 0 would clobber a real winner at doc ord 0
-    matches = jnp.zeros(d, jnp.bool_).at[
-        jnp.where(valid, top_idx, d)].set(True, mode="drop")
-    matches = matches & eligible
-    return jnp.where(matches, scores, 0.0), matches
+    with stage("top_k"):
+        masked = jnp.where(eligible, scores, -jnp.inf)
+        k_eff = min(int(k), int(d))
+        top_vals, top_idx = jax.lax.top_k(masked, k_eff)
+        valid = top_vals > -jnp.inf
+        # invalid slots scatter out of bounds and are dropped — routing
+        # them to index 0 would clobber a real winner at doc ord 0
+        matches = jnp.zeros(d, jnp.bool_).at[
+            jnp.where(valid, top_idx, d)].set(True, mode="drop")
+        matches = matches & eligible
+        return jnp.where(matches, scores, 0.0), matches
 
 
 # ------------------------------------------------------------------- IVF ----
@@ -234,8 +237,9 @@ def ivf_knn_scores(packed_vecs: jnp.ndarray, packed_ids: jnp.ndarray,
                                                   dims)
     cand = jnp.take(packed_ids.reshape(n_blocks, IVF_BLOCK),
                     blk_ids, axis=0).reshape(budget * IVF_BLOCK)
-    raw = raw_similarity(cand_vecs, query, space)
-    scores01 = space_score(raw, space)
+    with stage("distance"):
+        raw = raw_similarity(cand_vecs, query, space)
+        scores01 = space_score(raw, space)
     valid = cand >= 0
     # padding slots scatter out of bounds (dropped) — using index 0 would
     # overwrite doc ord 0's entries

@@ -151,6 +151,11 @@ def _run_search(node, index_expr: Optional[str], body: Optional[dict],
         _parse_deadline, execute_search)
     tracer = TELEMETRY.tracer
     metrics = TELEMETRY.metrics
+    # the always-on span ring (telemetry/tracer.py): `rest.search` is
+    # the interval `rest.search_ms` times, from the same two clock
+    # reads, under the HTTP request's span where there is one
+    ring = tracer.spans
+    ring_trace, ring_id, ring_parent = ring.enter()
     root = tracer.start_trace("rest.search", index=index_expr or "_all")
     metrics.counter("rest.search_requests").inc()
     # request lifecycle (telemetry/lifecycle.py): arrive is implicit at
@@ -161,7 +166,7 @@ def _run_search(node, index_expr: Optional[str], body: Optional[dict],
     tl = flight.timeline()
     tl_prev = flight.bind(tl) if tl is not None else None
     phase_times: Dict[str, float] = {}
-    t0 = time.perf_counter_ns()
+    t0 = time.monotonic_ns()
     try:
         executors, filters = _search_targets(node, index_expr)
         body = dict(body or {})
@@ -286,8 +291,11 @@ def _run_search(node, index_expr: Optional[str], body: Optional[dict],
             root.end(error=e)
         raise
     finally:
-        metrics.histogram("rest.search_ms").observe(
-            (time.perf_counter_ns() - t0) / 1e6)
+        t1 = time.monotonic_ns()
+        metrics.histogram("rest.search_ms").observe((t1 - t0) / 1e6)
+        ring_trace.spans.append(
+            (ring_id, ring_parent, "rest.search", t0, t1, None))
+        ring.leave(ring_trace, ring_parent)
         if tl is not None:
             flight.unbind(tl_prev)
             if tl.took_ms is None:      # the reject path completed above
@@ -958,6 +966,19 @@ def register_search_actions(node, c):
                 "_shards": res["_shards"]}
 
     def do_msearch(req):
+        # `rest.msearch` in the always-on span ring: the whole handler,
+        # whichever path serves the batch, on every exit
+        ring = TELEMETRY.tracer.spans
+        trace, sid, parent = ring.enter()
+        t0 = time.monotonic_ns()
+        try:
+            return _msearch(req)
+        finally:
+            trace.spans.append((sid, parent, "rest.msearch", t0,
+                                time.monotonic_ns(), None))
+            ring.leave(trace, parent)
+
+    def _msearch(req):
         lines = _ndjson_lines(req)
         if len(lines) % 2 != 0:
             raise IllegalArgumentError(
@@ -2388,6 +2409,26 @@ def register_telemetry_actions(node, c):
                 "stats": TELEMETRY.tracer.stats(),
                 "traces": TELEMETRY.tracer.traces(size or None)}
 
+    def do_get_spans(req):
+        # the always-on flat span ring (ISSUE 25): every kept span that
+        # overlaps [since_ns, until_ns] on the monotonic clock
+        def _ns(name):
+            v = req.param(name)
+            if v in (None, ""):
+                return None
+            try:
+                return int(v)
+            except ValueError:
+                raise IllegalArgumentError(
+                    f"[{name}] must be an integer of nanoseconds, "
+                    f"got [{v}]")
+        return TELEMETRY.tracer.spans.export(_ns("since_ns"),
+                                             _ns("until_ns"))
+
+    def do_clear_spans(req):
+        TELEMETRY.tracer.spans.clear()
+        return {"acknowledged": True}
+
     def do_clear_traces(req):
         TELEMETRY.tracer.clear()
         return {"acknowledged": True}
@@ -2547,7 +2588,10 @@ def register_telemetry_actions(node, c):
         # kernel-level device-compute profiler (ISSUE 19): the
         # executable census (always-on), per-family sampled device
         # walls and the roofline table — tools/kernel_report.py input
-        return {"kernels": TELEMETRY.kernels.snapshot()}
+        # ?scopes=true adds each executable's {HLO instruction ->
+        # stage} map, built on first demand (ISSUE 25)
+        return {"kernels": TELEMETRY.kernels.snapshot(
+            scopes=req.bool_param("scopes"))}
 
     def do_kernels_enable(req):
         k = TELEMETRY.kernels
@@ -2624,6 +2668,8 @@ def register_telemetry_actions(node, c):
 
     c.register("GET", "/_telemetry/traces", do_get_traces)
     c.register("POST", "/_telemetry/traces/_clear", do_clear_traces)
+    c.register("GET", "/_telemetry/spans", do_get_spans)
+    c.register("POST", "/_telemetry/spans/_clear", do_clear_spans)
     c.register("POST", "/_telemetry/_enable", do_enable)
     c.register("POST", "/_telemetry/_disable", do_disable)
     c.register("GET", "/_telemetry/metrics", do_metrics)

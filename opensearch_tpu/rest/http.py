@@ -10,11 +10,13 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 import urllib.parse
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
 from opensearch_tpu.node import Node
+from opensearch_tpu.telemetry import TELEMETRY
 
 
 class _Handler(BaseHTTPRequestHandler):
@@ -22,12 +24,45 @@ class _Handler(BaseHTTPRequestHandler):
     node: Node = None  # set by server factory
 
     def _do(self, method: str):
+        """One request, under the root span of its timeline: the
+        always-on ring (telemetry/tracer.py SpanRing) gets `http.request`
+        from the first line to after the last byte is written, with
+        `http.read_decode` and `http.encode_write` under it; whatever
+        the handler records on this thread (rest.search, envelope, ...)
+        hangs below through the bound trace, and the whole request goes
+        into the ring as one row when it ends. Every exit records.
+        `marks` are the clock reads: entry, body decoded, handler
+        returned, payload written."""
+        marks = [time.monotonic_ns()]
+        ring = TELEMETRY.tracer.spans
+        trace, sid, parent = ring.enter()
+        attrs = {"method": method, "route": "other", "status": 0,
+                 "request_bytes": 0, "response_bytes": 0}
+        try:
+            self._serve(method, marks, attrs)
+        finally:
+            spans = trace.spans
+            spans.append((sid, parent, "http.request", marks[0],
+                          time.monotonic_ns(), attrs))
+            if len(marks) > 1:
+                spans.append((sid + 1, sid, "http.read_decode", marks[0],
+                              marks[1], None))
+            if len(marks) > 3:
+                spans.append((sid + 2, sid, "http.encode_write", marks[2],
+                              marks[3], None))
+            ring.leave(trace, parent)
+
+    def _serve(self, method: str, marks: list, attrs: dict):
         parsed = urllib.parse.urlsplit(self.path)
+        tail = parsed.path.rstrip("/").rsplit("/", 1)[-1]
+        if tail in ("_search", "_msearch"):
+            attrs["route"] = tail
         params = {k: v[-1] for k, v in
                   urllib.parse.parse_qs(parsed.query,
                                         keep_blank_values=True).items()}
         length = int(self.headers.get("Content-Length") or 0)
         raw = self.rfile.read(length) if length else None
+        attrs["request_bytes"] = length
         body = None
         if raw:
             # Content-Type negotiation (libs/x-content XContentType
@@ -48,6 +83,8 @@ class _Handler(BaseHTTPRequestHandler):
                     },
                     "status": 406,
                 }).encode("utf-8")
+                attrs["status"] = 406
+                attrs["response_bytes"] = len(payload)
                 self.send_response(406)
                 self.send_header("Content-Type", "application/json")
                 self.send_header("Content-Length", str(len(payload)))
@@ -76,9 +113,12 @@ class _Handler(BaseHTTPRequestHandler):
                 # raw binary into the NDJSON parser (which would 500)
                 body = None
                 raw = None
+        marks.append(time.monotonic_ns())
         resp = self.node.handle(method, parsed.path, params=params,
                                 body=body, raw_body=raw,
                                 headers=dict(self.headers.items()))
+        marks.append(time.monotonic_ns())
+        attrs["status"] = resp.status
         content_type = resp.content_type
         if content_type == "application/json":
             from opensearch_tpu.common import xcontent
@@ -99,6 +139,8 @@ class _Handler(BaseHTTPRequestHandler):
         self.end_headers()
         if method != "HEAD":
             self.wfile.write(payload)
+        attrs["response_bytes"] = len(payload)
+        marks.append(time.monotonic_ns())
 
     def do_GET(self):
         self._do("GET")

@@ -576,12 +576,24 @@ _DEVMEM.add_provider(
 # import cycle; the executor names stay as aliases — warmup.py and the
 # ingest-serving tests import them from here
 from opensearch_tpu.telemetry.kernels import (  # noqa: E402
-    THREAD_COMPILES as _THREAD_COMPILES, note_compile as _note_compile,
-    offpath_compiles, timed_first_call as _timed_first_call)
+    THREAD_COMPILES as _THREAD_COMPILES, jit_family,
+    note_compile as _note_compile, offpath_compiles, stage as _stage,
+    timed_first_call as _timed_first_call)
 
 # kernel profiler handle (ISSUE 19): census registration is always-on
 # (compile-time only); the sampled dispatch timer rides the gate
 _KERNELS = TELEMETRY.kernels
+# the always-on span ring (telemetry/tracer.py, ISSUE 25): the envelope
+# and its waves record completed spans from the clock reads the
+# msearch.phase.* histograms already make
+_SPANS = TELEMETRY.tracer.spans
+# a `_search` served through the B=1 envelope observes the operator's
+# search.* metrics as the general path does (controller.execute_search)
+_SEARCH_QUERIES = TELEMETRY.metrics.counter("search.queries")
+_SEARCH_TOOK = TELEMETRY.metrics.histogram("search.took_ms")
+_SEARCH_PHASE_HISTS = {
+    name: TELEMETRY.metrics.histogram(f"search.phase.{name}_ms")
+    for name in ("parse", "query", "render")}
 
 
 def _plan_family(plan: Plan, agg_plans=()) -> str:
@@ -787,6 +799,75 @@ class _StagingPool:
                 self._bytes += buf.nbytes
 
 
+class _EnvelopeSpan:
+    """What one envelope carries for the always-on span ring: the
+    request's trace and its own span id (the parent of its phase and
+    wave spans), the trace ids a scheduler-coalesced envelope serves
+    (per body, or None), its wave count, the two reads of its parse
+    phase and the read its `took` came from."""
+
+    __slots__ = ("trace", "span_id", "trace_ids", "waves", "parse", "end")
+
+    def __init__(self, trace, span_id: int,
+                 trace_ids: Optional[list] = None):
+        self.trace = trace
+        self.span_id = span_id
+        self.trace_ids = trace_ids
+        self.waves = 0
+        self.parse = None
+        self.end = 0.0
+
+
+# the spans' attributes, built when the ring is exported (SpanRing
+# keeps `(build, *arguments)`): the serving path stores the raw values
+
+def _envelope_attrs(bodies: int, waves: int, trace_ids) -> dict:
+    out = {"bodies": bodies, "waves": waves}
+    if trace_ids is not None:
+        out["trace_ids"] = sorted({t for t in trace_ids if t is not None})
+    return out
+
+
+def _wave_attrs(wave: int, trace_ids) -> dict:
+    """What every span of a wave carries: its index in the envelope,
+    and for a scheduler-coalesced wave the traces (requests) whose
+    items it serves."""
+    out = {"wave": wave}
+    if trace_ids is not None:
+        out["trace_ids"] = trace_ids
+    return out
+
+
+def _dispatch_attrs(wave: int, trace_ids, programs: int, nbytes: int,
+                    infos) -> dict:
+    """`dispatch`: programs enqueued, bytes uploaded, and the
+    executables by their census records (telemetry/kernels.py
+    ExecInfo). One program a wave is the common case (B=1, one group)
+    and is named outright; more are listed in dispatch order."""
+    out = _wave_attrs(wave, trace_ids)
+    out.update(programs=programs, nbytes=nbytes)
+    if infos:
+        out.update(family=infos[0].family,
+                   fingerprint=infos[0].fingerprint, shape=infos[0].shape)
+        if len(infos) > 1:
+            out["fingerprints"] = [i.fingerprint for i in infos]
+    return out
+
+
+def _hybrid_dispatch_attrs(wave: int, trace_ids, programs: int) -> dict:
+    out = _wave_attrs(wave, trace_ids)
+    out.update(family="hybrid_env", programs=programs)
+    return out
+
+
+def _wait_attrs(wave: int, trace_ids, nbytes: int, programs: int) -> dict:
+    """`device_wait`: bytes fetched, and whether a program of its own
+    (concat_rows) ran inside it."""
+    out = _wave_attrs(wave, trace_ids)
+    out.update(nbytes=nbytes, programs=programs)
+    return out
+
+
 class _MsearchWave:
     """One wave of the msearch pipeline: its item indices, the payload
     the prepare half consumes, and the dispatch/collect bookkeeping the
@@ -795,7 +876,7 @@ class _MsearchWave:
     __slots__ = ("kind", "items", "payload", "state", "scope", "ph",
                  "raise_errors", "window", "prep_t0", "prep_t1",
                  "collect_t0", "collect_t1", "error", "index",
-                 "timeline", "breaker_probe")
+                 "timeline", "breaker_probe", "span")
 
     def __init__(self, kind: str, items: List[int], payload,
                  raise_errors: bool = False):
@@ -816,6 +897,11 @@ class _MsearchWave:
         # collect event lands on the owning request's lifecycle
         self.breaker_probe = False  # this wave is the device-memory
         # breaker's single half-open probe (common/admission.py)
+        self.span = None            # (the request's Trace, the envelope's
+        # span id, the id this wave's `dispatch` drew, the wave's index,
+        # the trace ids it serves or None): rides the wave across the
+        # collector-thread boundary like `scope`, so a wave collected
+        # there keeps its request's trace
 
 
 class _TimelineFan:
@@ -1165,18 +1251,14 @@ def build_query_phase(plan: Plan, meta: DeviceSegmentMeta, k: int,
         cursor = [0]
         scores, matches = _eval_plan(plan, seg, flat_inputs, cursor)
         d_pad = seg["live"].shape[0]
-        in_seg = jnp.arange(d_pad, dtype=jnp.int32) < meta.num_docs
-        # root: only top-level rows are returnable hits — nested child rows
-        # participate in scoring solely through the `nested` plan's join
-        # (Queries.newNonNestedFilter analog)
-        eligible = matches & seg["live"] & seg["root"] & in_seg \
-            & (scores >= min_score)
-        total = jnp.sum(eligible.astype(jnp.int32))
+        eligible, total = _eligible_total(matches, seg, meta.num_docs,
+                                          scores, min_score)
         keys = scores if sort_mode == "score" else sort_key_arr
-        masked = jnp.where(eligible, keys, NEG_INF)
         k_eff = min(k, d_pad)
-        top_keys, top_idx = jax.lax.top_k(masked, k_eff)
-        top_scores = scores[top_idx]
+        with _stage("top_k"):
+            masked = jnp.where(eligible, keys, NEG_INF)
+            top_keys, top_idx = jax.lax.top_k(masked, k_eff)
+            top_scores = scores[top_idx]
         agg_outs = []
         if agg_plans:
             eval_aggs(list(agg_plans), seg, flat_inputs, cursor, eligible,
@@ -1293,19 +1375,44 @@ def _pack_row(top_scores, top_idx, total):
     read 0 on a v5e. Integer lanes are never flushed, and a bitcast is
     pure data movement on every backend (the input envelope and the
     result page already travel this way round)."""
-    return jnp.concatenate([
-        jax.lax.bitcast_convert_type(top_scores, jnp.int32),
-        top_idx.astype(jnp.int32),
-        total[None].astype(jnp.int32)])
+    with _stage("pack_row"):
+        return jnp.concatenate([
+            jax.lax.bitcast_convert_type(top_scores, jnp.int32),
+            top_idx.astype(jnp.int32),
+            total[None].astype(jnp.int32)])
 
 
-def _topk_or_empty(masked, k_eff: int):
-    """lax.top_k, except k=0 (size=0 agg/count queries) skips the
-    selection networks entirely — the dominant device cost for a
-    hits-free query."""
+def _topk_or_empty(eligible, scores, k_eff: int):
+    """lax.top_k over the eligible docs' scores, except k=0 (size=0
+    agg/count queries) skips the selection networks entirely — the
+    dominant device cost for a hits-free query."""
     if k_eff == 0:
         return (jnp.zeros((0,), jnp.float32), jnp.zeros((0,), jnp.int32))
-    return jax.lax.top_k(masked, k_eff)
+    with _stage("top_k"):
+        return jax.lax.top_k(jnp.where(eligible, scores, NEG_INF), k_eff)
+
+
+def _unpack_envelope(packed_buf, layout, treedef):
+    """The packed input envelope back into its stacked leaves: (the
+    batched flat inputs, the trailing min_score leaf)."""
+    with _stage("unpack_envelope"):
+        leaves = unpack_leaves(packed_buf, layout)
+        return (jax.tree_util.tree_unflatten(treedef, leaves[:-1]),
+                leaves[-1])
+
+
+def _eligible_total(matches, seg, num_docs: int, scores, min_score):
+    """The docs a query may return (matched, live, top-level, inside
+    the segment, at or over min_score) and how many they are."""
+    with _stage("eligible_total"):
+        in_seg = jnp.arange(seg["live"].shape[0],
+                            dtype=jnp.int32) < num_docs
+        # root: only top-level rows are returnable hits — nested child
+        # rows participate in scoring solely through the `nested`
+        # plan's join (Queries.newNonNestedFilter analog)
+        eligible = matches & seg["live"] & seg["root"] & in_seg \
+            & (scores >= min_score)
+        return eligible, jnp.sum(eligible.astype(jnp.int32))
 
 
 # candidate-buffer kernel only pays off while the sorted buffer stays far
@@ -1364,56 +1471,63 @@ def build_candidate_query_phase(plan: Plan, meta: DeviceSegmentMeta, k: int,
             lane_real = lane_real & keep
         else:
             pruned = jnp.int32(0)
-        safe_ids = jnp.where(lane_real, my["ids"], 0)
-        docs = seg["post_docs"][safe_ids]             # [QB, 128]
-        tfs = seg["post_tf"][safe_ids]
-        valid = docs >= 0
-        safe_docs = jnp.where(valid, docs, 0)
-        norm_bytes = seg["norms"][my["row"]][safe_docs]
-        dl = seg["length_table"][norm_bytes]
-        b = my["b"]
-        k1 = my["k1"]
-        denom = tfs + k1 * (1.0 - b + b * dl / my["avgdl"])
-        partial = my["w"][:, None] * tfs * (k1 + 1.0) / denom
-        real = valid & lane_real[:, None]
+        with _stage("postings_gather"):
+            safe_ids = jnp.where(lane_real, my["ids"], 0)
+            docs = seg["post_docs"][safe_ids]             # [QB, 128]
+            tfs = seg["post_tf"][safe_ids]
+            valid = docs >= 0
+            safe_docs = jnp.where(valid, docs, 0)
+            norm_bytes = seg["norms"][my["row"]][safe_docs]
+            dl = seg["length_table"][norm_bytes]
+        with _stage("bm25_score"):
+            b = my["b"]
+            k1 = my["k1"]
+            denom = tfs + k1 * (1.0 - b + b * dl / my["avgdl"])
+            partial = my["w"][:, None] * tfs * (k1 + 1.0) / denom
+            real = valid & lane_real[:, None]
 
         n = docs.shape[0] * docs.shape[1]
         big = jnp.int32(2 ** 30)
-        doc_key = jnp.where(real, docs, big).reshape(n)
-        part = jnp.where(real, partial, 0.0).reshape(n)
-        hit = jnp.where(real, 1, 0).astype(jnp.int32).reshape(n)
-
-        sdoc, spart, shit = jax.lax.sort([doc_key, part, hit], num_keys=1)
-        is_end = jnp.concatenate([sdoc[:-1] != sdoc[1:],
-                                  jnp.ones((1,), bool)])
-        # exact windowed segment-sum: a doc's lanes are adjacent after the
-        # sort and number at most n_terms (each term lists a doc once), so
-        # summing a fixed backward window at the run's END lane is exact —
-        # no cumsum-difference cancellation, and the left-to-right order of
-        # the (stable) sort keeps float summation deterministic
-        run_score = spart
-        run_hits = shit
-        for j in range(1, n_terms):
-            prev_doc = jnp.concatenate([jnp.full((j,), -2, sdoc.dtype),
-                                        sdoc[:-j]])
-            same = prev_doc == sdoc
-            prev_part = jnp.concatenate([jnp.zeros((j,), spart.dtype),
-                                         spart[:-j]])
-            prev_hit = jnp.concatenate([jnp.zeros((j,), shit.dtype),
-                                        shit[:-j]])
-            run_score = run_score + jnp.where(same, prev_part, 0.0)
-            run_hits = run_hits + jnp.where(same, prev_hit, 0)
-        matches = run_hits >= my["min_hits"]
-        score = jnp.full(n, my["boost"]) if constant else run_score
-        valid_end = is_end & (sdoc < big)
-        safe_end_docs = jnp.where(valid_end, sdoc, 0)
-        eligible = valid_end & matches & seg["live"][safe_end_docs] \
-            & seg["root"][safe_end_docs] & (score >= min_score)
-        total = jnp.sum(eligible.astype(jnp.int32))
-        masked = jnp.where(eligible, score, NEG_INF)
+        with _stage("candidate_sort"):
+            doc_key = jnp.where(real, docs, big).reshape(n)
+            part = jnp.where(real, partial, 0.0).reshape(n)
+            hit = jnp.where(real, 1, 0).astype(jnp.int32).reshape(n)
+            sdoc, spart, shit = jax.lax.sort([doc_key, part, hit],
+                                             num_keys=1)
+        with _stage("run_sum"):
+            is_end = jnp.concatenate([sdoc[:-1] != sdoc[1:],
+                                      jnp.ones((1,), bool)])
+            # exact windowed segment-sum: a doc's lanes are adjacent
+            # after the sort and number at most n_terms (each term lists
+            # a doc once), so summing a fixed backward window at the
+            # run's END lane is exact — no cumsum-difference
+            # cancellation, and the left-to-right order of the (stable)
+            # sort keeps float summation deterministic
+            run_score = spart
+            run_hits = shit
+            for j in range(1, n_terms):
+                prev_doc = jnp.concatenate(
+                    [jnp.full((j,), -2, sdoc.dtype), sdoc[:-j]])
+                same = prev_doc == sdoc
+                prev_part = jnp.concatenate(
+                    [jnp.zeros((j,), spart.dtype), spart[:-j]])
+                prev_hit = jnp.concatenate(
+                    [jnp.zeros((j,), shit.dtype), shit[:-j]])
+                run_score = run_score + jnp.where(same, prev_part, 0.0)
+                run_hits = run_hits + jnp.where(same, prev_hit, 0)
+        with _stage("eligible_total"):
+            matches = run_hits >= my["min_hits"]
+            score = jnp.full(n, my["boost"]) if constant else run_score
+            valid_end = is_end & (sdoc < big)
+            safe_end_docs = jnp.where(valid_end, sdoc, 0)
+            eligible = valid_end & matches & seg["live"][safe_end_docs] \
+                & seg["root"][safe_end_docs] & (score >= min_score)
+            total = jnp.sum(eligible.astype(jnp.int32))
         k_eff = min(k, n)
-        top_scores, top_lane = jax.lax.top_k(masked, k_eff)
-        top_docs = sdoc[top_lane]
+        with _stage("top_k"):
+            masked = jnp.where(eligible, score, NEG_INF)
+            top_scores, top_lane = jax.lax.top_k(masked, k_eff)
+            top_docs = sdoc[top_lane]
         if k_eff < k:
             top_scores = jnp.concatenate(
                 [top_scores, jnp.full(k - k_eff, NEG_INF)])
@@ -1427,10 +1541,10 @@ def build_candidate_query_phase(plan: Plan, meta: DeviceSegmentMeta, k: int,
         return row
 
     def run(seg, packed_buf):
-        leaves = unpack_leaves(packed_buf, layout)
-        batched_flat = jax.tree_util.tree_unflatten(treedef, leaves[:-1])
+        batched_flat, min_scores = _unpack_envelope(packed_buf, layout,
+                                                    treedef)
         return jax.vmap(one, in_axes=(None, 0, 0))(seg, batched_flat,
-                                                   leaves[-1])
+                                                   min_scores)
 
     return run
 
@@ -1467,20 +1581,17 @@ def build_batched_query_phase(plan: Plan, meta: DeviceSegmentMeta, k: int,
     def one(seg, flat_inputs, min_score):
         cursor = [0]
         scores, matches = _eval_plan(plan, seg, flat_inputs, cursor)
-        in_seg = jnp.arange(seg["live"].shape[0], dtype=jnp.int32) < meta.num_docs
-        eligible = matches & seg["live"] & seg["root"] & in_seg \
-            & (scores >= min_score)
-        total = jnp.sum(eligible.astype(jnp.int32))
-        masked = jnp.where(eligible, scores, NEG_INF)
+        eligible, total = _eligible_total(matches, seg, meta.num_docs,
+                                          scores, min_score)
         k_eff = min(k, seg["live"].shape[0])
-        top_scores, top_idx = _topk_or_empty(masked, k_eff)
+        top_scores, top_idx = _topk_or_empty(eligible, scores, k_eff)
         return _pack_row(top_scores, top_idx, total)
 
     def run(seg, packed_buf):
-        leaves = unpack_leaves(packed_buf, layout)
-        batched_flat = jax.tree_util.tree_unflatten(treedef, leaves[:-1])
+        batched_flat, min_scores = _unpack_envelope(packed_buf, layout,
+                                                    treedef)
         return jax.vmap(one, in_axes=(None, 0, 0))(seg, batched_flat,
-                                                   leaves[-1])
+                                                   min_scores)
 
     return run
 
@@ -1506,13 +1617,10 @@ def build_batched_agg_query_phase(plan: Plan, meta: DeviceSegmentMeta,
         cursor = [0]
         scores, matches = _eval_plan(plan, seg, flat_inputs, cursor)
         d_pad = seg["live"].shape[0]
-        in_seg = jnp.arange(d_pad, dtype=jnp.int32) < meta.num_docs
-        eligible = matches & seg["live"] & seg["root"] & in_seg \
-            & (scores >= min_score)
-        total = jnp.sum(eligible.astype(jnp.int32))
-        masked = jnp.where(eligible, scores, NEG_INF)
+        eligible, total = _eligible_total(matches, seg, meta.num_docs,
+                                          scores, min_score)
         k_eff = min(k, d_pad)
-        top_scores, top_idx = _topk_or_empty(masked, k_eff)
+        top_scores, top_idx = _topk_or_empty(eligible, scores, k_eff)
         agg_outs: List[dict] = []
         eval_aggs(list(agg_plans), seg, flat_inputs, cursor, eligible,
                   agg_outs)
@@ -1526,11 +1634,11 @@ def build_batched_agg_query_phase(plan: Plan, meta: DeviceSegmentMeta,
         return jnp.concatenate(pieces)
 
     def run(seg, packed_buf):
-        leaves = unpack_leaves(packed_buf, layout)
-        batched_flat = jax.tree_util.tree_unflatten(treedef, leaves[:-1])
+        batched_flat, min_scores = _unpack_envelope(packed_buf, layout,
+                                                    treedef)
         axes_tree = jax.tree_util.tree_unflatten(treedef, list(axes[:-1]))
         return jax.vmap(one, in_axes=(None, axes_tree, 0))(
-            seg, batched_flat, leaves[-1])
+            seg, batched_flat, min_scores)
 
     return run
 
@@ -1602,8 +1710,8 @@ def _agg_envelope_runner(plan_sig, plan: Plan, meta: DeviceSegmentMeta,
     if hit is None:
         out_layout, width = _agg_out_layout(
             plan, meta, agg_plans, arrays, example_flat, np.float32(0))
-        fn = jax.jit(build_batched_agg_query_phase(
-            plan, meta, k, layout, treedef, axes, agg_plans))
+        fn = jit_family(build_batched_agg_query_phase(
+            plan, meta, k, layout, treedef, axes, agg_plans), "agg_env")
         _JIT_CACHE[key] = (fn, out_layout, width)  # shared-state-ok: benign double-jit race; dict slot write is GIL-atomic
         wrapped = _timed_first_call(
             fn, family="agg_env", shape=_env_shape(layout, k, meta),
@@ -1616,8 +1724,7 @@ def _agg_envelope_runner(plan_sig, plan: Plan, meta: DeviceSegmentMeta,
     return hit
 
 
-@functools.partial(jax.jit, static_argnums=())
-def _concat_rows(outs):
+def concat_rows(outs):
     """Column-pad + row-concat all group outputs into ONE device array, so
     a whole msearch batch is fetched in a single transfer (each fetch is
     a synchronization with the device)."""
@@ -1625,6 +1732,10 @@ def _concat_rows(outs):
     return jnp.concatenate(
         [jnp.pad(o, ((0, 0), (0, width - o.shape[1]))) for o in outs],
         axis=0)
+
+
+# the XLA module is named for the function: jit_concat_rows
+_concat_rows = jax.jit(concat_rows)
 
 
 def unpack_batched_result(packed: np.ndarray, k_eff: int):
@@ -1656,19 +1767,22 @@ def _envelope_runner(plan_sig, plan: Plan, meta: DeviceSegmentMeta, k: int,
                 qb128 = shape[1] * 128
                 break
         cand = _candidate_kernel_fits(plan.kind, n_terms, qb128)
+        # the family names the XLA module (jit_<family>) and the census
+        # record alike
+        fam = "bm25_candidate" if cand else _plan_family(plan)
         if cand:
             # blockmax admission is a pure function of facts already in
             # the JIT key: the plan's input tree (treedef gains tid/
             # bscale only when compiled with the gate on), the layout's
             # lane count, and k — no extra key component needed
-            fn = jax.jit(build_candidate_query_phase(
+            fn = jit_family(build_candidate_query_phase(
                 plan, meta, k, layout, treedef,
-                bm=_blockmax_admitted(plan, k)))
+                bm=_blockmax_admitted(plan, k)), fam)
         else:
-            fn = jax.jit(build_batched_query_phase(plan, meta, k,
-                                                   layout, treedef))
+            fn = jit_family(build_batched_query_phase(plan, meta, k,
+                                                      layout, treedef),
+                            fam)
         _JIT_CACHE[key] = fn  # shared-state-ok: benign double-jit race; dict slot write is GIL-atomic
-        fam = "bm25_candidate" if cand else _plan_family(plan)
         return _timed_first_call(
             fn, family=fam, shape=_env_shape(layout, k, meta), key=key,
             cost=_plan_cost(plan, meta, _layout_batch(layout)))
@@ -1742,10 +1856,12 @@ def _runner(plan_sig, plan: Plan, meta: DeviceSegmentMeta, k: int, sort_mode: st
             return kp.timed(fn, _plan_family(plan, agg_plans),
                             f"k{k}/d{meta.d_pad}/{sort_mode}")
         return fn
-    fn = jax.jit(build_query_phase(plan, meta, k, sort_mode, agg_plans))
+    fam = _plan_family(plan, agg_plans)
+    fn = jit_family(build_query_phase(plan, meta, k, sort_mode, agg_plans),
+                    fam)
     _JIT_CACHE[key] = fn  # shared-state-ok: benign double-jit race; dict slot write is GIL-atomic
     return _timed_first_call(
-        fn, family=_plan_family(plan, agg_plans),
+        fn, family=fam,
         shape=f"k{k}/d{meta.d_pad}/{sort_mode}", key=key,
         cost=_plan_cost(plan, meta))
 
@@ -1780,8 +1896,9 @@ def build_hybrid_query_phase(plans, meta: DeviceSegmentMeta, k: int):
             scores, matches = _eval_plan(plans[i], seg, flat_inputs, cursor)
             eligible = matches & base & (scores >= min_score)
             union = union | eligible
-            masked = jnp.where(eligible, scores, NEG_INF)
-            top_scores, top_idx = jax.lax.top_k(masked, k_eff)
+            with _stage("top_k"):
+                masked = jnp.where(eligible, scores, NEG_INF)
+                top_scores, top_idx = jax.lax.top_k(masked, k_eff)
             valid = top_scores > NEG_INF
             cnt = jnp.sum(valid.astype(jnp.int32))
             mn = jnp.min(jnp.where(valid, top_scores, jnp.inf))
@@ -1810,10 +1927,10 @@ def build_batched_hybrid_query_phase(plans, meta: DeviceSegmentMeta,
     one = build_hybrid_query_phase(plans, meta, k)
 
     def run(seg, packed_buf):
-        leaves = unpack_leaves(packed_buf, layout)
-        batched_flat = jax.tree_util.tree_unflatten(treedef, leaves[:-1])
+        batched_flat, min_scores = _unpack_envelope(packed_buf, layout,
+                                                    treedef)
         return jax.vmap(one, in_axes=(None, 0, 0))(seg, batched_flat,
-                                                   leaves[-1])
+                                                   min_scores)
 
     return run
 
@@ -1824,8 +1941,8 @@ def _batched_hybrid_runner(plans, meta: DeviceSegmentMeta, k: int,
            k, layout, treedef)
     fn = _JIT_CACHE.get(key)
     if fn is None:
-        fn = jax.jit(build_batched_hybrid_query_phase(plans, meta, k,
-                                                      layout, treedef))
+        fn = jit_family(build_batched_hybrid_query_phase(
+            plans, meta, k, layout, treedef), "hybrid_env")
         _JIT_CACHE[key] = fn  # shared-state-ok: benign double-jit race; dict slot write is GIL-atomic
         cost = [_plan_cost(p, meta, _layout_batch(layout))
                 for p in plans]
@@ -2079,7 +2196,7 @@ def _page_merger(sig, mode, k_page: int, stride: int, seg_statics,
                      .astype(jnp.int32).reshape(-1))
         return jnp.concatenate(parts)
 
-    fn = jax.jit(run)
+    fn = jit_family(run, "page_merger")
     _JIT_CACHE[sig] = fn  # shared-state-ok: benign double-jit race; dict slot write is GIL-atomic
     return _timed_first_call(
         fn, family="page_merger",
@@ -2754,7 +2871,8 @@ class SearchExecutor:
                      phase_times: Optional[dict] = None,
                      waves: Optional[int] = None,
                      timelines: Optional[list] = None,
-                     tenants: Optional[list] = None) -> dict:
+                     tenants: Optional[list] = None,
+                     trace_ids: Optional[list] = None) -> dict:
         """_msearch: execute many search bodies, batching same-shaped
         score-sorted queries into single vmapped device programs per segment
         (reference: action/search/TransportMultiSearchAction fans bodies out
@@ -2802,12 +2920,17 @@ class SearchExecutor:
         tenants: per-body tenant ids from the scheduler (aligned with
         `timelines`) — the insights recorder's per-shape tenant
         breakdown reads them per item on coalesced waves; inline paths
-        ride the thread-local binding instead."""
+        ride the thread-local binding instead.
+        trace_ids: per-body span-ring trace ids from the scheduler
+        (aligned with `bodies`): a coalesced envelope runs on the
+        scheduler's thread under a trace of its own and lists, on its
+        `envelope` and `dispatch` spans, the traces it serves."""
         if timelines is not None or not _FLIGHT.enabled \
                 or _FLIGHT.current() is not None:
             return self._multi_search_impl(
                 bodies, _bypass_request_cache, _raise_item_errors, task,
-                deadline, trace, phase_times, waves, timelines, tenants)
+                deadline, trace, phase_times, waves, timelines, tenants,
+                trace_ids)
         tl = _FLIGHT.timeline()
         if tl is None:      # disabled race: behave as the gate said
             return self._multi_search_impl(
@@ -2835,7 +2958,38 @@ class SearchExecutor:
                            phase_times: Optional[dict] = None,
                            waves: Optional[int] = None,
                            timelines: Optional[list] = None,
-                           tenants: Optional[list] = None) -> dict:
+                           tenants: Optional[list] = None,
+                           trace_ids: Optional[list] = None) -> dict:
+        """The envelope under its span: `envelope` in the always-on ring
+        runs from the envelope's `start` to its return (the read `took`
+        is computed from), closes on every exit with `envelope.parse`
+        beside it, and is the parent of the wave spans recorded below
+        it."""
+        ring_trace, sid, parent = _SPANS.enter()
+        env = _EnvelopeSpan(ring_trace, sid, trace_ids)
+        start = time.monotonic()
+        try:
+            return self._envelope(
+                bodies, start, env, _bypass_request_cache,
+                _raise_item_errors, task, deadline, trace, phase_times,
+                waves, timelines, tenants)
+        finally:
+            ring_trace.spans.append((
+                sid, parent, "envelope", start,
+                env.end or time.monotonic(),
+                (_envelope_attrs, len(bodies), env.waves, trace_ids)))
+            if env.parse is not None:
+                ring_trace.spans.append((sid + 1, sid, "envelope.parse",
+                                         env.parse[0], env.parse[1], None))
+            _SPANS.leave(ring_trace, parent)
+
+    def _envelope(self, bodies: List[dict], start: float,
+                  env: "_EnvelopeSpan", _bypass_request_cache: bool,
+                  _raise_item_errors: bool, task,
+                  deadline: Optional[float], trace,
+                  phase_times: Optional[dict], waves: Optional[int],
+                  timelines: Optional[list],
+                  tenants: Optional[list]) -> dict:
         TELEMETRY.metrics.counter("msearch.requests").inc()
         TELEMETRY.metrics.counter("msearch.bodies").inc(len(bodies))
         scope = _LEDGER.scope(trace)
@@ -2852,7 +3006,6 @@ class SearchExecutor:
         if fan_tls:
             for _ftl in fan_tls:
                 _ftl.route()
-        start = time.monotonic()
         if task is not None:
             task.check_cancelled()
         ph = dict.fromkeys(MSEARCH_PHASE_NAMES, 0.0)
@@ -2881,7 +3034,9 @@ class SearchExecutor:
                     # thread-local binding never reached
                     tenant=tenants[i] if tenants is not None else None))
 
-        ph["parse"] += time.monotonic() - _t
+        _t1 = time.monotonic()
+        ph["parse"] += _t1 - _t
+        env.parse = (_t, _t1)
         # Overlapped multi-wave dispatch: the batchable list splits into
         # power-of-two-bucketed waves; wave N+1's host work and async
         # dispatch run while wave N's device_get is in flight on the
@@ -2904,6 +3059,7 @@ class SearchExecutor:
                 wave_list.append(_MsearchWave(
                     "plain", [e[0] for e in chunk], chunk,
                     raise_errors=_raise_item_errors))
+        env.waves = len(wave_list)
         if wave_list:
             # mixed hybrid+plain envelopes have >1 waves structurally;
             # whether they OVERLAP still follows the wave-count policy
@@ -2918,7 +3074,7 @@ class SearchExecutor:
                 deadline=deadline, scope=scope,
                 resp_cache_keys=resp_cache_keys,
                 allow_pipeline=allow_pipeline, timeline=tl,
-                item_timelines=timelines, item_tenants=tenants)
+                item_timelines=timelines, item_tenants=tenants, env=env)
         # parse always runs; the wave phases only get a sample when a
         # batched wave actually executed — otherwise every all-general or
         # all-hybrid envelope would log spurious 0-ms device_get/respond
@@ -2964,7 +3120,24 @@ class SearchExecutor:
             ph_ms = {name: sec * 1000.0 for name, sec in ph.items()}
             for _ftl in fan_tls:
                 _ftl.merge_phases(ph_ms)
-        return {"took": int((time.monotonic() - start) * 1000),
+        env.end = time.monotonic()
+        if _raise_item_errors and len(bodies) == 1:
+            # a `_search` served through the B=1 envelope (the
+            # delegation from search() and the controller, which is
+            # what asks for raised errors) counts in the operator's
+            # search.* metrics as the general path's does
+            # (controller.execute_search), from the reads above: plan
+            # compile and the device round trip are its query phase,
+            # response assembly its render. `_msearch` batches do not.
+            _SEARCH_QUERIES.inc()
+            _SEARCH_TOOK.observe((env.end - start) * 1000.0)
+            hists = _SEARCH_PHASE_HISTS
+            hists["parse"].observe(ph["parse"] * 1000.0)
+            hists["query"].observe(
+                (ph["compile_group"] + ph["stack_pack_dispatch"]
+                 + ph["device_get"]) * 1000.0)
+            hists["render"].observe(ph["respond"] * 1000.0)
+        return {"took": int((env.end - start) * 1000),
                 "responses": responses}
 
     def _run_wave_pipeline(self, wave_list: List[_MsearchWave], responses,
@@ -2974,7 +3147,8 @@ class SearchExecutor:
                            allow_pipeline: bool = True,
                            timeline=None,
                            item_timelines: Optional[list] = None,
-                           item_tenants: Optional[list] = None) -> None:
+                           item_tenants: Optional[list] = None,
+                           env: Optional[_EnvelopeSpan] = None) -> None:
         """Drive the wave engine: prepare + async-dispatch each wave on
         THIS thread, collect on the collector thread (bounded in-flight
         window), and merge per-wave phase times, ledger scopes and
@@ -3049,6 +3223,14 @@ class SearchExecutor:
                     # BEFORE compiling/dispatching the next wave
                     wave.window = collector.acquire_slot()
                 wave.scope = LedgerScope() if scope is not None else None
+                if env is not None:
+                    # a scheduler-coalesced wave lists the traces
+                    # (requests) whose items it carries
+                    wave.span = (
+                        env.trace, env.span_id, next(_SPANS.ids), wave_idx,
+                        None if env.trace_ids is None else sorted(
+                            {env.trace_ids[i] for i in wave.items
+                             if env.trace_ids[i] is not None}))
                 wave.prep_t0 = time.monotonic()
                 if wave.kind == "hybrid":
                     wave.state = self._msearch_hybrid_prepare(
@@ -3058,9 +3240,20 @@ class SearchExecutor:
                     wave.state = self._msearch_prepare(
                         wave.payload, responses, start, wave.ph,
                         wave.raise_errors, deadline=deadline,
-                        scope=wave.scope)
+                        scope=wave.scope, span=wave.span)
                     wave.state["resp_cache_keys"] = resp_cache_keys or {}
                 wave.prep_t1 = time.monotonic()
+                if wave.kind == "hybrid" and wave.span is not None:
+                    # the hybrid halves keep no phase clock of their
+                    # own: the wave's prepare is its `dispatch` (pack
+                    # included), its collect its `device_wait`
+                    # (response assembly included)
+                    _trace, _eid, _base, _idx, _tids = wave.span
+                    _trace.spans.append((
+                        _base, _eid, "dispatch", wave.prep_t0,
+                        wave.prep_t1,
+                        (_hybrid_dispatch_attrs, _idx, _tids,
+                         len(wave.state["pending"]))))
                 # the in-flight gauges rise HERE (not inside prepare) so
                 # an exception out of prepare can never strand them; the
                 # collect path and the finally below are the two release
@@ -3269,6 +3462,11 @@ class SearchExecutor:
             wave.error = e
         finally:
             wave.collect_t1 = time.monotonic()
+            if wave.kind == "hybrid" and wave.span is not None:
+                _trace, _eid, _base, _idx, _tids = wave.span
+                _trace.spans.append((
+                    _base + 3, _eid, "device_wait", wave.collect_t0,
+                    wave.collect_t1, (_wave_attrs, _idx, _tids)))
             if wave.timeline is not None:
                 # collect lands on the owning request's lifecycle from
                 # THIS thread (appends are GIL-atomic; the timeline is
@@ -3687,9 +3885,17 @@ class SearchExecutor:
 
     def _msearch_prepare(self, batchable, responses, start, ph,
                          raise_item_errors: bool = False,
-                         deadline: Optional[float] = None, scope=None):
+                         deadline: Optional[float] = None, scope=None,
+                         span=None):
         """Wave half 1: compile + group + stack + pack + DISPATCH (async).
         Returns the state _msearch_finish consumes.
+
+        `span` is the wave's `_MsearchWave.span` for the always-on
+        ring: the phase boundaries below become
+        `envelope.compile_group`, `envelope.pack` (up to the first
+        upload) and `dispatch` (first upload to the return of the last
+        jit call: enqueue, not execution), and it rides the returned
+        state to the finish half.
 
         Template interning makes this phase O(unique (template, literals)
         pairs): interned bodies memoize their whole compiled bundle
@@ -3871,8 +4077,17 @@ class SearchExecutor:
                         str(getattr(self.reader, "shard_id", 0)),
                         _scan_rows, _scan_per_query)
         entry_by_i = {e[0]: e for e in batchable}
-        ph["compile_group"] += time.monotonic() - _t
-        _t = time.monotonic()
+        _t_pack = time.monotonic()
+        ph["compile_group"] += _t_pack - _t
+        # `dispatch` in the ring: its id is the open span for the loop
+        # below, so a compile that a first call pays lands under it
+        # (`xla.compile`, telemetry/kernels.py)
+        _t_dispatch = 0.0       # the first upload of the wave
+        span_programs = span_nbytes = 0
+        span_infos = []         # the executables' census records
+        if span is not None:
+            span_top = span[0].top
+            span[0].top = span[2]
         from opensearch_tpu.parallel.distributed import plan_struct
         # dispatch every group × segment program without blocking — jax
         # dispatch is async, so device work and transfers overlap.
@@ -3938,6 +4153,8 @@ class SearchExecutor:
                         if faults.ENABLED:
                             faults.fire("query.dispatch")
                         return fn(arrays, jnp.asarray(buf))
+                    if not _t_dispatch:
+                        _t_dispatch = time.monotonic()
                     out = retry.call_with_retry(_dispatch,
                                                 label="msearch.dispatch")
                 except Exception as e:  # except-ok: per-item isolation -- a runtime device fault downgrades only this group's items
@@ -3977,14 +4194,37 @@ class SearchExecutor:
                 # exception out of this loop can never strand bytes
                 wave_buffer_bytes += buf.nbytes
                 staging.append(buf)
+                if span is not None:
+                    span_programs += 1
+                    span_nbytes += buf.nbytes
+                    info = getattr(fn, "exec_info", None)
+                    if info is not None:
+                        span_infos.append(info)
                 # bm: whether this program's packed rows carry the extra
                 # pruned-count lane — MUST mirror _envelope_runner's
                 # admission (same predicate on the same plan/k)
                 pending.append((idxs, seg_i, k_seg, out, out_layout,
                                 agg_sig is None
                                 and _blockmax_admitted(plan0, k_seg)))
-        ph["stack_pack_dispatch"] += time.monotonic() - _t
+        _t_end = time.monotonic()
+        ph["stack_pack_dispatch"] += _t_end - _t_pack
+        if span is not None:
+            # the prepare half's spans; the finish half's two take the
+            # ids after these (`did` + 3, + 4)
+            trace, eid, did, wave_idx, wave_tids = span
+            trace.top = span_top
+            attrs = (_wave_attrs, wave_idx, wave_tids)
+            spans = [(did + 1, eid, "envelope.compile_group", _t,
+                      _t_pack, attrs),
+                     (did + 2, eid, "envelope.pack", _t_pack,
+                      _t_dispatch or _t_end, attrs)]
+            if _t_dispatch:
+                spans.append((did, eid, "dispatch", _t_dispatch, _t_end,
+                              (_dispatch_attrs, wave_idx, wave_tids,
+                               span_programs, span_nbytes, span_infos)))
+            trace.spans.extend(spans)
         return {"groups": groups, "entry_by_i": entry_by_i,
+                "span": span,
                 "pending": pending, "agg_by_i": agg_by_i,
                 "agg_nodes_by_i": agg_nodes_by_i, "dead": dead,
                 "staging": staging,
@@ -4075,8 +4315,18 @@ class SearchExecutor:
                         for i in idxs:
                             responses[i] = dict(err)
                             dead.add(i)
-        collect_s = time.monotonic() - _t
-        ph["device_get"] += collect_s; _t = time.monotonic()
+        _t_got = time.monotonic()
+        collect_s = _t_got - _t
+        ph["device_get"] += collect_s
+        span = state.get("span")
+        if span is not None:
+            # the blocking fetch of the wave's rows, and whether a
+            # program of its own (concat_rows) ran inside it
+            span[0].spans.append((
+                span[2] + 3, span[1], "device_wait", _t, _t_got,
+                (_wait_attrs, span[3], span[4], fetch_stats[0],
+                 int(len(pending) > 1))))
+        _t = _t_got
         _release_wave_gauges(state)
         if scope is not None:
             _ledger_packed_rows(scope, pending, fetched, fetch_stats[0],
@@ -4236,7 +4486,12 @@ class SearchExecutor:
                     _request_cache(), key,
                     (per_query_total[i], per_query_decoded.get(i),
                      agg_nodes_by_i.get(i)))
-        ph["respond"] += time.monotonic() - _t
+        _t_end = time.monotonic()
+        ph["respond"] += _t_end - _t
+        if span is not None:
+            span[0].spans.append((
+                span[2] + 4, span[1], "respond", _t, _t_end,
+                (_wave_attrs, span[3], span[4])))
 
     def _render_cached_msearch(self, cached, start: float) -> dict:
         """Build a fresh response from a cached (total, decoded partials,

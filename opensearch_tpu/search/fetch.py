@@ -382,10 +382,10 @@ def _eval_child_scores(plan, arrays):
     sig = ("inner_hits", plan.sig())
     fn = _INNER_JIT.get(sig)
     if fn is None:
-        def run(seg, flat, _plan=plan):
+        def inner_hits(seg, flat, _plan=plan):  # names jit_inner_hits
             cursor = [0]
             return _eval_plan(_plan, seg, flat, cursor)
-        fn = _INNER_JIT[sig] = jax.jit(run)  # shared-state-ok: benign double-jit race; dict slot write is GIL-atomic
+        fn = _INNER_JIT[sig] = jax.jit(inner_hits)  # shared-state-ok: benign double-jit race; dict slot write is GIL-atomic
     host_flat = plan.flatten_inputs([])
     ledger = TELEMETRY.ledger
     # scope: the request's LedgerScope, bound ambiently by the

@@ -158,7 +158,8 @@ class _SchedItem:
     unit — queue bookkeeping stays O(1) per envelope)."""
 
     __slots__ = ("target", "bodies", "deadline", "timeline", "tenant",
-                 "task", "enq_t", "done", "responses", "error", "shed")
+                 "task", "enq_t", "done", "responses", "error", "shed",
+                 "trace_id")
 
     def __init__(self, target, bodies, deadline, timeline, tenant, task,
                  enq_t):
@@ -169,6 +170,12 @@ class _SchedItem:
         self.tenant = tenant
         self.task = task
         self.enq_t = enq_t
+        # the submitting request's trace in the always-on span ring
+        # (telemetry/tracer.py): the shared envelope runs on the
+        # scheduler's thread and lists the traces it serves
+        from opensearch_tpu.telemetry import TELEMETRY
+        trace = TELEMETRY.tracer.spans.current()
+        self.trace_id = trace.trace_id if trace is not None else None
         self.done = threading.Event()
         self.responses: Optional[List[dict]] = None
         self.error: Optional[BaseException] = None
@@ -564,11 +571,17 @@ class WaveScheduler:
         tenants = [item.tenant for item in live
                    for _ in item.bodies] \
             if TELEMETRY.insights.enabled else None
+        # the span ring's traces the shared envelope serves, per body;
+        # passed only where a request opened one (the HTTP path)
+        traced = {}
+        if any(item.trace_id is not None for item in live):
+            traced["trace_ids"] = [item.trace_id for item in live
+                                   for _ in item.bodies]
         t0 = time.monotonic()
         try:
             res = live[0].target.multi_search(
                 bodies, deadline=group_deadline, timelines=timelines,
-                phase_times=pt, tenants=tenants)
+                phase_times=pt, tenants=tenants, **traced)
             responses = res["responses"]
         except BaseException as e:  # except-ok: waiter wakeup -- a dispatch failure delivers the error to every blocked request thread instead of stranding them on the Event
             for item in live:

@@ -9,13 +9,28 @@ deliberate departures, both forced by this build's execution model:
   one device program on one thread, so ambient context would attribute
   every sub-request's device work to whichever request happened to be
   "current".
-- Spans time with `time.perf_counter_ns()` and close via context manager
+- Spans time with `time.monotonic_ns()` and close via context manager
   (`with span.child("phase"):`), so failure paths — exceptions,
   backpressure rejections — still close every opened span.
 
 When tracing is disabled (the default), `start_trace` returns a shared
 NOOP span whose every method is a constant-time no-op — the query path
 pays a couple of attribute loads, nothing else.
+
+Beside the verbose tree sits the ALWAYS-ON span ring (`SpanRing`,
+`Tracer.spans`): flat completed records `(trace_id, span_id, parent_id,
+name, start_ns, end_ns, attributes)` at the layer boundaries of the
+served path (http.request -> rest.search -> envelope -> envelope.parse /
+compile_group / pack, dispatch, device_wait, respond). It is fed from
+the clock reads the always-on histograms already make, so a request
+costs a handful of tuple appends, one row in the ring and no lock; it
+is what
+`GET /_telemetry/spans` serves and what the benchmark's per-layer
+metrics read. Both forms are on `time.monotonic_ns()`, the clock of
+`time.monotonic()` on Linux, so a span lies on a client's samples
+without conversion; the export carries one `(monotonic_ns, time_ns)`
+pair for wall time. `telemetry.tracing.enabled` gates only the verbose
+tree.
 
 Completed root spans land in a bounded in-memory ring buffer served by
 `GET /_telemetry/traces` and, when configured with a data dir, are
@@ -25,30 +40,178 @@ appended as JSONL under `_state/traces.jsonl` for offline analysis
 
 from __future__ import annotations
 
+import itertools
 import json
-import os
 import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
 DEFAULT_RING_SIZE = 256
+# requests kept. A served request is at least eight spans (http.request
+# and its two halves, rest.*, envelope, and five a wave), so the ring
+# holds 65,536 spans and more; the 30 s window of the busiest queued
+# benchmark cell is 140 requests/s = 4,200 requests
+SPAN_RING_SIZE = 8192
+
+
+class Trace:
+    """One request's timeline while it is served: the id its spans
+    share, the id of the span open on the serving thread (`top`: the
+    parent of what starts next), and the completed spans, each
+    `(span_id, parent_id, name, start, end, attributes)`."""
+
+    __slots__ = ("trace_id", "top", "spans")
+
+    def __init__(self, trace_id: int):
+        self.trace_id = self.top = trace_id
+        self.spans: List[tuple] = []
+
+
+class SpanRing:
+    """Bounded ring of completed requests' flat span records, always on.
+
+    A request costs ONE row: the outermost span that starts on a thread
+    (`enter`: `http.request`; `rest.search` or `envelope` for a caller
+    that came in below HTTP) makes a `Trace`, every layer below appends
+    its completed spans to `trace.spans` from clock reads it already
+    made for a histogram, and the outermost span's `leave` puts the
+    trace into the ring: a list append a span, a deque append a
+    request, both atomic under the interpreter lock. No lock is taken
+    and none is held across a device call. A request still being served
+    is not in the ring yet.
+
+    A span is `(span_id, parent_id, name, start, end, attributes)`. A
+    time is `time.monotonic_ns()` as read, or `time.monotonic()` seconds
+    as read; `attributes` is None, a dict, or `(build, *arguments)` with
+    `build(*arguments)` the dict: the export converts and builds, off
+    the serving path. The ring drops its oldest request when full and
+    `dropped` says how many (the count's `+= 1` is not atomic: racing
+    writers can count one for two, so it is exact when writers do not
+    overlap and a close lower bound otherwise).
+
+    The trace reaches the layers below as a thread-local binding
+    (`current`), the way the lifecycle timeline does: one HTTP request
+    is served on one thread. Where the work changes thread (the wave
+    collector, the wave scheduler) the trace rides the wave or the
+    queued item. The items of a batch get no spans of their own, so the
+    objection to ambient context in the module docstring (B
+    sub-requests in one program) does not apply.
+
+    Ids come eight apart (`ids`): a span that closes with leaf children
+    names them `its id + 1 .. + 7` without another draw."""
+
+    def __init__(self, size: int = SPAN_RING_SIZE):
+        self._ring: "deque[Trace]" = deque(maxlen=size)
+        self.ids = itertools.count(8, 8)
+        self._put = 0               # requests completed since `clear`
+        self._local = threading.local()
+
+    # ------------------------------------------------------------- writing
+
+    def current(self) -> Optional[Trace]:
+        """The request being served on this thread."""
+        return getattr(self._local, "trace", None)
+
+    def enter(self):
+        """A span that has children starts on this thread: `(the
+        request's trace, the span's id, its parent's id)`. With no
+        request open it is the root (parent 0) of a new trace. The
+        caller appends the span to `trace.spans` when it ends, then
+        calls `leave`."""
+        trace = getattr(self._local, "trace", None)
+        span_id = next(self.ids)
+        if trace is None:
+            self._local.trace = trace = Trace(span_id)
+            return trace, span_id, 0
+        parent_id = trace.top
+        trace.top = span_id
+        return trace, span_id, parent_id
+
+    def leave(self, trace: Trace, parent_id: int) -> None:
+        """The span `enter` began has ended: its parent is the open span
+        again, and the root's end puts the request into the ring."""
+        if parent_id:
+            trace.top = parent_id
+        else:
+            self._local.trace = None
+            self._put += 1
+            self._ring.append(trace)
+
+    def child(self, name: str, start, end, attributes=None) -> None:
+        """A completed leaf span under the span open on this thread;
+        nothing when no request is open (a direct library caller)."""
+        trace = getattr(self._local, "trace", None)
+        if trace is not None:
+            trace.spans.append((next(self.ids), trace.top, name, start,
+                                end, attributes))
+
+    # ------------------------------------------------------------- reading
+
+    @property
+    def dropped(self) -> int:
+        """Requests the ring pushed out since `clear`."""
+        return max(self._put - len(self._ring), 0)
+
+    def export(self, since_ns: Optional[int] = None,
+               until_ns: Optional[int] = None) -> dict:
+        """The `GET /_telemetry/spans` body: every span of a completed
+        request that ends at or after `since_ns` and starts at or
+        before `until_ns`."""
+        traces = self._ring.copy()  # one C call: atomic under the GIL
+        out = []
+        for trace in traces:
+            for span_id, parent_id, name, t0, t1, attrs in \
+                    trace.spans.copy():
+                if type(t0) is not int:
+                    t0 = int(t0 * 1e9)
+                if type(t1) is not int:
+                    t1 = int(t1 * 1e9)
+                if (since_ns is not None and t1 < since_ns) \
+                        or (until_ns is not None and t0 > until_ns):
+                    continue
+                row = {"trace_id": trace.trace_id, "span_id": span_id,
+                       "parent_id": parent_id, "name": name,
+                       "start_ns": t0, "end_ns": t1}
+                if type(attrs) is tuple:
+                    attrs = attrs[0](*attrs[1:])
+                if attrs:
+                    row["attributes"] = attrs
+                out.append(row)
+        return {"clock": "monotonic_ns",
+                "anchor": {"monotonic_ns": time.monotonic_ns(),
+                           "time_ns": time.time_ns()},
+                "dropped": self.dropped, "spans": out}
+
+    def clear(self) -> None:
+        self._ring.clear()
+        self._put = 0
+
+    def stats(self) -> dict:
+        retained = len(self._ring)
+        return {"size": self._ring.maxlen, "retained": retained,
+                "recorded": max(self._put, retained),
+                "dropped": self.dropped}
 
 
 class Span:
     """One timed operation. `children` nest; attributes are flat K/V."""
 
     __slots__ = ("name", "attributes", "children", "start_ns", "end_ns",
-                 "status", "error")
+                 "status", "error", "trace_id")
 
     recording = True
 
-    def __init__(self, name: str, attributes: Optional[dict] = None):
+    def __init__(self, name: str, attributes: Optional[dict] = None,
+                 trace_id: Optional[int] = None):
         self.name = name
         self.attributes: Dict[str, Any] = dict(attributes) \
             if attributes else {}
         self.children: List["Span"] = []
-        self.start_ns = time.perf_counter_ns()
+        # the ring's trace id where the request has one, so the tree and
+        # the flat records of one request can be laid side by side
+        self.trace_id = trace_id
+        self.start_ns = time.monotonic_ns()
         self.end_ns: Optional[int] = None
         self.status = "ok"
         self.error: Optional[str] = None
@@ -56,7 +219,7 @@ class Span:
     # ------------------------------------------------------------- lifecycle
 
     def child(self, name: str, **attributes) -> "Span":
-        s = Span(name, attributes)
+        s = Span(name, attributes, trace_id=self.trace_id)
         self.children.append(s)
         return s
 
@@ -66,7 +229,7 @@ class Span:
     def end(self, status: Optional[str] = None,
             error: Optional[BaseException] = None) -> None:
         if self.end_ns is None:
-            self.end_ns = time.perf_counter_ns()
+            self.end_ns = time.monotonic_ns()
         if error is not None:
             self.status = "error"
             self.error = f"{type(error).__name__}: {error}"
@@ -84,15 +247,19 @@ class Span:
 
     def duration_ns(self) -> int:
         end = self.end_ns if self.end_ns is not None \
-            else time.perf_counter_ns()
+            else time.monotonic_ns()
         return end - self.start_ns
 
     def to_dict(self) -> dict:
         out: Dict[str, Any] = {
             "name": self.name,
             "duration_ms": round(self.duration_ns() / 1e6, 3),
+            "start_ns": self.start_ns,
+            "end_ns": self.end_ns,
             "status": self.status,
         }
+        if self.trace_id is not None:
+            out["trace_id"] = self.trace_id
         if self.error is not None:
             out["error"] = self.error
         if self.attributes:
@@ -142,6 +309,8 @@ class Tracer:
 
     def __init__(self, ring_size: int = DEFAULT_RING_SIZE):
         self.enabled = False
+        # the always-on flat span ring (not gated by `enabled`)
+        self.spans = SpanRing()
         self._ring: "deque[dict]" = deque(maxlen=ring_size)
         self._lock = threading.Lock()
         # separate lock for file appends: a slow disk must not block
@@ -167,7 +336,9 @@ class Tracer:
             # reach finish(); counting them would make started/finished
             # read as leaked spans
             self.started += 1
-        return Span(name, attributes)
+        ctx = self.spans.current()
+        return Span(name, attributes,
+                    trace_id=ctx.trace_id if ctx is not None else None)
 
     def finish(self, span) -> None:
         """Close a root span and retain it (ring + optional JSONL).
@@ -223,4 +394,5 @@ class Tracer:
         return {"enabled": self.enabled, "started": self.started,
                 "finished": self.finished, "retained": retained,
                 "ring_size": maxlen, "jsonl_path": self.jsonl_path,
-                "export_errors": self.export_errors}
+                "export_errors": self.export_errors,
+                "spans": self.spans.stats()}

@@ -42,7 +42,9 @@ SCALAR_CASTS = {"int", "float", "bool"}
 
 
 def _is_jit_func(node: ast.expr) -> bool:
-    return name_of(node) in ("jax.jit", "jit")
+    # jit_family(fn, family) is jax.jit(fn) under the family's name
+    # (telemetry/kernels.py): the same first argument, the same checks
+    return name_of(node) in ("jax.jit", "jit", "jit_family")
 
 
 def _static_names(call: Optional[ast.Call], fn) -> Set[str]:
