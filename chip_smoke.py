@@ -829,6 +829,25 @@ class Smoke:
 
     # -------------------------------------------------------------- checks
 
+    def norm_decode(self):
+        """The kernels build a posting's length from its norm byte by
+        assembling an f32 from its fields (ops/bm25.py posting_lengths).
+        Whether that equals Lucene's LENGTH_TABLE for all 256 bytes, bit
+        for bit, is a property of the device: checked here, on it."""
+        import jax.numpy as jnp
+        from opensearch_tpu.index.segment import LENGTH_TABLE
+        from opensearch_tpu.ops.bm25 import posting_lengths
+        seg = {"post_norm": jnp.arange(256, dtype=jnp.uint8).reshape(2, 128)}
+        got = np.asarray(self.jax.jit(posting_lengths)(
+            seg, jnp.arange(2, dtype=jnp.int32))).ravel()
+        wrong = np.flatnonzero(got.view(np.int32)
+                               != LENGTH_TABLE.view(np.int32))
+        require(wrong.size == 0,
+                f"norm bytes {wrong[:8].tolist()} decode to "
+                f"{got[wrong[:8]].tolist()}, LENGTH_TABLE has "
+                f"{LENGTH_TABLE[wrong[:8]].tolist()}")
+        return {"bytes_checked": 256}
+
     def node_checks(self):
         """What the product's fault tolerance would otherwise absorb."""
         n = self.client.node_stats()
@@ -940,7 +959,8 @@ def main(argv=None) -> int:
                          "served_terms_agg", "served_date_histogram",
                          "served_sorted_and_ties", "scale_load",
                          "scale_match", "vectors_load", "vectors_knn",
-                         "sharded_load", "sharded_bodies", "node_checks"):
+                         "sharded_load", "sharded_bodies", "norm_decode",
+                         "node_checks"):
                 smoke.phase(name, getattr(smoke, name))
     finally:
         smoke.stop()

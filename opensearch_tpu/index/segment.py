@@ -11,8 +11,10 @@ Reference behaviors re-designed here:
   dense, MXU/VPU-friendly batch computation.
 - Lucene norms (SmallFloat-encoded doc lengths used by BM25Similarity) are kept
   bit-identical: `smallfloat_int_to_byte4` mirrors Lucene's
-  `SmallFloat.intToByte4`, and scoring decodes through a 256-entry length
-  table, so BM25 scores match Lucene's to float precision.
+  `SmallFloat.intToByte4`, and scoring decodes each byte to the value of
+  Lucene's 256-entry length table (on the device arithmetically, from the
+  byte carried beside each posting: `posting_norms`), so BM25 scores match
+  Lucene's to float precision.
 - Doc values (reference: index/fielddata/) become value-pair columns
   `(doc_ids[int32], values[float64])` per field — the scatter/segment-sum
   friendly layout for aggregations — plus a dense `exists` bitmap per field.
@@ -124,6 +126,45 @@ SEAL_B = 0.75
 _BOUNDS_CHUNK_ROWS = 1 << 16    # bound host memory on multi-GB postings
 
 
+def _field_block_rows(seg: "Segment") -> Dict[str, np.ndarray]:
+    """field → the posting-block rows of its terms (int64, run by run): a
+    (field, term) entry of the term dict owns one contiguous run of blocks,
+    so every real block belongs to exactly one field."""
+    runs: Dict[str, List[np.ndarray]] = {}
+    for (field, _term), tm in seg.term_dict.items():
+        if tm.num_blocks:
+            runs.setdefault(field, []).append(
+                np.arange(tm.start_block, tm.start_block + tm.num_blocks,
+                          dtype=np.int64))
+    return {field: np.concatenate(r) for field, r in runs.items()}
+
+
+def posting_norms(seg: "Segment") -> np.ndarray:
+    """The SmallFloat norm byte of every posting, uint8 [NB, BLOCK] lane for
+    lane with `post_docs`/`post_tf`: `seg.norms[field of block b][post_docs[b,
+    l]]`. 0 in padding lanes and in blocks of fields without norms (those
+    score with b=0, which multiplies the decoded length away).
+
+    A posting's norm is fixed when the segment is sealed, so the scoring
+    kernels read it beside the tf (ops/bm25.py `posting_lengths`) instead of
+    gathering `norms[doc]` per query and per lane. Derived at upload from
+    what the segment already holds; not memoized (one byte a lane of host
+    memory for something read once).
+    """
+    out = np.zeros(seg.post_docs.shape, dtype=np.uint8)
+    for field, rows in _field_block_rows(seg).items():
+        norm = seg.norms.get(field)
+        if norm is None:
+            continue
+        for lo in range(0, len(rows), _BOUNDS_CHUNK_ROWS):
+            chunk = rows[lo:lo + _BOUNDS_CHUNK_ROWS]
+            docs = seg.post_docs[chunk]
+            vals = np.take(norm, docs, mode="clip")     # -1 reads doc 0
+            vals[docs < 0] = 0
+            out[chunk] = vals
+    return out
+
+
 def block_score_bounds(seg: "Segment") -> np.ndarray:
     """Per-posting-block BM25 score upper bounds: max over the block's lanes
     of tf/(tf + SEAL_K1·(1−SEAL_B+SEAL_B·dl/avgdl)), f32 [NB].
@@ -143,15 +184,9 @@ def block_score_bounds(seg: "Segment") -> np.ndarray:
         return cached
     nb = seg.post_docs.shape[0]
     bounds = np.zeros(nb, dtype=np.float32)
-    # group the term dict's contiguous block runs by field: the denominator
-    # constant c(dl) = 1−b+b·dl/avgdl is a per-field per-doc vector
-    field_rows: Dict[str, List[np.ndarray]] = {}
-    for (field, _term), tm in seg.term_dict.items():
-        if tm.num_blocks:
-            field_rows.setdefault(field, []).append(
-                np.arange(tm.start_block, tm.start_block + tm.num_blocks,
-                          dtype=np.int64))
-    for field, runs in field_rows.items():
+    # the denominator constant c(dl) = 1−b+b·dl/avgdl is a per-field
+    # per-doc vector
+    for field, rows in _field_block_rows(seg).items():
         norm = seg.norms.get(field)
         stats = seg.field_stats.get(field)
         if norm is not None and stats is not None and stats.doc_count > 0:
@@ -160,7 +195,6 @@ def block_score_bounds(seg: "Segment") -> np.ndarray:
             c_doc = (1.0 - SEAL_B + SEAL_B * dl / avgdl).astype(np.float32)
         else:
             c_doc = None        # omit-norms field: c ≡ 1
-        rows = np.concatenate(runs)
         for lo in range(0, len(rows), _BOUNDS_CHUNK_ROWS):
             chunk = rows[lo:lo + _BOUNDS_CHUNK_ROWS]
             docs = seg.post_docs[chunk]

@@ -12,8 +12,10 @@ doc equals the number of distinct clause terms that matched).
 Score parity: idf = ln(1 + (docCount - df + 0.5)/(df + 0.5)) per
 LegacyBM25Similarity (reference: index/similarity/SimilarityService.java:85 —
 OpenSearch's default keeps the (k1+1) numerator factor), doc length decoded
-from SmallFloat-quantized norms through the 256-entry LENGTH_TABLE, and
-avgdl = sumTotalTermFreq / docCount, all matching Lucene to float precision.
+from the SmallFloat norm byte that rides beside each posting's tf
+(`posting_lengths`: the values of Lucene's 256-entry LENGTH_TABLE, computed,
+not looked up), and avgdl = sumTotalTermFreq / docCount, all matching Lucene
+to float precision.
 """
 
 from __future__ import annotations
@@ -51,6 +53,27 @@ _MIN_SCORE_OFF = -1e30
 def idf(doc_count: int, doc_freq: int) -> float:
     """Lucene BM25Similarity.idfExplain."""
     return math.log(1.0 + (doc_count - doc_freq + 0.5) / (doc_freq + 0.5))
+
+
+def posting_lengths(seg, block_ids):
+    """Doc length of every posting in the gathered blocks, f32 [..., 128].
+
+    seg["post_norm"] holds each posting's SmallFloat norm byte lane for lane
+    with post_docs/post_tf, so this is one more row gather with the ids the
+    caller already gathers docs and tfs with — no per-lane gather from a
+    [d_pad] norms row, no table lookup. The decode is
+    index/segment.py `smallfloat_byte4_to_int` exactly: with bits = n & 7 and
+    e = n >> 3, the length is bits where e == 0, else (bits | 8) << (e - 1)
+    = 1.bbb × 2^(e+2). The top bytes overflow int32 and exp2 is not promised
+    exact, so the f32 is assembled from its fields: exponent e + 129,
+    mantissa bits << 20 — a normal number for every e >= 1.
+    """
+    n = seg["post_norm"][block_ids].astype(jnp.int32)
+    bits = n & 7
+    e = n >> 3
+    scaled = jax.lax.bitcast_convert_type(
+        ((e + 129) << 23) | (bits << 20), jnp.float32)
+    return jnp.where(e == 0, bits.astype(jnp.float32), scaled)
 
 
 def blockmax_keep_mask(seg, blk, k1, n_terms, k, min_score=None):
@@ -102,10 +125,9 @@ def _blockmax_keep_mask(seg, blk, k1, n_terms, k, min_score):
     s_real = lane_real[sidx]                               # [S]
     docs = seg["post_docs"][safe_ids[sidx]]                # [S, 128]
     tfs = seg["post_tf"][safe_ids[sidx]]
+    dl = posting_lengths(seg, safe_ids[sidx])
     valid = (docs >= 0) & s_real[:, None]
     safe_docs = jnp.where(valid, docs, 0)
-    norm_bytes = seg["norms"][blk["row"]][safe_docs]
-    dl = seg["length_table"][norm_bytes]
     b = blk["b"]
     denom = tfs + k1 * (1.0 - b + b * dl / blk["avgdl"])
     partial = blk["w"][sidx][:, None] * tfs * (k1 + 1.0) / denom
@@ -143,12 +165,11 @@ def _blockmax_keep_mask(seg, blk, k1, n_terms, k, min_score):
 def score_text_clause(seg, blk, k1, block_keep=None):
     """Score one text clause (match / term / terms over one field family).
 
-    seg: device segment dict (post_docs, post_tf, norms, length_table).
+    seg: device segment dict (post_docs, post_tf, post_norm, live).
     blk: per-block gathered inputs:
-      - ids:    int32 [QB] block row indices into post_docs/post_tf
+      - ids:    int32 [QB] block row indices into post_docs/post_tf/post_norm
                 (power-of-two bucketed; -1 = padding lane)
       - w:      float32 [QB] idf * boost * multiplicity for the block's term
-      - row:    int32 scalar norms-stack row of the clause's field
       - avgdl:  float32 scalar average field length for the clause's field
       - b:      float32 scalar BM25 b (0 for norm-less keyword fields,
                 matching Lucene's omit-norms denominator tf + k1)
@@ -174,10 +195,8 @@ def score_text_clause(seg, blk, k1, block_keep=None):
         safe_ids = jnp.where(lane_real, blk["ids"], 0)
         docs = seg["post_docs"][safe_ids]            # [QB, 128]
         tfs = seg["post_tf"][safe_ids]               # [QB, 128]
+        dl = posting_lengths(seg, safe_ids)          # [QB, 128]
         valid = docs >= 0
-        safe_docs = jnp.where(valid, docs, 0)
-        norm_bytes = seg["norms"][blk["row"]][safe_docs]          # [QB, 128]
-        dl = seg["length_table"][norm_bytes]
     with stage("bm25_score"):
         b = blk["b"]
         denom = tfs + k1 * (1.0 - b + b * dl / blk["avgdl"])

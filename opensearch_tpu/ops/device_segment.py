@@ -7,9 +7,13 @@ power-of-two buckets so differently-sized segments reuse the same compiled
 executable (XLA recompiles per shape — bucketing bounds the compile count).
 
 Layout:
-- `post_docs`/`post_tf`: the global blocked postings matrices `[NBp, 128]`.
-- `norms`: stacked `[F, Dp]` uint8 SmallFloat norms, one row per indexed text
-  field (row index assigned in `DeviceSegmentMeta.norm_rows`).
+- `post_docs`/`post_tf`/`post_norm`: the global blocked postings matrices
+  `[NBp, 128]` — doc id (int32, -1 padded), term frequency (f32) and the
+  SmallFloat norm byte (uint8) of that doc in the block's field, lane for
+  lane, so BM25 reads a posting's length where it reads its tf.
+- `norms`: stacked `[F, Dp]` int32 SmallFloat norm bytes, one row per indexed
+  text field (row index assigned in `DeviceSegmentMeta.norm_rows`); read by
+  `exists` on a text field only — scoring reads `post_norm`.
 - numeric doc values per field: `(doc_ids, val_ords, values_f32)` value-pair
   arrays (pad doc_id = -1) + dense `exists`, `min_rank`/`max_rank` per doc for
   sorting and can-match pruning.
@@ -27,8 +31,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from opensearch_tpu.index.segment import (LENGTH_TABLE, Segment,
-                                          block_score_bounds, pad_bucket)
+from opensearch_tpu.index.segment import (Segment, block_score_bounds,
+                                          pad_bucket, posting_norms)
 
 INT32_MAX = np.int32(2 ** 31 - 1)
 _F32_MAX = float(np.finfo(np.float32).max)
@@ -105,6 +109,9 @@ def upload_segment(seg: Segment, to_device: bool = True):
     post_docs[:nb] = seg.post_docs
     post_tf = np.zeros((nb_pad, seg.post_tf.shape[1]), dtype=np.float32)
     post_tf[:nb] = seg.post_tf
+    # each posting's norm byte beside its tf; padding blocks and lanes 0
+    post_norm = np.zeros(post_docs.shape, dtype=np.uint8)
+    post_norm[:nb] = posting_norms(seg)
     # seal-time per-block score upper bounds (block-max pruning, ISSUE 20):
     # [nb_pad] f32 next to the block matrices; padding blocks bound 0
     post_bound = np.zeros(nb_pad, dtype=np.float32)
@@ -135,9 +142,9 @@ def upload_segment(seg: Segment, to_device: bool = True):
     arrays: Dict = {
         "post_docs": post_docs,
         "post_tf": post_tf,
+        "post_norm": post_norm,
         "post_bound": post_bound,
         "norms": norms,
-        "length_table": LENGTH_TABLE,
         "live": live,
         "root": root,
         "parent_ptr": parent_ptr,
@@ -261,7 +268,7 @@ def tree_nbytes(tree) -> int:
 def _compact_spec(seg: Segment, meta: DeviceSegmentMeta) -> Dict[tuple, tuple]:
     """Tree-path → ((compact extents, None = full axis), pad fill) for
     every leaf whose padded tail is a constant fill. Leaves absent from
-    the spec (length_table, ivf_* packings) transfer in full."""
+    the spec (ivf_* packings, PQ codebooks) transfer in full."""
     nd = seg.num_docs
     nb = seg.post_docs.shape[0]
     # postings width is sized to the DOC pad bucket by the builder, but
@@ -271,6 +278,7 @@ def _compact_spec(seg: Segment, meta: DeviceSegmentMeta) -> Dict[tuple, tuple]:
     spec: Dict[tuple, tuple] = {
         ("post_docs",): ((nb, nd), -1),
         ("post_tf",): ((nb, nd), 0.0),
+        ("post_norm",): ((nb, nd), 0),
         ("post_bound",): ((nb,), 0.0),
         ("norms",): ((None, nd), 0),
         ("live",): ((nd,), False),

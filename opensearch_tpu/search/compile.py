@@ -753,14 +753,15 @@ class Compiler:
         if cached is not None:
             return cached
         ft = self.mapper.get_field(field)
-        row = meta.norm_row(field)
-        has_norms = ft is not None and ft.is_text and row is not None
+        has_norms = ft is not None and ft.is_text \
+            and meta.norm_row(field) is not None
         b_eff = b if has_norms else 0.0
         avgdl = self.stats.avgdl(field)
         # per-lane data is only (block id, weight); the clause constants
-        # (norms row, avgdl, b) are scalars — one field per clause — which
-        # shrinks both compile work and the msearch envelope bytes that
-        # cross the host↔device link per query
+        # (avgdl, b) are scalars — one field per clause — which shrinks
+        # both compile work and the msearch envelope bytes that cross the
+        # host↔device link per query. The field's norms travel with its
+        # posting blocks (device image `post_norm`), so no norms row is named
         ids, ws, tids = [], [], []
         for t_i, (term, w) in enumerate(weighted_terms):
             tm = seg.get_term(field, term)
@@ -775,7 +776,6 @@ class Compiler:
         inputs = {
             "ids": _i32(ids + [-1] * pad),    # -1 = padding lane (no hit)
             "w": _f32(ws + [0.0] * pad),
-            "row": _i32(row if has_norms else 0),
             "avgdl": _f32(avgdl if avgdl > 0 else 1.0),
             "b": _f32(b_eff),
             "k1": _f32(k1),

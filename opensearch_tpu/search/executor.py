@@ -44,8 +44,8 @@ from opensearch_tpu.index.mapper import MapperService
 from opensearch_tpu.index.segment import Segment, pad_bucket
 from opensearch_tpu.ops import bm25 as _bm25
 from opensearch_tpu.ops.bm25 import (
-    blockmax_keep_mask, ordinal_terms_match, range_match_on_ranks,
-    score_text_clause)
+    blockmax_keep_mask, ordinal_terms_match, posting_lengths,
+    range_match_on_ranks, score_text_clause)
 from opensearch_tpu.ops import device_segment as _devseg
 from opensearch_tpu.ops.device_segment import (
     DeviceSegmentMeta, refresh_live, tree_nbytes, upload_segment)
@@ -1475,10 +1475,8 @@ def build_candidate_query_phase(plan: Plan, meta: DeviceSegmentMeta, k: int,
             safe_ids = jnp.where(lane_real, my["ids"], 0)
             docs = seg["post_docs"][safe_ids]             # [QB, 128]
             tfs = seg["post_tf"][safe_ids]
+            dl = posting_lengths(seg, safe_ids)
             valid = docs >= 0
-            safe_docs = jnp.where(valid, docs, 0)
-            norm_bytes = seg["norms"][my["row"]][safe_docs]
-            dl = seg["length_table"][norm_bytes]
         with _stage("bm25_score"):
             b = my["b"]
             k1 = my["k1"]

@@ -197,14 +197,13 @@ def test_blockmax_mask_is_a_stage_where_it_is_compiled_in():
     from opensearch_tpu.ops import bm25
     seg = {"post_bound": jnp.ones(4), "post_docs": jnp.zeros((4, 128),
                                                              jnp.int32),
-           "post_tf": jnp.ones((4, 128)), "norms": jnp.zeros((1, 256),
-                                                             jnp.uint8),
-           "length_table": jnp.ones(256), "live": jnp.ones(256, bool),
-           "root": jnp.ones(256, bool)}
+           "post_tf": jnp.ones((4, 128)),
+           "post_norm": jnp.zeros((4, 128), jnp.uint8),
+           "live": jnp.ones(256, bool), "root": jnp.ones(256, bool)}
     blk = {"ids": jnp.arange(4, dtype=jnp.int32),
            "tid": jnp.zeros(4, jnp.int32), "bscale": jnp.ones(4),
            "w": jnp.ones(4), "b": jnp.float32(0.75),
-           "avgdl": jnp.float32(8.0), "row": jnp.int32(0),
+           "avgdl": jnp.float32(8.0),
            "min_hits": jnp.int32(1)}
 
     def run(seg, blk):
