@@ -77,8 +77,9 @@ class Corpus:
 
     # ------------------------------------------------------------- oracle
 
-    def judge(self, pairs: list) -> list:
-        """[(query, response)] -> one message a page that differs."""
+    def judge(self, pairs: list, seen: dict = None) -> list:
+        """[(query, response)] -> one message a page that differs;
+        `seen` keeps what was compared (oracle.check_page)."""
         if not pairs:
             return []
         q = np.stack([p[0].vector for p in pairs]).astype(np.float64)
@@ -92,7 +93,7 @@ class Corpus:
         bad = []
         for j, (query, resp) in enumerate(pairs):
             try:
-                self._judge_one(query, resp, d2[:, j])
+                self._judge_one(query, resp, d2[:, j], seen)
             except oracle.Mismatch as e:
                 bad.append(str(e))
         return bad
@@ -101,7 +102,7 @@ class Corpus:
         diff = self.x[ords].astype(np.float64) - qv
         return (diff * diff).sum(axis=1)
 
-    def _judge_one(self, query, resp, d2_col) -> None:
+    def _judge_one(self, query, resp, d2_col, seen=None) -> None:
         what = "knn"
         oracle.check_clean(resp, what)
         qv = query.vector.astype(np.float64)
@@ -114,7 +115,8 @@ class Corpus:
             what, resp["hits"]["hits"], cand, scores,
             lambda o: float(1.0 / (1.0 + self._exact_d2(
                 np.array([o]), qv)[0])),
-            lambda _id: int(_id[1:]), self.k, rtol=oracle.KNN_RTOL)
+            lambda _id: int(_id[1:]), self.k, rtol=oracle.KNN_RTOL,
+            seen=seen)
 
 
 def build(config: dict, seed: int, dry_run: bool) -> Corpus:

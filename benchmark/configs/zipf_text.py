@@ -238,7 +238,7 @@ class Corpus:
         ords = np.flatnonzero(dense)
         return ords, dense[ords]
 
-    def judge(self, pairs: list) -> list:
+    def judge(self, pairs: list, seen: dict = None) -> list:
         bad = []
         for query, resp in pairs:
             what = f"match [{query.text}]"
@@ -253,7 +253,7 @@ class Corpus:
                 oracle.check_total(what, resp, len(ords))
                 oracle.check_page(what, resp["hits"]["hits"], ords, scores,
                                   score_of, lambda _id: int(_id[1:]),
-                                  self.k)
+                                  self.k, seen=seen)
             except oracle.Mismatch as e:
                 bad.append(str(e))
         return bad

@@ -51,6 +51,17 @@ def check_total(what: str, resp: dict, want: int) -> None:
             f"{what}: total {t}, oracle {want}")
 
 
+def lower_precision(scores: np.ndarray) -> np.ndarray:
+    """The control of `correct`: the reference's scores in the nearest
+    precision below the float32 both configurations state, bfloat16
+    (round to nearest even on the top 16 bits of the float32). A page
+    ranked and scored so has to FAIL `check_page`; the tests beside the
+    benchmark hold it to that (tests/benchmark/test_correct.py)."""
+    bits = np.asarray(scores, dtype=np.float32).view(np.uint32)
+    rounded = (bits + 0x7FFF + ((bits >> 16) & 1)) & np.uint32(0xFFFF0000)
+    return rounded.view(np.float32).astype(np.float64)
+
+
 def top_ords(scores: np.ndarray, ords: np.ndarray, k: int):
     """The best k of (ords, scores): score desc, then ordinal asc."""
     if len(ords) > 4 * k:
@@ -63,14 +74,31 @@ def top_ords(scores: np.ndarray, ords: np.ndarray, k: int):
 
 
 def check_page(what: str, hits: list, ords: np.ndarray, scores: np.ndarray,
-               score_of, ord_of_id, k: int, rtol: float = RTOL) -> None:
+               score_of, ord_of_id, k: int, rtol: float = RTOL,
+               seen: dict = None) -> None:
     """Hit ids equal the oracle's in order, except permutations among hits
     whose ORACLE scores are within `rtol` of each other (f32 cannot
     order what f64 separates by less); every score within `rtol`.
 
     `ords`/`scores`: every matching doc; `score_of(ord)`: the oracle's
-    score of one doc or None; `ord_of_id(_id)`: the doc's ordinal."""
+    score of one doc or None; `ord_of_id(_id)`: the doc's ordinal.
+    `seen`, where given, keeps what was compared for the run's last
+    lines: `score_rel_err_max`, the widest relative gap of a served
+    score from the oracle's at its rank, beside its limit `rtol`, and
+    `hits_compared`."""
     want_ords, want_scores = top_ords(scores, ords, k)
+    if seen is not None:
+        seen["score_rel_err_limit"] = rtol
+        seen.setdefault("score_rel_err_max", 0.0)
+        seen.setdefault("hits_compared", 0)
+        for h, ws in zip(hits, want_scores):
+            gs = h["_score"]
+            if gs is not None and math.isfinite(gs):
+                seen["hits_compared"] += 1
+                seen["score_rel_err_max"] = max(
+                    seen["score_rel_err_max"],
+                    abs(gs - float(ws)) / max(abs(gs), abs(float(ws)),
+                                              1e-300))
     require(len(hits) == len(want_ords),
             f"{what}: {len(hits)} hits, oracle has {len(want_ords)}")
     got = [ord_of_id(h["_id"]) for h in hits]

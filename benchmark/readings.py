@@ -1,11 +1,13 @@
 """What several metric readers share: deltas of the node's always-on
 histograms and counters over the window (`_nodes/stats` before and after;
 only sum and count are read, never the bucket-interpolated percentiles),
-and which queries the traced slice covers."""
+and which queries the traced window covers."""
 
 from __future__ import annotations
 
 from typing import Optional
+
+from benchmark import spans
 
 
 def _metrics(run, when: str) -> dict:
@@ -35,17 +37,25 @@ def counter_delta(run, name: str) -> int:
 
 def slice_shares(run):
     """[(sample, share)] for every request that overlaps the traced
-    slice: the share of its service interval inside the slice. Summed,
+    window: the share of its service interval inside the window. Summed,
     the shares count the requests the traced device time belongs to
-    without an edge error of a whole request at either end."""
+    without an edge error of a whole request at either end. The window
+    is the interval the device recorded, put on the host's clock by the
+    join's offset (benchmark/spans.py `recorded_intervals`; a request's
+    share is its mean over the planes), the same interval `window_s`
+    and idle are read over; without a join, the host's slice around the
+    profiler's start and stop, for both."""
     if run.trace_slice is None:
         return []
-    a, b = run.trace_slice
+    intervals = spans.recorded_intervals(run) or [run.trace_slice]
     out = []
     for s in run.all_samples:
-        lo, hi = max(s.sent, a), min(s.done, b)
-        if hi > lo and s.done > s.sent:
-            out.append((s, (hi - lo) / (s.done - s.sent)))
+        if s.done <= s.sent:
+            continue
+        inside = sum(max(min(s.done, b) - max(s.sent, a), 0.0)
+                     for a, b in intervals) / len(intervals)
+        if inside > 0:
+            out.append((s, inside / (s.done - s.sent)))
     return out
 
 

@@ -17,8 +17,13 @@ metric is a file found by the name BENCHMARK.json gives it:
     benchmark/metrics/<metric>.json   `reader` + its parameters
     benchmark/metrics/readers/<reader>.py
 
+The line's last key, `compared`, and the last lines of standard error
+hold every number `correct` rests on beside its limit (oracle.py).
+
 It runs on what jax gives it and refuses anything but a TPU with the
-chips the cell asks for. The one exception is `--dry-run` with
+chips the cell asks for; a cell of several chips also has to have run
+on a mesh of that width (`mesh_width`, read after the window from what
+the node reports). The one exception is `--dry-run` with
 JAX_PLATFORMS=cpu set by the caller: tiny sizes from the files' `dry_run`
 blocks, for tests and debugging; it says so and reports platform "cpu".
 """
@@ -154,8 +159,10 @@ class Run:
         self.window = (0.0, 0.0)    # monotonic start, end
         self.trace = None           # trace_reduce.Reduction, --trace 1
         self.trace_slice = None     # monotonic (start, stop) of the trace
+        self.trace_called = None    # and when start_trace was called
         self.judged = 0
         self.mismatches = []
+        self.seen = {}              # what the comparison read (oracle.py)
         self.node = self.server = self.corpus = self.jax = None
 
     # ---------------------------------------------------------- set-up
@@ -335,11 +342,16 @@ class Run:
             out = self.args.keep_trace \
                 or tempfile.mkdtemp(prefix="bench-trace-")
             try:
+                self.trace_called = time.monotonic()
                 self.jax.profiler.start_trace(out, profiler_options=options)
                 a = time.monotonic()
                 time.sleep(length)
                 b = time.monotonic()
                 self.jax.profiler.stop_trace()
+                # the host's reading of the slice; the device's own, from
+                # its first recorded op to its last, is the window
+                # wherever the planes can be laid on this clock
+                # (trace_reduce.py, spans.py `recorded_intervals`)
                 self.trace_slice = (a, b)
                 self.trace = trace_reduce.reduce_file(
                     trace_reduce.find_xplane(out), window_s=b - a)
@@ -355,6 +367,34 @@ class Run:
                     or time.monotonic() + length + 2.0 > t_end:
                 break
             log("no device plane in the light trace: again with defaults")
+
+    def keep_slice(self) -> None:
+        """--keep-trace: beside the profile, what the span readers took
+        from the same slice (the ring's rows of the requests near it and
+        the client's samples), so that a kept trace can be read again
+        off the chip (tests/benchmark/recorded)."""
+        from benchmark import spans
+        ring = spans.fetch(self)
+        a, b = self.trace_slice
+        lo, hi = a - 2.0, b + 1.0
+        kept = {"trace_slice": [a, b], "trace_called": self.trace_called,
+                "ring": None,
+                "samples": [{"index": s.index, "sent": s.sent,
+                             "done": s.done,
+                             "queries": len(self.requests[s.index])}
+                            for s in self.all_samples
+                            if s.done >= lo and s.sent <= hi]}
+        if ring is not None:
+            near = {s["trace_id"] for s in ring.spans
+                    if s["parent_id"] == 0 and s["end_ns"] >= lo * 1e9
+                    and s["start_ns"] <= hi * 1e9}
+            kept["ring"] = {"clock": "monotonic_ns", "dropped": ring.dropped,
+                            "anchor": ring.anchor,
+                            "spans": [s for s in ring.spans
+                                      if s["trace_id"] in near]}
+        with open(os.path.join(self.args.keep_trace, "slice.json"),
+                  "w") as f:
+            json.dump(kept, f)
 
     # ------------------------------------------------------------ judge
 
@@ -398,11 +438,30 @@ class Run:
             else:
                 pages = rng.sample(pages, want)
         t0 = time.monotonic()
-        bad = self.corpus.judge([(q, item) for _, _, q, item in pages])
+        bad = self.corpus.judge([(q, item) for _, _, q, item in pages],
+                                self.seen)
         self.mismatches += bad
         self.judged = len(pages)
         log(f"judged {self.judged} pages in {time.monotonic() - t0:.2f}s, "
             f"{len(bad)} differ")
+
+    def compared(self) -> dict:
+        """Each number `correct` rests on beside its limit: the widest
+        relative gap of a served score from the reference's (the limit
+        is the configuration's own, `guarantees`), the judged pages that
+        differ from the reference in any way (ids, order, total, a
+        failed shard), and how many pages were judged at all."""
+        out = {}
+        if "score_rel_err_max" in self.seen:
+            out["score_rel_err_max"] = {
+                "value": self.seen["score_rel_err_max"],
+                "limit": self.seen["score_rel_err_limit"],
+                "holds": "at_most"}
+        out["pages_differing"] = {"value": len(self.mismatches),
+                                  "limit": 0, "holds": "at_most"}
+        out["pages_judged"] = {"value": self.judged, "limit": 1,
+                               "holds": "at_least"}
+        return out
 
     # ---------------------------------------------------------- metrics
 
@@ -418,6 +477,17 @@ class Run:
     def stop(self) -> None:
         if self.server is not None:
             self.server.close()
+
+
+def mesh_width(node_stats: dict) -> int:
+    """On how many devices the node holds a part of an SPMD shard set
+    (`telemetry.device_memory`, class `spmd_shard_sets`, `by_device`):
+    the width of the mesh the sharded requests of the window ran on, 0
+    where none took the SPMD program."""
+    image = node_stats["telemetry"]["device_memory"]["classes"].get(
+        "spmd_shard_sets", {})
+    return sum(1 for nbytes in image.get("by_device", {}).values()
+               if nbytes > 0)
 
 
 def sweep(run: Run, window_reqs: list, rates: list) -> None:
@@ -529,6 +599,24 @@ def main(argv=None) -> int:
             sweep(run, window_reqs, rates)
             return 0
         run.measure(payloads)
+        # a cell that asks for several chips is there for what exists
+        # only across them: it has to have run on a mesh of that width,
+        # as the node itself reports it, not on one chip of the host
+        chips = run.workload["chips"]
+        width = mesh_width(run.stats["after"]) if chips > 1 else chips
+        if width != chips:
+            sys.stderr.write(
+                f"benchmark: cell [{args.workload}] asks for {chips} "
+                f"chips and its index is on a mesh of {width} after the "
+                f"window (spmd_shard_sets.by_device). No result.\n")
+            return 1
+        if args.trace:
+            # lay the planes on the host's clock before anything reads
+            # the window or counts its requests
+            from benchmark import spans
+            spans.device_join(run)
+            if args.keep_trace:
+                run.keep_slice()
         log(f"window done: {len(run.samples)} requests counted; "
             f"setup {run.spans['setup_s']:.2f}s")
         run.parse_and_judge()
@@ -554,6 +642,13 @@ def main(argv=None) -> int:
         log(f"MISMATCH {msg}")
     log(f"spans {json.dumps({k: round(v, 3) for k, v in run.spans.items()})}"
         f" judged={run.judged} dry_run={args.dry_run}")
+    # last on standard error and last in the line: what was compared
+    result["compared"] = run.compared()
+    for name, c in result["compared"].items():
+        sign = "<=" if c["holds"] == "at_most" else ">="
+        sys.stderr.write(f"[compared] {name} {c['value']!r} {sign} "
+                         f"{c['limit']!r}\n")
+    sys.stderr.flush()
     print(json.dumps(result))
     sys.stdout.flush()
     return 0

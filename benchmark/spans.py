@@ -10,10 +10,12 @@ device ops of the traced slice.
 (a TPU plane counts nanoseconds from the profiler session's start, which
 the reduction does not keep), and with the profiler's host tracer off no
 annotation reaches the trace. So the offset `device - host` is bracketed
-by causality. The unit is the wave (a B=1 request is one wave): device
-ops are cut into program runs (a program's ops follow one another
-within a microsecond or so; the next program starts later than
-`RUN_GAP_NS`), the k-th `dispatch` span of the slice owns the next runs, as
+by causality. The unit is the wave (a B=1 request is one wave): a
+plane's device ops are cut into program runs (by the plane's "XLA
+Modules" events, one a run and named for the jitted function, so two
+programs that queue back to back are still two runs; where a plane has
+no such line, where the device stood still for more than `RUN_GAP_NS`),
+the k-th `dispatch` span of the slice owns the next runs, as
 many as its `programs` (and its `device_wait`'s, for the row-concat
 program) say, and for every wave
 
@@ -27,6 +29,16 @@ before giving up (None). A feasible interval does not prove the
 matching: `runs_agree` checks it against what the bracket did not use,
 the time each executable's runs take.
 
+**Every plane.** The join runs on each chip's plane (`join_planes`):
+on an SPMD mesh every plane runs every program, on one chip there is
+one plane. The planes of one trace count from one zero, so the offset's
+feasible interval is the intersection of the planes' intervals; an
+empty intersection is no join. With the offset, a plane's recorded
+interval (first op to last, `trace_reduce`) lies on the host's clock:
+that is the interval the requests are counted over and idle is
+attributed in (`recorded_intervals`), not the host's slice around the
+profiler's start and stop, which is longer at both ends.
+
 Against a node that has no such endpoint (a parent commit of the PR that
 added it), and without a device plane, every function here returns None
 and nothing raises; anything else that goes wrong is a bug and fails
@@ -36,6 +48,7 @@ the traced run.
 from __future__ import annotations
 
 import bisect
+import copy
 import re
 import statistics
 import sys
@@ -161,22 +174,22 @@ def waves_of(spans: Spans) -> List[Wave]:
 # ------------------------------------------------------- the device ops
 
 class Run:
-    """One program run: consecutive device ops with no gap between
-    them."""
+    """One program run: the device ops (by start) inside one module
+    event, with the module's name; or, cut at the gap, consecutive
+    device ops with no gap between them, and no name."""
 
-    __slots__ = ("events", "start", "end")
+    __slots__ = ("events", "start", "end", "name")
 
-    def __init__(self, events):
+    def __init__(self, events, name: Optional[str] = None):
         self.events = events
         self.start = events[0][1]
         self.end = max(e[2] for e in events)
+        self.name = name
 
 
-def program_runs(events) -> List[Run]:
-    """Device ops (name, start, end) cut where the device stood still
-    for more than `RUN_GAP_NS`."""
+def _cut_at_gaps(events) -> List[Run]:
     runs, cur, cur_end = [], [], 0
-    for ev in sorted(events, key=lambda e: e[1]):
+    for ev in events:
         if cur and ev[1] - cur_end > RUN_GAP_NS:
             runs.append(Run(cur))
             cur = []
@@ -187,18 +200,70 @@ def program_runs(events) -> List[Run]:
     return runs
 
 
+def program_runs(events, modules=()) -> List[Run]:
+    """A plane's device ops (name, start, end) as program runs, by
+    start. Where the plane has module events (`modules`, the "XLA
+    Modules" line: one event a program run), a run is the ops that
+    start inside one of them and carries its name; ops inside none (the
+    profiler cut their module event away at an edge of the trace) are
+    cut like a plane without the line: where the device stood still for
+    more than `RUN_GAP_NS`. Which of the two is decided by what the
+    trace holds."""
+    events = sorted(events, key=lambda e: e[1])
+    if not modules:
+        return _cut_at_gaps(events)
+    modules = sorted(modules, key=lambda m: m[1])
+    starts = [m[1] for m in modules]
+    inside: Dict[int, list] = {}
+    outside = []
+    for ev in events:
+        i = bisect.bisect_right(starts, ev[1]) - 1
+        if i >= 0 and ev[1] < max(modules[i][2], modules[i][1] + 1):
+            inside.setdefault(i, []).append(ev)
+        else:
+            outside.append(ev)
+    runs = [Run(evs, modules[i][0]) for i, evs in inside.items()]
+    return sorted(runs + _cut_at_gaps(outside), key=lambda r: r.start)
+
+
 class Join:
-    """Waves with their runs, and the offset that puts device time on
-    the host's clock: host = device - offset, offset in [lo, hi]."""
+    """One plane's waves with their runs, and the offset that puts
+    device time on the host's clock: host = device - offset, offset in
+    [lo, hi]. `offset` is the middle of the plane's own interval until
+    `MeshJoin` sets the planes' common one."""
 
     def __init__(self, waves, runs, lo: int, hi: int, matched_runs: int,
                  all_waves=()):
-        self.waves = waves          # those that were given runs
+        self.waves = waves          # those that were given runs (copies)
         self.all_waves = all_waves  # every completed wave of the ring
         self.runs = runs            # every run of the plane
         self.lo, self.hi = lo, hi
         self.offset = (lo + hi) // 2
         self.matched_runs = matched_runs
+
+    @property
+    def bracket_ns(self) -> int:
+        return self.hi - self.lo
+
+    @property
+    def recorded_ns(self) -> Tuple[int, int]:
+        """The plane's recorded interval, first op to last, on the
+        host's clock."""
+        return (min(r.start for r in self.runs) - self.offset,
+                max(r.end for r in self.runs) - self.offset)
+
+
+class MeshJoin:
+    """The planes' joins under one offset: the middle of the
+    intersection of their feasible intervals."""
+
+    def __init__(self, planes: Dict[str, Join]):
+        self.planes = planes
+        self.lo = max(jn.lo for jn in planes.values())
+        self.hi = min(jn.hi for jn in planes.values())
+        self.offset = (self.lo + self.hi) // 2
+        for jn in planes.values():
+            jn.offset = self.offset
 
     @property
     def bracket_ns(self) -> int:
@@ -229,14 +294,16 @@ def _assign(waves: List[Wave], runs: List[Run], first_wave: int,
 
 
 def join(waves: List[Wave], events, slice_ns: Tuple[int, int],
-         max_shift: int = 3) -> Optional[Join]:
+         max_shift: int = 3, modules=()) -> Optional[Join]:
     """Match the waves that can have run inside the traced slice with
-    the program runs of one device plane. The first run of the trace
+    the program runs of one device plane (`events`, its ops; `modules`,
+    its module events where it has them). The first run of the trace
     may belong to a wave already in flight when the profiler started
     (the run is then cut short, or whole), so the first few waves and
     the first few runs are each tried as the start; the feasible
-    matching that places most runs wins."""
-    runs = program_runs(events)
+    matching that places most runs wins. The waves of the result are
+    copies: another plane's join gives the same waves other runs."""
+    runs = program_runs(events, modules)
     a, b = slice_ns
     # a wave can have device ops in the trace if it was open at any time
     # from a little before the slice (the profiler starts recording
@@ -270,35 +337,83 @@ def join(waves: List[Wave], events, slice_ns: Tuple[int, int],
     _, lo, hi, given, n = best
     matched = []
     for w, take in given:
+        w = copy.copy(w)
         w.runs = take
         matched.append(w)
     return Join(matched, runs, lo, hi, n, all_waves=waves)
 
 
-def device_join(run) -> Optional[Join]:
-    """The join of the run's spans with the first device plane of its
-    traced slice, once a run."""
+def join_planes(waves: List[Wave], planes: Dict[str, list],
+                slice_ns: Tuple[int, int],
+                modules: Optional[Dict[str, list]] = None
+                ) -> Optional[MeshJoin]:
+    """`join` on every plane that recorded an op, under one offset: the
+    planes of a trace count from one zero, so the offset lies in every
+    plane's feasible interval. None where a plane has no feasible join,
+    or the intersection of the planes' intervals is empty."""
+    joins = {}
+    for name, events in planes.items():
+        if not events:
+            continue
+        jn = join(waves, events, slice_ns,
+                  modules=(modules or {}).get(name, ()))
+        if jn is None:
+            return None
+        joins[name] = jn
+    if not joins:
+        return None
+    mesh = MeshJoin(joins)
+    return mesh if mesh.lo <= mesh.hi else None
+
+
+def device_join(run) -> Optional[MeshJoin]:
+    """The join of the run's spans with the device planes of its traced
+    slice, once a run. Without one the traced window and the count of
+    its requests keep the host's slice, and stderr says so."""
     if "_join" not in run.__dict__:
         run._join = None
         spans = fetch(run)
         trace = getattr(run, "trace", None)
-        if spans is not None and trace is not None and trace.planes \
-                and run.trace_slice is not None:
-            events = next(iter(trace.planes.values()))
-            a, b = run.trace_slice
-            jn = run._join = join(
-                waves_of(spans), events, (int(a * 1e9), int(b * 1e9)))
+        if trace is None or not trace.planes or run.trace_slice is None:
+            return None
+        a, b = run.trace_slice
+        # nothing is recorded of a wave that was over before the
+        # profiler was started
+        called = getattr(run, "trace_called", None)
+        since = 0 if called is None else int(called * 1e9)
+        mesh = None if spans is None else join_planes(
+            [w for w in waves_of(spans) if w.end_ns >= since],
+            trace.planes, (int(a * 1e9), int(b * 1e9)), trace.modules)
+        run._join = mesh
+        if mesh is None:
+            trace.keep_host_window()
             sys.stderr.write(
-                "[spans] no feasible join of waves and device ops\n"
-                if jn is None else
+                "[spans] no feasible join of waves and device ops: the "
+                "traced window and its requests are the host's slice, "
+                f"{b - a:.4f}s\n")
+            return None
+        for name, jn in mesh.planes.items():
+            lo, hi = jn.recorded_ns
+            named = sum(1 for r in jn.runs if r.name is not None)
+            sys.stderr.write(
                 f"[spans] {len(jn.waves)} waves own {jn.matched_runs} of "
-                f"{len(jn.runs)} program runs; offset {jn.offset} ns "
-                f"+- {jn.bracket_ns // 2}; first op "
-                f"{(jn.runs[0].start - jn.offset) / 1e9 - a:+.4f}s from "
-                f"the slice's start, last "
-                f"{(jn.runs[-1].end - jn.offset) / 1e9 - b:+.4f}s from "
+                f"{len(jn.runs)} program runs ({named} by a module event)"
+                f" on {name}; offset {mesh.offset} ns +- "
+                f"{mesh.bracket_ns // 2}; first op {lo / 1e9 - a:+.4f}s "
+                f"from the slice's start, last {hi / 1e9 - b:+.4f}s from "
                 f"its end\n")
     return run._join
+
+
+def recorded_intervals(run) -> Optional[List[Tuple[float, float]]]:
+    """Every joined plane's recorded interval on the host's clock,
+    seconds (to half the bracket, `span_clock_bracket_us`): what the
+    traced device time belongs to. None without a join."""
+    mesh = device_join(run)
+    if mesh is None:
+        return None
+    return [(lo / 1e9, hi / 1e9) for lo, hi in
+            (jn.recorded_ns for jn in mesh.planes.values())]
 
 
 # -------------------------------------------------------- idle, by span
@@ -309,7 +424,8 @@ IDLE_PARTS = ("between_requests", "before_first_op", "inside_request",
 
 def idle_parts(spans: Spans, jn: Join, slice_ns: Tuple[int, int]
                ) -> Dict[str, int]:
-    """The device's idle time inside the slice (host clock), each
+    """One plane's idle time inside `slice_ns` (host clock; its
+    recorded interval in a run, `mesh_idle_parts`), each
     instant put down to what the host was in: no served request open;
     a request open whose first op has not started (decode, parse, pack,
     upload); between the ops of an open request (a later program
@@ -380,6 +496,24 @@ def idle_parts(spans: Spans, jn: Join, slice_ns: Tuple[int, int]
     return out
 
 
+def mesh_idle_parts(spans: Spans, mesh: MeshJoin) -> Dict[str, float]:
+    """`idle_parts` of every plane inside its own recorded interval,
+    averaged over the planes: the parts sum to the mean window less the
+    mean busy time, as `trace_reduce.Reduction` reads them."""
+    out = dict.fromkeys(IDLE_PARTS, 0.0)
+    for jn in mesh.planes.values():
+        for part, ns in idle_parts(spans, jn, jn.recorded_ns).items():
+            out[part] += ns / len(mesh.planes)
+    return out
+
+
+def mesh_runs_agree(mesh: MeshJoin) -> Optional[float]:
+    """`runs_agree` of the plane that agrees least; None where no plane
+    has anything to compare."""
+    shares = [runs_agree(jn) for jn in mesh.planes.values()]
+    return min((s for s in shares if s is not None), default=None)
+
+
 def runs_agree(jn: Join) -> Optional[float]:
     """The join checked against what the bracket did not use: a run of
     the executable its wave's `dispatch` names takes the time that
@@ -447,7 +581,7 @@ def scope_maps(run) -> Optional[Dict[str, Dict[str, str]]]:
 
 def stage_ns(jn: Join, maps: Dict[str, Dict[str, str]]
              ) -> Optional[Dict[Optional[str], int]]:
-    """Device-op nanoseconds of every run of the plane by stage as the
+    """Device-op nanoseconds of every run of one plane by stage as the
     census writes it (`stage`; `~stage` where it was inferred from the
     op's neighbours; None: of no stage). An op of a wave's run is read
     in the map of the executable the wave dispatched for that run; an
@@ -475,6 +609,21 @@ def stage_ns(jn: Join, maps: Dict[str, Dict[str, str]]
         for name, lo, hi in r.events:
             st = m.get(instruction(name))
             out[st] = out.get(st, 0) + (hi - lo)
+    return out
+
+
+def mesh_stage_ns(mesh: MeshJoin, maps: Dict[str, Dict[str, str]]
+                  ) -> Optional[Dict[Optional[str], float]]:
+    """`stage_ns` of every plane, averaged over the planes (a chip's
+    share of a query's device time, as `device_ms_per_query` has it);
+    None where any plane ran an executable without a map."""
+    out: Dict[Optional[str], float] = {}
+    for jn in mesh.planes.values():
+        by_stage = stage_ns(jn, maps)
+        if by_stage is None:
+            return None
+        for st, ns in by_stage.items():
+            out[st] = out.get(st, 0.0) + ns / len(mesh.planes)
     total = sum(out.values()) or 1
     inferred = sum(ns for st, ns in out.items() if st and st[0] == "~")
     sys.stderr.write(

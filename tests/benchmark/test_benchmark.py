@@ -1,7 +1,10 @@
-"""The benchmark harness off the chip: every cell runs end to end at
-`--dry-run` sizes on the CPU backend and prints the contract's last line;
-configurations, mixes and metrics are found by name; the classes a cell
-sends do not depend on the seed; BENCHMARK.json keeps to its own rules.
+"""The benchmark harness off the chip: every cell that BENCHMARK.json or
+the fuller copy beside this file names runs end to end at `--dry-run`
+sizes on the CPU backend and prints the contract's last line;
+configurations, mixes and metrics are found by name, and so are the
+cells these tests run: a cell added as entries and new files is tested
+with no edit here; the classes a cell sends do not depend on the seed;
+BENCHMARK.json keeps to its own rules, `chips` 1 or 4 among them.
 No timing is asserted: a CPU run gives correctness and counts only.
 """
 
@@ -31,11 +34,38 @@ BENCH = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
 FULL = json.load(open(os.path.join(
     os.path.dirname(os.path.abspath(__file__)), "BENCHMARK.full.json")))
 CELLS = [w["name"] for w in FULL["workloads"]]
-BOTH = pytest.mark.parametrize("bench", [BENCH, FULL],
-                               ids=["committed", "full"])
+# every cell that either file names, once, with the file that names it:
+# the fuller copy's cells run from a copy of the tree that holds it, a
+# cell only BENCHMARK.json names (as a later PR adds one) from the repo
+EVERY_CELL = [("full", c) for c in CELLS] \
+    + [("committed", w["name"]) for w in BENCH["workloads"]
+       if w["name"] not in CELLS]
+EACH_CELL = pytest.mark.parametrize("where,cell", EVERY_CELL,
+                                    ids=[c for _, c in EVERY_CELL])
+
+
+def with_a_four_chip_cell(bench: dict) -> dict:
+    """A copy with a second cell appended as the next `model_config` PR
+    appends one: `chips` 4, reporting the closed-loop median."""
+    bench = json.loads(json.dumps(bench))
+    bench["workloads"].append({
+        "name": "four-chip-cell", "config": bench["workloads"][0]["config"],
+        "traffic": "rare-msearch-256", "chips": 4,
+        "why": "what exists only across chips: the SPMD program on a "
+               "mesh of 4"})
+    for m in bench["end_to_end"]:
+        if m["name"] == "closed_search_p50_ms":
+            m["workloads"].append("four-chip-cell")
+    return bench
+
+
+BOTH = pytest.mark.parametrize(
+    "bench", [BENCH, FULL, with_a_four_chip_cell(BENCH)],
+    ids=["committed", "full", "committed-and-a-four-chip-cell"])
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -53,12 +83,25 @@ def files(full_root):
     return bench_run.Files(full_root)
 
 
-WITH_REPO = dict(os.environ, PYTHONPATH=REPO)
+# the program comes from this tree, or from wherever the caller's
+# PYTHONPATH already finds it (a copy of the benchmark alone, tested from
+# the repo)
+WITH_REPO = dict(os.environ, PYTHONPATH=os.pathsep.join(
+    [REPO] + [p for p in os.environ.get("PYTHONPATH", "").split(os.pathsep)
+              if p]))
 
 
-def run_cell(*flags, cwd=REPO, env=None):
+def run_cell(*flags, cwd=REPO, env=None, chips=1):
     assert os.environ["JAX_PLATFORMS"] == "cpu"     # conftest pinned it
     script = os.path.join(cwd, "benchmark", "run.py")
+    if chips > 1:
+        # a cell of several chips has to find a mesh of that width: as
+        # many virtual CPU devices as the cell asks for chips
+        env = dict(os.environ if env is None else env)
+        env["XLA_FLAGS"] = " ".join(
+            [f for f in env.get("XLA_FLAGS", "").split()
+             if "xla_force_host_platform_device_count" not in f]
+            + [f"--xla_force_host_platform_device_count={chips}"])
     return subprocess.run([sys.executable, script, *flags], cwd=cwd,
                           capture_output=True, text=True, timeout=600,
                           env=env)
@@ -74,30 +117,54 @@ def names_of(cell: str, group: str, bench=FULL) -> set:
             if cell in m.get("workloads", [cell])}
 
 
+def chips_of(cell: str, bench: dict) -> int:
+    return next(w["chips"] for w in bench["workloads"] if w["name"] == cell)
+
+
 # ------------------------------------------------------- every cell runs
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_cell_prints_the_contract_line(cell, full_root):
+@pytest.fixture(scope="module")
+def roots(full_root):
+    """The root a cell runs from and the file that names it there."""
+    return {"full": (full_root, FULL), "committed": (REPO, BENCH)}
+
+
+@EACH_CELL
+def test_cell_prints_the_contract_line(where, cell, roots):
+    root, bench = roots[where]
     out = last_line(run_cell("--workload", cell, "--seed", "3000000019",
                              "--seconds", "2", "--trace", "0", "--dry-run",
-                             cwd=full_root, env=WITH_REPO))
+                             cwd=root, env=WITH_REPO,
+                             chips=chips_of(cell, bench)))
     assert set(out) == RESULT_KEYS
     assert out["correct"] is True and out["failed"] == 0
     assert out["attempted"] > 0
     assert set(out["device"]) == DEVICE_KEYS
     assert out["device"]["platform"] == "cpu"
-    assert set(out["metrics"]) == names_of(cell, "end_to_end")
-    units = {m["name"]: m["unit"] for m in FULL["end_to_end"]}
+    # what `correct` rests on comes last in the line, each number beside
+    # its limit
+    assert list(out)[-1] == "compared"
+    for c in out["compared"].values():
+        assert set(c) == {"value", "limit", "holds"}
+        assert c["value"] <= c["limit"] if c["holds"] == "at_most" \
+            else c["value"] >= c["limit"]
+    assert out["compared"]["pages_differing"]["value"] == 0
+    assert 0 <= out["compared"]["score_rel_err_max"]["value"] \
+        <= out["compared"]["score_rel_err_max"]["limit"] <= 1e-4
+    assert set(out["metrics"]) == names_of(cell, "end_to_end", bench)
+    units = {m["name"]: m["unit"] for m in bench["end_to_end"]}
     for name, m in out["metrics"].items():
         assert set(m) == {"value", "unit"} and m["unit"] == units[name]
         assert m["value"] > 0
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_traced_run_prints_per_layer_metrics(cell, full_root):
+@EACH_CELL
+def test_traced_run_prints_per_layer_metrics(where, cell, roots):
+    root, bench = roots[where]
     out = last_line(run_cell("--workload", cell, "--seed", "7",
                              "--seconds", "3", "--trace", "1", "--dry-run",
-                             cwd=full_root, env=WITH_REPO))
+                             cwd=root, env=WITH_REPO,
+                             chips=chips_of(cell, bench)))
     assert set(out) == RESULT_KEYS | {"breakdown"}
     assert out["correct"] is True
     assert set(out["device"]) == DEVICE_KEYS | {"busy_s", "window_s"}
@@ -105,16 +172,19 @@ def test_traced_run_prints_per_layer_metrics(cell, full_root):
     assert set(out["breakdown"]) == {"device_ops", "idle_gaps"}
     # a reader that finds nothing to read (no device plane on the CPU)
     # leaves its metric out; no other name may appear
-    assert set(out["metrics"]) <= names_of(cell, "per_layer")
+    assert set(out["metrics"]) <= names_of(cell, "per_layer", bench)
     assert {"compiles_in_window", "warmup_s", "install_upload_s",
             "resident_corpus_gb"} <= set(out["metrics"])
     assert out["metrics"]["compiles_in_window"]["value"] == 0
+    # nothing ran on a device here: no device time a query, not 0
+    assert not [n for n in out["metrics"] if n.startswith("device_ms")]
 
 
-def test_committed_cell_runs_from_the_repo_itself():
-    cell = BENCH["workloads"][0]["name"]
+@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
+def test_committed_cell_runs_from_the_repo_itself(cell):
     out = last_line(run_cell("--workload", cell, "--seed", "11",
-                             "--seconds", "2", "--trace", "0", "--dry-run"))
+                             "--seconds", "2", "--trace", "0", "--dry-run",
+                             chips=chips_of(cell, BENCH)))
     assert out["correct"] is True
     assert set(out["metrics"]) == names_of(cell, "end_to_end", BENCH)
 
@@ -158,15 +228,10 @@ def digest(root: str) -> dict:
     return out
 
 
-def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
-    """A later PR adds a configuration, a traffic mix and a per-layer
-    metric as files of their own plus entries in BENCHMARK.json, and
-    edits no file that is there."""
-    root = str(tmp_path)
-    copy_benchmark(root)
+def add_a_small_knn_cell(root: str, bench: dict) -> dict:
+    """A cell as a later PR adds one: a configuration file, a traffic
+    file and entries, no file edited. Returns the new BENCHMARK."""
     bdir = os.path.join(root, "benchmark")
-    before = digest(bdir)
-
     cfg = json.load(open(os.path.join(bdir, "configs", "sift-1m.json")))
     cfg["name"], cfg["dry_run"] = "sift-small", {"vectors": 500}
     json.dump(cfg, open(os.path.join(bdir, "configs", "sift-small.json"),
@@ -177,15 +242,7 @@ def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
                       "provision_per_s": 400, "judge": {"sample": 10}}
     json.dump(mix, open(os.path.join(bdir, "traffic", "knn-closed-2.json"),
                         "w"))
-    json.dump({"reader": "count_requests", "params": {"scale": 1}},
-              open(os.path.join(bdir, "metrics", "requests_counted.json"),
-                   "w"))
-    with open(os.path.join(bdir, "metrics", "readers",
-                           "count_requests.py"), "w") as f:
-        f.write("def read(run, params):\n"
-                "    return len(run.samples) * params['scale']\n")
-
-    bench = json.loads(json.dumps(FULL))
+    bench = json.loads(json.dumps(bench))
     bench["configs"].append({
         "name": "sift-small", "source": "test",
         "file": "benchmark/configs/sift-small.json", "reduced": [],
@@ -196,6 +253,27 @@ def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
     for m in bench["end_to_end"]:
         if m["name"] == "closed_search_p50_ms":
             m["workloads"].append("sift-small.closed")
+    json.dump(bench, open(os.path.join(root, "BENCHMARK.json"), "w"))
+    return bench
+
+
+def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
+    """A later PR adds a configuration, a traffic mix and a per-layer
+    metric as files of their own plus entries in BENCHMARK.json, and
+    edits no file that is there."""
+    root = str(tmp_path)
+    copy_benchmark(root)
+    bdir = os.path.join(root, "benchmark")
+    before = digest(bdir)
+
+    bench = add_a_small_knn_cell(root, FULL)
+    json.dump({"reader": "count_requests", "params": {"scale": 1}},
+              open(os.path.join(bdir, "metrics", "requests_counted.json"),
+                   "w"))
+    with open(os.path.join(bdir, "metrics", "readers",
+                           "count_requests.py"), "w") as f:
+        f.write("def read(run, params):\n"
+                "    return len(run.samples) * params['scale']\n")
     bench["per_layer"].append({
         "name": "requests_counted", "unit": "count", "better": "higher",
         "source": "host_clock", "layer": "load generator",
@@ -216,6 +294,57 @@ def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
     after = digest(bdir)
     assert {k: after[k] for k in before} == before     # nothing edited
     assert len(after) == len(before) + 4
+
+
+def test_a_cell_added_to_benchmark_json_is_dry_run_with_no_edit_here(
+        tmp_path):
+    """The per-cell tests of this file find their cells in
+    BENCHMARK.json: in a copy of the benchmark where a PR has appended
+    a cell with its files, this file, unedited, dry-runs the new cell
+    from the root that names it."""
+    root = str(tmp_path)
+    copy_benchmark(root)
+    before = digest(root)
+    add_a_small_knn_cell(root, BENCH)
+    after = digest(root)
+    assert {k: after[k] for k in before if k != "BENCHMARK.json"} \
+        == {k: before[k] for k in before if k != "BENCHMARK.json"}
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-p", "no:randomly", "-k", "sift-small.closed",
+         os.path.join(root, "tests", "benchmark", "test_benchmark.py")],
+        cwd=root, capture_output=True, text=True, timeout=900,
+        env=WITH_REPO)
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-2000:]
+    ran = re.findall(r"(\d+) passed", proc.stdout)
+    # the contract line, the traced run, and the run from the root itself
+    assert ran and int(ran[-1]) == 3, proc.stdout[-2000:]
+
+
+def test_a_four_chip_cell_that_ran_on_no_mesh_of_four_gets_no_result(
+        tmp_path):
+    """`chips` is not a label: the committed one-chip index under a
+    cell that asks for 4 chips never takes the SPMD program, the node
+    reports a mesh of 0 after the window, and the run prints no result.
+    Read from what the node reports, `mesh_width`."""
+    stats = {"telemetry": {"device_memory": {"classes": {
+        "corpus_columns": {"live_bytes": 9},
+        "spmd_shard_sets": {"live_bytes": 8, "entries": 1, "by_device": {
+            "0": 2, "1": 2, "2": 2, "3": 2, "4": 0}}}}}}
+    assert bench_run.mesh_width(stats) == 4
+    stats["telemetry"]["device_memory"]["classes"].pop("spmd_shard_sets")
+    assert bench_run.mesh_width(stats) == 0
+    root = str(tmp_path)
+    copy_benchmark(root)
+    bench = json.loads(json.dumps(BENCH))
+    bench["workloads"][0]["chips"] = 4
+    json.dump(bench, open(os.path.join(root, "BENCHMARK.json"), "w"))
+    proc = run_cell("--workload", bench["workloads"][0]["name"], "--seed",
+                    "13", "--seconds", "2", "--trace", "0", "--dry-run",
+                    cwd=root, env=WITH_REPO, chips=4)
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+    assert "on a mesh of 0" in proc.stderr
 
 
 # ------------------------------------------- shapes do not depend on seed
@@ -337,13 +466,39 @@ def test_class_cycle_interleaves_evenly_and_is_fixed():
 
 # ------------------------------------------------ BENCHMARK.json's own rules
 
+def chips_complaint(bench: dict):
+    """The contract's rule for `chips`, or None: a cell takes 1 chip or
+    4; of a benchmark's cells at most half, rounded down, may ask for
+    4, and one always may."""
+    chips = [w["chips"] for w in bench["workloads"]]
+    if any(c not in (1, 4) for c in chips):
+        return f"chips is 1 or 4, not {sorted(set(chips) - {1, 4})}"
+    allowed = max(len(chips) // 2, 1)
+    if chips.count(4) > allowed:
+        return f"{chips.count(4)} of {len(chips)} cells ask for 4 chips; " \
+               f"at most {allowed} may"
+    return None
+
+
+@pytest.mark.parametrize("chips,passes", [
+    ([1], True), ([1, 4], True), ([4], True), ([1, 1, 1, 4, 4], True),
+    ([1, 1, 4, 4, 4], False), ([1, 4, 4], False), ([4, 4], False),
+    ([2], False), ([1, 2], False), ([1, 8], False), ([1, 0], False)],
+    ids=lambda v: "-".join(map(str, v)) if isinstance(v, list) else None)
+def test_a_cell_takes_one_chip_or_four_and_at_most_half_take_four(
+        chips, passes):
+    bench = {"workloads": [{"name": f"c{i}", "chips": c}
+                           for i, c in enumerate(chips)]}
+    assert (chips_complaint(bench) is None) is passes
+
+
 @BOTH
 def test_benchmark_json_has_exactly_the_contract_keys(bench):
     BENCH = bench
     assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
     assert 1 <= BENCH["run_seconds"] <= 51
-    assert all(w["chips"] == 1 for w in BENCH["workloads"])
+    assert chips_complaint(BENCH) is None
     for word in BENCH["command"]:
         assert not word.startswith("/") and ".." not in word
 
