@@ -288,6 +288,76 @@ class RankVectorsColumn:
 _SEGMENT_UID = itertools.count(1)
 
 
+class PrefixedIds:
+    """`_id`s `f"{prefix}{i}"` for i in [0, n), made when one is read: a
+    sequence a bulk builder hands to `Segment` in place of a list of n
+    `str`, which at tens of millions of documents is gigabytes of
+    Python objects that a `size: 0` workload never renders. Answers
+    what the list answered: length, index, slice, iteration, equality,
+    and the ordinal of an id (`ord_of_id`, the arithmetic inverse that
+    `Segment` uses in place of a dict)."""
+
+    __slots__ = ("prefix", "n")
+
+    def __init__(self, prefix: str, n: int):
+        self.prefix, self.n = prefix, int(n)
+
+    def __len__(self) -> int:
+        return self.n
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return [f"{self.prefix}{j}" for j in range(*i.indices(self.n))]
+        i = int(i)
+        if i < 0:
+            i += self.n
+        if not 0 <= i < self.n:
+            raise IndexError(i)
+        return f"{self.prefix}{i}"
+
+    def __iter__(self):
+        prefix = self.prefix
+        return (f"{prefix}{i}" for i in range(self.n))
+
+    def __eq__(self, other):
+        if isinstance(other, PrefixedIds):
+            return (self.prefix, self.n) == (other.prefix, other.n)
+        return len(other) == self.n and all(
+            a == b for a, b in zip(self, other))
+
+    __hash__ = None
+
+    def ord_of_id(self, doc_id) -> Optional[int]:
+        """i where `self[i] == doc_id`, else None: only the canonical
+        decimal spelling of an ordinal in range is an id."""
+        if not isinstance(doc_id, str) \
+                or not doc_id.startswith(self.prefix):
+            return None
+        digits = doc_id[len(self.prefix):]
+        if not digits.isascii() or not digits.isdigit() \
+                or (digits[0] == "0" and digits != "0"):
+            return None
+        i = int(digits)
+        return i if i < self.n else None
+
+
+class _IdOrds:
+    """`Segment._id_to_ord` over a `PrefixedIds`: the `get` of the dict
+    the list form builds, by arithmetic."""
+
+    __slots__ = ("ids",)
+
+    def __init__(self, ids: PrefixedIds):
+        self.ids = ids
+
+    def get(self, doc_id, default=None):
+        i = self.ids.ord_of_id(doc_id)
+        return default if i is None else i
+
+    def __contains__(self, doc_id) -> bool:
+        return self.ids.ord_of_id(doc_id) is not None
+
+
 class Segment:
     """A sealed, immutable columnar segment (host numpy representation)."""
 
@@ -337,12 +407,28 @@ class Segment:
             else np.full(num_docs, -1, dtype=np.int32)
         self.nested_paths = list(nested_paths or [])
         self.root = self.parent_ptr < 0
-        self._id_to_ord = {d: i for i, d in enumerate(doc_ids)
-                           if d is not None}
+        # _id -> local ord, built on first use (`_id_to_ord`): a search
+        # that fetches no document by id never pays for it
+        self._id_ords = None
         # doc_id → (version, seq_no, primary_term) — Lucene stores these as
         # per-doc fields (_version docvalue, _seq_no); here a host-side map
         # attached by the engine at seal/merge time
         self.doc_meta: Dict[str, Tuple[int, int, int]] = {}
+
+    @property
+    def _id_to_ord(self):
+        """_id -> local ord, on first use: a dict over a list of ids
+        (the last row of a repeated id wins, as a dict comprehension
+        has it), arithmetic over a `PrefixedIds`. Clones made before
+        the first use build their own; the ids are immutable, so every
+        copy builds the same map."""
+        ords = self.__dict__.get("_id_ords")
+        if ords is None:
+            ids = self.doc_ids
+            ords = _IdOrds(ids) if isinstance(ids, PrefixedIds) else \
+                {d: i for i, d in enumerate(ids) if d is not None}
+            self._id_ords = ords
+        return ords
 
     @property
     def live_doc_count(self) -> int:

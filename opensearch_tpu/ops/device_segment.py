@@ -52,7 +52,12 @@ def _to_f32_finite(values: np.ndarray) -> np.ndarray:
     fields store an unbounded-side sentinel (mapper.RANGE_UNBOUNDED = 1e308)
     that must stay finite on device so metric kernels over the decode tables
     never see inf."""
-    return np.clip(values, -_F32_MAX, _F32_MAX).astype(np.float32)
+    # cast first, then saturate in place: one float32 pass instead of a
+    # float64 temporary (8 columns x 8.4M values a row at log scale)
+    with np.errstate(over="ignore"):
+        out = np.asarray(values).astype(np.float32)
+    np.clip(out, -_F32_MAX, _F32_MAX, out=out)
+    return out
 
 
 @dataclass(frozen=True)

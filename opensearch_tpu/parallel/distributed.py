@@ -22,6 +22,8 @@ query and mapper, while per-shard constants live in the stacked inputs.
 
 from __future__ import annotations
 
+import threading
+import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -87,6 +89,14 @@ from opensearch_tpu.ops.topk import NEG_INF, value_merge_key
 from opensearch_tpu.search.compile import Plan
 from opensearch_tpu.search.plan_eval import _eval_plan
 from opensearch_tpu.search.aggs.engine import eval_aggs
+from opensearch_tpu.telemetry.kernels import (jit_family, stage,
+                                              timed_first_call)
+
+# one SPMD program is enqueued at a time, literals first: every chip of
+# the mesh then runs the programs of concurrent requests in one order
+# (their collectives pair up), which is also the order of the requests'
+# `dispatch` spans, what lays a device trace on the host's clock
+_DISPATCH_LOCK = threading.Lock()
 
 
 def spmd_blockmax_admitted(plan: Plan, meta, k: int, sort_spec,
@@ -356,12 +366,18 @@ class DistributedSearcher:
                 scores = jnp.where(matches, scores, 0.0)
             else:
                 pruned = jnp.int32(0)
-                scores, matches = _eval_plan(plan, seg, flat_inputs, cursor)
+                # a text clause keeps its own stages inside (the
+                # innermost scope names an op); what is left is the
+                # match mask of a filter: a range over the rank columns
+                with stage("filter_mask"):
+                    scores, matches = _eval_plan(plan, seg, flat_inputs,
+                                                 cursor)
             # `live` is False on padding rows (ops/device_segment.py), so no
             # per-shard num_docs mask is needed — metas stay shape-only here.
-            eligible = matches & seg["live"] & seg["root"] \
-                & (scores >= min_score)
-            local_total = jnp.sum(eligible.astype(jnp.int32))
+            with stage("eligible_total"):
+                eligible = matches & seg["live"] & seg["root"] \
+                    & (scores >= min_score)
+                local_total = jnp.sum(eligible.astype(jnp.int32))
             if sort_spec is None:
                 keys = scores
             else:
@@ -377,14 +393,16 @@ class DistributedSearcher:
                 field, order = sort_spec
                 keys = value_merge_key(seg["numeric"].get(field), order,
                                        d_pad)
-            masked = jnp.where(eligible, keys, NEG_INF)
-            top_keys, top_idx = jax.lax.top_k(masked, k_eff)
-            top_scores = scores[top_idx]
+            with stage("top_k"):
+                masked = jnp.where(eligible, keys, NEG_INF)
+                top_keys, top_idx = jax.lax.top_k(masked, k_eff)
+                top_scores = scores[top_idx]
 
             agg_outs = []
             if agg_plans:
-                eval_aggs(list(agg_plans), seg, flat_inputs, cursor, eligible,
-                          agg_outs)
+                with stage("agg_bins"):
+                    eval_aggs(list(agg_plans), seg, flat_inputs, cursor,
+                              eligible, agg_outs)
             return (top_keys, top_scores, top_idx.astype(jnp.int32),
                     local_total, pruned, agg_outs)
 
@@ -392,23 +410,25 @@ class DistributedSearcher:
             # block shape: [rpd, ...] rows packed on this device
             tk, ts, ti, tot, prn, agg_outs = jax.vmap(one_row)(
                 seg, flat_inputs, min_scores)
-            shard_i = jax.lax.axis_index(axis)
-            row_ids = shard_i * rpd + jnp.arange(rpd, dtype=jnp.int32)
-            gids = row_ids[:, None] * d_pad + ti            # [rpd, k]
-            # intra-device merge across packed rows, then the ICI merge:
-            # gather every device's candidates, replicated top-k —
-            # SearchPhaseController.mergeTopDocs as one collective + one
-            # sort instead of a coordinator RPC round per shard
-            lk, li = jax.lax.top_k(tk.reshape(-1), k_local)
-            lg = gids.reshape(-1)[li]
-            ls = ts.reshape(-1)[li]
-            gk = jax.lax.all_gather(lk, axis, tiled=True)
-            gg = jax.lax.all_gather(lg, axis, tiled=True)
-            gs = jax.lax.all_gather(ls, axis, tiled=True)
-            mk, mi = jax.lax.top_k(gk, k_merge)
-            mg = gg[mi]
-            ms = gs[mi]
-            total = jax.lax.psum(jnp.sum(tot), axis)
+            with stage("collective_merge"):
+                shard_i = jax.lax.axis_index(axis)
+                row_ids = shard_i * rpd + jnp.arange(rpd, dtype=jnp.int32)
+                gids = row_ids[:, None] * d_pad + ti            # [rpd, k]
+                # intra-device merge across packed rows, then the ICI
+                # merge: gather every device's candidates, replicated
+                # top-k — SearchPhaseController.mergeTopDocs as one
+                # collective + one sort instead of a coordinator RPC
+                # round per shard
+                lk, li = jax.lax.top_k(tk.reshape(-1), k_local)
+                lg = gids.reshape(-1)[li]
+                ls = ts.reshape(-1)[li]
+                gk = jax.lax.all_gather(lk, axis, tiled=True)
+                gg = jax.lax.all_gather(lg, axis, tiled=True)
+                gs = jax.lax.all_gather(ls, axis, tiled=True)
+                mk, mi = jax.lax.top_k(gk, k_merge)
+                mg = gg[mi]
+                ms = gs[mi]
+                total = jax.lax.psum(jnp.sum(tot), axis)
             # per-row pruned-block counts stay sharded ([rpd] per device →
             # [R_pad]); rows without block-max admission report 0
             return mk, ms, mg, total, prn, agg_outs
@@ -419,11 +439,23 @@ class DistributedSearcher:
         # keep a leading [rpd] axis that P(axis) concatenates to [R_pad]
         n_agg_outs = sum(_count_agg_nodes(a) for a in agg_plans)
         out_specs = (P(), P(), P(), P(), P(axis), [P(axis)] * n_agg_outs)
-        fn = jax.jit(_shard_map(
-            local_query_phase, mesh=self.mesh,
-            in_specs=in_specs, out_specs=out_specs))
-        self._cache[key] = fn
-        return fn
+        mapped = _shard_map(local_query_phase, mesh=self.mesh,
+                            in_specs=in_specs, out_specs=out_specs)
+
+        def spmd_query_phase(seg, flat_inputs, min_scores):
+            return mapped(seg, flat_inputs, min_scores)
+
+        # named for its family like every served program: the module of
+        # a device trace reads `jit_spmd_query_phase(<fingerprint>)`
+        fn = jit_family(spmd_query_phase, "spmd_query_phase")
+        self._cache[key] = fn   # shared-state-ok: benign double-jit race; dict slot write is GIL-atomic
+        # the miss gets the first-call timer (the compile reaches
+        # search.xla_cache_miss and the executable census, whose scope
+        # map names the stages above); hits get the raw executable,
+        # which carries the same `exec_info`
+        return timed_first_call(
+            fn, family="spmd_query_phase",
+            shape=f"r{self.n_shards}x{rpd}xd{d_pad}k{k}", key=key)
 
     def build_shard_set(self, shard_arrays: Sequence[Dict],
                         metas: Sequence[Any]) -> HbmShardSet:
@@ -451,7 +483,8 @@ class DistributedSearcher:
                         k: int, min_score: float = float(NEG_INF),
                         agg_plans: Tuple = (),
                         sort_spec: Optional[Tuple[str, str]] = None,
-                        device_scope=None, return_pruned: bool = False):
+                        device_scope=None, return_pruned: bool = False,
+                        marks: Optional[dict] = None):
         """Run the distributed query phase against HBM-resident segments:
         only the flat plan inputs (query constants — term ids, weights,
         range bounds) travel host→device per query.
@@ -477,7 +510,13 @@ class DistributedSearcher:
         row's slice with that row's own agg plans (ordinal spaces are
         segment-local). With return_pruned=True a 7th element is
         appended: per-row pruned posting-block counts [n_rows] (int32,
-        all zeros unless block-max pruning was admitted — ISSUE 20)."""
+        all zeros unless block-max pruning was admitted — ISSUE 20).
+
+        `marks`, where given, gets the clock reads the caller's spans
+        are made of (`time.monotonic()`): `dispatch` = (first literal
+        upload, the jit call's return, bytes uploaded, the executable's
+        `exec_info`) and `device_wait` = (start, end, bytes) of the
+        blocking pull of the result page."""
         if len(flat_inputs) != shard_set.n_rows:
             raise ValueError(
                 f"{len(flat_inputs)} flat-input lists for a "
@@ -496,29 +535,10 @@ class DistributedSearcher:
         # eligible, so they add no candidates, no totals, empty aggs
         min_scores = np.full(r_pad, np.inf, np.float32)
         min_scores[:shard_set.n_rows] = min_score
-        import time as _time
-        t_up = _time.monotonic() if device_scope is not None else 0.0
+        t_up = time.monotonic()
         flat_stack = pad_stack_trees(flat_inputs)
-        flat_stack = _device_put_sharded_tree(flat_stack, self.mesh,
-                                              self.axis,
-                                              channel="upload.literals")
-        min_stack = _device_put_sharded_tree(min_scores, self.mesh,
-                                             self.axis,
-                                             channel="upload.literals")
-        if device_scope is not None:
-            device_scope.devices = self.n_shards
-            device_scope.rows = shard_set.n_rows
-            device_scope.upload_ms = \
-                (_time.monotonic() - t_up) * 1000
-            device_scope.upload_bytes = sum(
-                np.asarray(v).nbytes  # sync-ok: host -- flat inputs are host leaves pre-upload
-                for flat in flat_inputs for d in flat
-                for v in d.values())
-        cache_key = (plan_struct(plan),
-                     tuple(plan_struct(a) for a in agg_plans),
-                     shard_set.shapes, _tree_shapes(flat_stack))
-        fn = self.runner(cache_key, plan, meta, k, agg_plans,
-                         rows_per_dev=rpd, sort_spec=sort_spec)
+        literal_bytes = min_scores.nbytes + sum(
+            l.nbytes for l in jax.tree_util.tree_leaves(flat_stack))
         # collect under an attributed region: the np.asarray conversions
         # ARE the d2h sync of the SPMD path (there is no jax.device_get
         # here), and the ledger decomposes them as its own channel
@@ -527,14 +547,37 @@ class DistributedSearcher:
         scope = ledger.current()
         accounting = ledger.enabled or scope is not None
         with ledger.attributed():
-            # dispatch BEFORE starting the clock: fn's first call per
-            # signature XLA-compiles synchronously (seconds), and that
-            # wall must not pollute the wave_ms percentiles the item-2
-            # scheduler budgets against — only the conversions below
-            # (which block on compute + transfer, like the executor's
-            # device_get) are the collect wall
-            keys, scores, gids, total, pruned_rows, agg_outs = fn(
-                shard_set.seg_stack, flat_stack, min_stack)
+            with _DISPATCH_LOCK:
+                t_dispatch = time.monotonic()
+                flat_stack = _device_put_sharded_tree(
+                    flat_stack, self.mesh, self.axis,
+                    channel="upload.literals")
+                min_stack = _device_put_sharded_tree(
+                    min_scores, self.mesh, self.axis,
+                    channel="upload.literals")
+                t_uploaded = time.monotonic()
+                cache_key = (plan_struct(plan),
+                             tuple(plan_struct(a) for a in agg_plans),
+                             shard_set.shapes, _tree_shapes(flat_stack))
+                fn = self.runner(cache_key, plan, meta, k, agg_plans,
+                                 rows_per_dev=rpd, sort_spec=sort_spec)
+                # dispatch BEFORE starting the clock: fn's first call per
+                # signature XLA-compiles synchronously (seconds), and
+                # that wall must not pollute the wave_ms percentiles the
+                # item-2 scheduler budgets against — only the
+                # conversions below (which block on compute + transfer,
+                # like the executor's device_get) are the collect wall
+                keys, scores, gids, total, pruned_rows, agg_outs = fn(
+                    shard_set.seg_stack, flat_stack, min_stack)
+            t_enqueued = time.monotonic()
+            if device_scope is not None:
+                device_scope.devices = self.n_shards
+                device_scope.rows = shard_set.n_rows
+                device_scope.upload_ms = (t_uploaded - t_up) * 1000
+                device_scope.upload_bytes = sum(
+                    np.asarray(v).nbytes  # sync-ok: host -- flat inputs are host leaves pre-upload
+                    for flat in flat_inputs for d in flat
+                    for v in d.values())
             # ONE post-dispatch clock (t0) for both the per-chip walls
             # and note_device_get below: a cold call's synchronous XLA
             # compile (seconds) must not read as a straggling chip, and
@@ -542,8 +585,7 @@ class DistributedSearcher:
             # whether or not the device gate is on — the per-chip
             # blocks merely move wait out of the np.asarray conversions,
             # they must not shrink the recorded d2h wall
-            t0 = _time.monotonic() \
-                if accounting or device_scope is not None else 0.0
+            t0 = t_enqueued
             t_disp = t0
             if device_scope is not None:
                 # per-chip walls: block on each device's replica of the
@@ -563,14 +605,14 @@ class DistributedSearcher:
                         sh.data.block_until_ready()  # sync-ok: gated device-phase capture -- the result is fetched right below anyway
                         device_scope.partials.append(
                             (int(sh.device.id),
-                             (_time.monotonic() - t_disp) * 1000))
+                             (time.monotonic() - t_disp) * 1000))
                 except (AttributeError, TypeError):
                     # backend without addressable_shards: whole-array
                     # wall attributed to the first mesh device
                     jax.block_until_ready(keys)  # sync-ok: gated device-phase capture -- the result is fetched right below anyway
                     device_scope.partials.append(
                         (int(self.mesh.devices.flatten()[0].id),
-                         (_time.monotonic() - t_disp) * 1000))
+                         (time.monotonic() - t_disp) * 1000))
                 # analytic collective-merge accounting from program
                 # statics: each device gathers 3 channels (keys, gids,
                 # scores) × k_local × 4 B from every mesh device, plus
@@ -581,7 +623,7 @@ class DistributedSearcher:
                     3 * 4 * k_local * n * (n - 1)
             # the scope's pull wall starts AFTER the per-chip blocks
             # (it isolates the host-copy cost the blocks can't absorb)
-            t_pull = _time.monotonic() if device_scope is not None \
+            t_pull = time.monotonic() if device_scope is not None \
                 else t0
             keys = np.asarray(keys)
             scores = np.asarray(scores)
@@ -591,8 +633,11 @@ class DistributedSearcher:
             agg_outs = jax.tree_util.tree_map(np.asarray, agg_outs)
         nb = keys.nbytes + scores.nbytes + gids.nbytes + 8 \
             + pruned_rows.nbytes + sum(
-            a.nbytes for a in jax.tree_util.tree_leaves(agg_outs)) \
-            if (accounting or device_scope is not None) else 0
+            a.nbytes for a in jax.tree_util.tree_leaves(agg_outs))
+        if marks is not None:
+            marks["dispatch"] = (t_dispatch, t_enqueued, literal_bytes,
+                                 getattr(fn, "exec_info", None))
+            marks["device_wait"] = (t_enqueued, time.monotonic(), nb)
         pull_dev = int(self.mesh.devices.flatten()[0].id)
         if accounting:
             # the replicated result page is pulled from the first mesh
@@ -601,10 +646,10 @@ class DistributedSearcher:
                           wave=ledger.new_wave(), scope=scope,
                           devices=[(pull_dev, nb)]
                           if ledger.devices.enabled else None)
-            ledger.note_device_get((_time.monotonic() - t0) * 1000,
+            ledger.note_device_get((time.monotonic() - t0) * 1000,
                                    nbytes=nb, scope=scope)
         if device_scope is not None:
-            device_scope.pull_ms = (_time.monotonic() - t_pull) * 1000
+            device_scope.pull_ms = (time.monotonic() - t_pull) * 1000
             device_scope.pull_bytes = nb
             device_scope.pull_device = pull_dev
         row_idx = gids // meta.d_pad

@@ -67,6 +67,18 @@ AGG_GEMM_MAX_BINS = 256
 # 128 MB. The popcount path's bitmask is 32× smaller per element.
 AGG_GEMM_MAX_ELEMS = 1 << 25
 AGG_POPCOUNT_MAX_ELEMS = 1 << 30
+# A float scatter-add accumulates a bin's addends one after another in
+# float32: over the 10^4-10^5 addends of a bucket of a large segment its
+# sum drifts by 1e-5 and more from the float64 sum upstream computes
+# (sequential rounding grows with the count). So a float bin is summed
+# in up to this many interleaved partial accumulators (lane i feeds
+# accumulator i mod ways), which are then added pairwise: each partial
+# sees 1/ways of the addends, their rounding errors are independent,
+# and the tree adds log2(ways) roundings. Held to 1e-6 of float64 by
+# the http_logs benchmark cell and tests/test_agg_sum_precision.py.
+AGG_SUM_WAYS = 64
+# ...as long as bins x ways stays under this many accumulators
+AGG_SUM_MAX_ACCUMULATORS = 1 << 22
 
 # Input arrays that are segment/node-static by construction (host-computed
 # lookup tables): their CONTENT is part of the plan signature, so a batched
@@ -1113,6 +1125,35 @@ def _pack_bits(ok):
     return (x * w).sum(-1).astype(jnp.uint32)
 
 
+def _sum_ways(total: int) -> int:
+    """How many interleaved partial accumulators a float bin gets: the
+    largest power of two up to AGG_SUM_WAYS that keeps bins x ways under
+    AGG_SUM_MAX_ACCUMULATORS (1: the plain scatter-add)."""
+    ways = AGG_SUM_WAYS
+    while ways > 1 and total * ways > AGG_SUM_MAX_ACCUMULATORS:
+        ways //= 2
+    return ways
+
+
+def _scatter_sum(safe, total: int, v, dt):
+    """Σ of `v` into `total` bins by scatter-add; `safe` [n] int32 holds
+    the bin of each lane, `total` for a lane that drops. Integer sums
+    are exact in any order. A float sum goes through `_sum_ways`
+    interleaved partials a bin and a pairwise tree over them (see
+    AGG_SUM_WAYS): the same number of lanes scattered, blockwise float32
+    partial sums instead of one sequential accumulation."""
+    ways = _sum_ways(total) if jnp.issubdtype(dt, jnp.floating) else 1
+    if ways == 1:
+        return jnp.zeros(total, dt).at[safe].add(v.astype(dt), mode="drop")
+    lane = jnp.arange(safe.shape[0], dtype=safe.dtype) & (ways - 1)
+    # a dropped lane's bin is `total`: past the last accumulator
+    part = jnp.zeros(total * ways, dt).at[safe * ways + lane].add(
+        v.astype(dt), mode="drop").reshape(total, ways)
+    while part.shape[-1] > 1:
+        part = part[:, 0::2] + part[:, 1::2]
+    return part[:, 0]
+
+
 def _binned_sums(bin_lanes, total: int, contribs, static_bins: bool):
     """Per-bin Σ of each (values, out_dtype) contrib. bin_lanes: [n]
     int32; entries outside [0, total) drop. Contribs carry the DYNAMIC
@@ -1127,7 +1168,9 @@ def _binned_sums(bin_lanes, total: int, contribs, static_bins: bool):
       every query of a vmapped batch, reduced as a [B, n] × [n, total]
       matmul (the MXU path) at full f32 operand precision
       (ops.F32_MATMUL). f32 accumulation exact below 2^24.
-    - dynamic bins or many bins: scatter-add.
+    - dynamic bins or many bins: scatter-add (`_scatter_sum`; a float
+      sum in interleaved partials, so that it stays within 1e-6 of the
+      float64 sum).
     """
     n = bin_lanes.shape[0]
     out: List[Any] = [None] * len(contribs)
@@ -1161,13 +1204,11 @@ def _binned_sums(bin_lanes, total: int, contribs, static_bins: bool):
                          bin_lanes, total)
         for i in rest:
             v, dt = contribs[i]
-            out[i] = jnp.zeros(total, dt).at[safe].add(
-                v.astype(dt), mode="drop")
+            out[i] = _scatter_sum(safe, total, v, dt)
         return out
     safe = jnp.where((bin_lanes >= 0) & (bin_lanes < total),
                      bin_lanes, total)
-    return [jnp.zeros(total, dt).at[safe].add(v.astype(dt), mode="drop")
-            for v, dt in contribs]
+    return [_scatter_sum(safe, total, v, dt) for v, dt in contribs]
 
 
 def _pairs_context(seg, col, mask, parent_eff, d_pad):
@@ -1288,9 +1329,18 @@ def _eval_agg(plan: AggPlan, seg: Dict, inputs: List[Dict], cursor: List[int],
             # (multi-valued docs keep the max bin — the engine's
             # single-bucket simplification); dynamic membership rides the
             # child pmask, so this scatter stays unbatched under vmap
-            child_bin = jnp.full(d_pad, -1, jnp.int32).at[
-                jnp.where(bin_ok, safe_doc, d_pad)].max(
-                jnp.where(bin_ok, bin_lanes, -1), mode="drop")
+            if ident and bin_lanes.shape[0] <= d_pad:
+                # identity pairs (doc k <-> lane k): the lanes ARE the
+                # docs, so the scatter-max is a select, padded to d_pad
+                child_bin = jnp.where(bin_ok, bin_lanes, -1)
+                if child_bin.shape[0] < d_pad:
+                    child_bin = jnp.pad(
+                        child_bin, (0, d_pad - child_bin.shape[0]),
+                        constant_values=-1)
+            else:
+                child_bin = jnp.full(d_pad, -1, jnp.int32).at[
+                    jnp.where(bin_ok, safe_doc, d_pad)].max(
+                    jnp.where(bin_ok, bin_lanes, -1), mode="drop")
             child_ctx = (child_bin, _and_pmask(pmask, mask), total,
                          pstatic)
             for c in plan.children:

@@ -557,6 +557,10 @@ def _execute_search_impl(executors: List, body: Optional[dict],
     flags_box: List = [None]
     skipped_box = [0]
     pruned_box = [0]    # SPMD block-max pruned bytes: total -> "gte"
+    # when the SPMD program last answered the query phase (monotonic),
+    # None on the host loop: where the route's `spmd.reduce` (the
+    # cross-row half) and `respond` spans start
+    spmd_served_box: List = [None]
 
     def can_match_flags():
         if flags_box[0] is None:
@@ -587,6 +591,7 @@ def _execute_search_impl(executors: List, body: Optional[dict],
         shard_failures.clear()      # k-growth retries re-run the phase
         failed_shard_ids.clear()
         pruned_box[0] = 0           # last phase run decides the relation
+        spmd_served_box[0] = None
         # SPMD path: with multiple (shard, segment) rows and enough mesh
         # devices, the query phase is ONE shard_map program with on-chip
         # all_gather/psum merge instead of a host loop (search/spmd.py).
@@ -628,9 +633,12 @@ def _execute_search_impl(executors: List, body: Optional[dict],
                 except Exception:   # except-ok: SPMD isolation -- any failure class degrades to the per-shard host loop
                     # the fused all-shard program failed as a unit:
                     # degrade to the per-shard host loop below, where
-                    # failure isolation is per shard
+                    # failure isolation is per shard; counted, with
+                    # the fallbacks the route itself decides on
+                    spmd.note_fallback("error")
                     out = None
             if out is not None:
+                spmd_served_box[0] = time.monotonic()
                 candidates, decoded_partials, total, spmd_pruned = out
                 # block-max pruning made `total` a lower bound: the
                 # response's hits.total.relation degrades to "gte"
@@ -884,6 +892,7 @@ def _execute_search_impl(executors: List, body: Optional[dict],
                     f"failed to reduce aggregations: "
                     f"{type(e).__name__}: {e}", phase="reduce")
         resp["aggregations"] = aggregations
+    t_reduced = time.monotonic()
     if body.get("suggest"):
         from opensearch_tpu.search.suggest import execute_suggest
         with _PhaseTimer(trace, phases, "suggest"):
@@ -956,6 +965,14 @@ def _execute_search_impl(executors: List, body: Optional[dict],
                        for i, (f, _) in enumerate(sort_specs)],
             "tiebreak": (last.shard_i, last.seg_i, last.ord),
         }
+    if spmd_served_box[0] is not None:
+        # the SPMD route's last two spans in the always-on ring: the
+        # cross-row half of `spmd.reduce` (candidate order, page, fetch,
+        # reduce_aggs over the rows' partials) and `respond` (the rest
+        # of the response, to this return)
+        ring = TELEMETRY.tracer.spans
+        ring.child("spmd.reduce", spmd_served_box[0], t_reduced)
+        ring.child("respond", t_reduced, time.monotonic())
     return resp
 
 

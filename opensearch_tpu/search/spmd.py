@@ -31,6 +31,7 @@ or multi-key sorts).
 from __future__ import annotations
 
 import threading
+import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -52,6 +53,28 @@ from opensearch_tpu.telemetry import TELEMETRY
 # item-2 async-scheduler thread-safety audit demanded.
 SPMD_QUERIES = TELEMETRY.metrics.counter("search.spmd_queries")
 SPMD_UPLOADS = TELEMETRY.metrics.counter("search.spmd_uploads")
+# requests that `eligible` admitted and that ended in the per-shard host
+# loop on one chip all the same, and why (`search.spmd_fallbacks.<reason>`
+# beside the total): `plan_structure` (the rows' compiled plans differ),
+# `agg_align` (their aggregation plans cannot be brought to one
+# structure), `sort` (a sort the merge cannot key), `searcher` (the
+# distributed searcher refused the rows: mismatched field layouts), and
+# `error` (anything the program raised, counted where the controller
+# catches it). `force_host_loop` is no fallback: nothing was admitted.
+SPMD_FALLBACKS = TELEMETRY.metrics.counter("search.spmd_fallbacks")
+FALLBACK_REASONS = ("plan_structure", "agg_align", "sort", "searcher",
+                    "error")
+_FALLBACKS_BY_REASON = {
+    reason: TELEMETRY.metrics.counter(f"search.spmd_fallbacks.{reason}")
+    for reason in FALLBACK_REASONS}
+_SPANS = TELEMETRY.tracer.spans
+
+
+def note_fallback(reason: str) -> None:
+    """One admitted request took the host loop; returns None so that a
+    caller can `return note_fallback(...)`."""
+    SPMD_FALLBACKS.inc()
+    _FALLBACKS_BY_REASON[reason].inc()
 
 # guards the searcher/residency caches below: queries mutate them at
 # miss/evict/LRU-touch time, and the item-2 wave scheduler will run
@@ -240,6 +263,7 @@ def _spmd_query_phase_raw(executors: List, body: dict, k: int,
                           extra_filters, rows):
     from opensearch_tpu.parallel.distributed import plan_struct
 
+    t_plan = time.monotonic()
     node = dsl.parse_query(body.get("query"))
     min_score = float(body["min_score"]) \
         if body.get("min_score") is not None else float(NEG_INF)
@@ -285,12 +309,12 @@ def _spmd_query_phase_raw(executors: List, body: dict, k: int,
             # stays row-local afterwards
             align_agg_plans([list(aps) for aps in agg_plans_rows])
         except ValueError:
-            return None
+            return note_fallback("agg_align")
     struct0 = (plan_struct(plans[0]),
                tuple(plan_struct(a) for a in agg_plans_rows[0]))
     for p, aps in zip(plans[1:], agg_plans_rows[1:]):
         if (plan_struct(p), tuple(plan_struct(a) for a in aps)) != struct0:
-            return None
+            return note_fallback("plan_structure")
     flat_rows = []
     for plan, aps in zip(plans, agg_plans_rows):
         flat = plan.flatten_inputs([])
@@ -302,7 +326,7 @@ def _spmd_query_phase_raw(executors: List, body: dict, k: int,
     sort_specs = _parse_sort(body.get("sort"))
     sort_spec = _spmd_sort_spec(executors, sort_specs)
     if sort_spec is False:
-        return None
+        return note_fallback("sort")
 
     # sharded-serving observability (ISSUE 14): the per-device phase
     # capture rides two gates — the device ledger (node-wide per-chip
@@ -323,17 +347,19 @@ def _spmd_query_phase_raw(executors: List, body: dict, k: int,
     searcher = _searcher(len(rows))
     if tl is not None:
         tl.event("fanout", devices=searcher.n_shards, rows=len(rows))
+    marks: dict = {}
     try:
         shard_set = _resident_shard_set(searcher, executors, rows)
         keys, scores, row_idx, ords, total, agg_outs, pruned_rows = \
             searcher.search_resident(
                 shard_set, flat_rows, plans[0], k, min_score=min_score,
                 agg_plans=agg_plans_rows[0], sort_spec=sort_spec,
-                device_scope=cap, return_pruned=True)
+                device_scope=cap, return_pruned=True, marks=marks)
     except (ValueError, KeyError):
         # e.g. a cross-index search whose rows have mismatched field
         # layouts (canonical_meta rejects them) — host loop handles it
-        return None
+        return note_fallback("searcher")
+    wave = _note_device_spans(t_plan, marks)
 
     # always-on scan accounting (telemetry/scan.py): every row of the
     # SPMD program gathers its plan's posting blocks and evaluates the
@@ -427,10 +453,41 @@ def _spmd_query_phase_raw(executors: List, body: dict, k: int,
             row_outs = jax.tree_util.tree_map(lambda o: o[r], agg_outs)
             decoded.append(decode_outputs(list(agg_plans_rows[r]),
                                           row_outs))
+    # everything since the result page arrived: scan accounting, the
+    # candidates, each row's partials decoded with its own plans
+    _SPANS.child("spmd.reduce", marks["device_wait"][1], time.monotonic(),
+                 {"wave": wave})
     # q_pruned > 0 makes `total` a lower bound (pruned blocks' docs were
     # never counted): the caller renders hits.total.relation = "gte",
     # the same contract Lucene's BMW path keeps via track_total_hits
     return cand_tuples, decoded, int(total), q_pruned
+
+
+def _note_device_spans(t_plan: float, marks: dict) -> int:
+    """The SPMD route's spans in the always-on ring, under the span open
+    on this thread (`rest.search`), from the clock reads
+    `search_resident` made: `spmd.plan` (parse, per-row compile, align,
+    structure check, flatten, stack; up to the first literal upload),
+    `dispatch` (first literal upload to the jit call's return, with
+    what the envelope's `dispatch` carries: wave, programs, nbytes,
+    family, fingerprint, shape) and `device_wait` (the blocking pull of
+    the result page). Returns the wave: how many times this request
+    dispatched before (a k-growth retry runs the phase again)."""
+    from opensearch_tpu.search.executor import (_dispatch_attrs,
+                                                _wait_attrs, _wave_attrs)
+    trace = _SPANS.current()
+    if trace is None:
+        return 0
+    wave = sum(1 for s in trace.spans
+               if s[2] == "dispatch" and s[1] == trace.top)
+    t0, t1, nbytes, info = marks["dispatch"]
+    _SPANS.child("spmd.plan", t_plan, t0, (_wave_attrs, wave, None))
+    _SPANS.child("dispatch", t0, t1,
+                 (_dispatch_attrs, wave, None, 1, nbytes,
+                  [info] if info is not None else []))
+    t0, t1, nbytes = marks["device_wait"]
+    _SPANS.child("device_wait", t0, t1, (_wait_attrs, wave, None, nbytes, 0))
+    return wave
 
 
 def _resident_shard_set(searcher, executors, rows):

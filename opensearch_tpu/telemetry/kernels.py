@@ -91,7 +91,7 @@ from opensearch_tpu.telemetry.rolling import RollingEstimator
 
 KERNEL_FAMILIES = ("bm25_candidate", "bm25_dense", "agg_env",
                    "hybrid_env", "page_merger", "knn", "maxsim",
-                   "maxsim_adc", "expand", "other")
+                   "maxsim_adc", "expand", "spmd_query_phase", "other")
 
 # census ring cap: one record per compiled executable — real nodes hold
 # hundreds of executables, not thousands; overflow counts, not crashes
@@ -111,10 +111,16 @@ DEFAULT_SAMPLE_EVERY = 16
 # and candidate kernels: postings_gather (block lanes, tf, norms),
 # bm25_score, scatter (the two `.at[].add` into [d_pad]),
 # eligible_total (mask and sum), top_k, pack_row, unpack_envelope,
-# candidate_sort, run_sum, blockmax_mask; k-NN: distance, top_k.
+# candidate_sort, run_sum, blockmax_mask; k-NN: distance, top_k. The
+# SPMD query phase (parallel/distributed.py): filter_mask (the query
+# plan's match mask where it is no text clause: a range over the rank
+# columns), eligible_total, top_k, agg_bins (the binned counts and
+# sums of the aggregations), collective_merge (all_gather, psum and
+# the merge of what they gathered).
 STAGES = ("postings_gather", "bm25_score", "scatter", "eligible_total",
           "top_k", "pack_row", "unpack_envelope", "candidate_sort",
-          "run_sum", "distance", "blockmax_mask")
+          "run_sum", "distance", "blockmax_mask", "filter_mask",
+          "agg_bins", "collective_merge")
 
 
 def stage(name: str):
