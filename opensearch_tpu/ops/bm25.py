@@ -5,9 +5,16 @@ Lucene Weight.bulkScorer → BM25 per posting, one doc at a time. Here the same
 math runs data-parallel: a query clause gathers its terms' 128-wide postings
 blocks from the resident `[NB, 128]` matrices, computes BM25 partials for all
 lanes at once on the VPU, and scatter-adds into a dense per-doc score vector.
-Conjunction/disjunction semantics fall out of a parallel hit-count scatter
-(each (term, doc) pair appears exactly once in postings, so the hit count per
-doc equals the number of distinct clause terms that matched).
+Conjunction semantics (`operator: and`, `minimum_should_match` >= 2) fall out
+of a second, parallel hit-count scatter (each (term, doc) pair appears exactly
+once in postings, so the hit count per doc equals the number of distinct
+clause terms that matched). That scatter costs what the score scatter costs,
+so it is built only where the count is read: a clause whose matches are "any
+term touched the doc" (the default `match`, `min_hits` 1) and whose every
+partial is provably a positive NORMAL float32 takes its matches from the
+score vector (`scores > 0`) and scatters once. The planner decides that
+statically (search/compile.py `text_clause_score_only`); see
+`score_text_clause` for the bound.
 
 Score parity: idf = ln(1 + (docCount - df + 0.5)/(df + 0.5)) per
 LegacyBM25Similarity (reference: index/similarity/SimilarityService.java:85 —
@@ -162,7 +169,7 @@ def _blockmax_keep_mask(seg, blk, k1, n_terms, k, min_score):
     return keep, pruned
 
 
-def score_text_clause(seg, blk, k1, block_keep=None):
+def score_text_clause(seg, blk, k1, block_keep=None, score_only=False):
     """Score one text clause (match / term / terms over one field family).
 
     seg: device segment dict (post_docs, post_tf, post_norm, live).
@@ -184,8 +191,25 @@ def score_text_clause(seg, blk, k1, block_keep=None):
     count (hence `total`) becomes a lower bound, mirroring Lucene BMW under
     track_total_hits.
 
-    Returns (scores f32 [Dp], hits int32 [Dp]) — hits counts distinct matched
-    clause terms per doc, powering operator=and / minimum_should_match.
+    score_only: STATIC (Plan.static[2], decided by the planner's
+    `text_clause_score_only`). False: a second scatter counts the distinct
+    matched clause terms per doc and `matches = hits >= blk["min_hits"]`,
+    which is what operator=and / minimum_should_match >= 2 need. True: the
+    count scatter is not built and `matches = scores > 0`. That is the same
+    set only when the count is not needed (`min_hits` <= 1, not
+    constant-score) and every partial of a real posting is > 0 AS THE CHIP
+    COMPUTES IT: w > 0, tf >= 1 and a finite positive denominator give
+    partial >= w_min * (k1 + 1) / (1 + k1 * c_max), c_max = 1 - b + b *
+    dl_max / avgdl with dl_max the largest length a norm byte decodes to.
+    The TPU flushes denormals to zero, so "positive" has to mean a normal
+    float32: the planner sets the flag only where that lower bound clears
+    the smallest normal with room to spare (a zero, negative or tiny boost
+    keeps the count). The score scatter is the same either way, so served
+    scores are bit-identical.
+
+    Returns (scores f32 [Dp], matches bool [Dp]); under `block_keep` a
+    pruned lane adds to neither vector, so `matches` keeps its lower-bound
+    meaning either way.
     """
     d_pad = seg["live"].shape[0]
     with stage("postings_gather"):
@@ -203,16 +227,18 @@ def score_text_clause(seg, blk, k1, block_keep=None):
         partial = blk["w"][:, None] * tfs * (k1 + 1.0) / denom
         real = valid & lane_real[:, None]
         partial = jnp.where(real, partial, 0.0)
-        ones = jnp.where(real, 1, 0).astype(jnp.int32)
     with stage("scatter"):
         # padding lanes scatter to index d_pad which is dropped (out of
         # bounds)
         scatter_idx = jnp.where(real, docs, d_pad).ravel()
         scores = jnp.zeros(d_pad, jnp.float32).at[scatter_idx].add(
             partial.ravel(), mode="drop")
+        if score_only:
+            return scores, scores > 0.0
+        ones = jnp.where(real, 1, 0).astype(jnp.int32)
         hits = jnp.zeros(d_pad, jnp.int32).at[scatter_idx].add(
             ones.ravel(), mode="drop")
-    return scores, hits
+    return scores, hits >= blk["min_hits"]
 
 
 def _pairs_to_docs(hit, doc_ids, d_pad, ident: bool):

@@ -360,9 +360,9 @@ class DistributedSearcher:
                 my = flat_inputs[0]
                 keep, pruned = blockmax_keep_mask(
                     seg, my, my["k1"], n_terms, k_eff, min_score)
-                scores, hits = score_text_clause(seg, my, my["k1"],
-                                                 block_keep=keep)
-                matches = hits >= my["min_hits"]
+                scores, matches = score_text_clause(
+                    seg, my, my["k1"], block_keep=keep,
+                    score_only=plan.static[2])
                 scores = jnp.where(matches, scores, 0.0)
             else:
                 pruned = jnp.int32(0)

@@ -14,6 +14,7 @@ its `matches` is false, so combinators compose by plain arithmetic.
 from __future__ import annotations
 
 import fnmatch
+import math
 import re
 from dataclasses import dataclass, field as dc_field, fields as dc_fields
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -37,6 +38,18 @@ from opensearch_tpu.telemetry import TELEMETRY
 _PLAN_COMPILES = TELEMETRY.metrics.counter("search.plan_compiles")
 _TEMPLATE_BINDS = TELEMETRY.metrics.counter("search.template_binds")
 _MEMO_ROTATIONS = TELEMETRY.metrics.counter("search.memo_rotations")
+# text clauses planned (a `_text_clause` memo hit counts too; a repeated
+# whole body served from the interned-bundle memo plans nothing and counts
+# nothing), by whether the dense kernel takes their matches from the score
+# vector or scatters a term count as well (ops/bm25.py score_text_clause)
+_TEXT_SCORE_ONLY = TELEMETRY.metrics.counter("search.text_clause.score_only")
+_TEXT_COUNTED = TELEMETRY.metrics.counter("search.text_clause.counted")
+
+
+def _counted_text_plan(plan: "Plan") -> "Plan":
+    (_TEXT_SCORE_ONLY if plan.static[2] else _TEXT_COUNTED).inc()
+    return plan
+
 
 # live RotatingMemo instances, sampled by the device-memory accounting
 # (telemetry/ledger.py): interned plan bundles hold flattened host
@@ -213,6 +226,40 @@ def struct_fingerprint(obj: Any) -> str:
 
 def _f32(x) -> np.ndarray:
     return np.asarray(x, dtype=np.float32)  # sync-ok: host -- plan literals are host scalars/lists
+
+
+# the smallest BM25 partial a `score_only` text clause may produce: 2**26
+# above float32's smallest normal (2**-126), so no rounding or approximate
+# division on the way to it can land in the denormals the TPU flushes to 0
+SCORE_ONLY_MIN_PARTIAL = 2.0 ** -100
+
+
+def text_clause_score_only(weights: Sequence[float], min_hits: int,
+                           constant: bool, boost: float, k1: float,
+                           b: float, avgdl: float) -> bool:
+    """Whether a text clause's matches can be read off its score vector
+    (`scores > 0`), so that the dense kernel need not scatter a term count
+    (ops/bm25.py score_text_clause). Decided from the plan's own scalars —
+    no scan of a segment — so the rows of one SPMD request agree on it: the
+    count is not needed (`min_hits` <= 1, not constant-score), the boost is
+    > 0, and the smallest partial the clause can produce, w_min * (k1 + 1)
+    / (1 + k1 * c_max) with c_max at the largest length a norm byte decodes
+    to (tf / (tf + k1 * c) grows with tf, tf >= 1), is a normal float32
+    with room to spare. A weight of exactly 0.0 under a positive boost is
+    the idf of a term the shard does not hold (`ShardStats.idf`): no
+    posting carries it, so it bounds nothing — and rows that hold the term
+    and rows that do not plan the same flag."""
+    if constant or min_hits > 1 or not boost > 0.0:
+        return False
+    carried = [w for w in weights if w != 0.0]
+    if not carried:
+        return True       # no posting at all: either program matches nothing
+    w_min = float(np.float32(min(carried)))
+    if not (w_min > 0.0 and math.isfinite(w_min) and k1 >= 0.0
+            and 0.0 <= b <= 1.0 and avgdl > 0.0):
+        return False      # NaN lands here too
+    c_max = 1.0 - b + b * float(LENGTH_TABLE[255]) / avgdl
+    return w_min * (k1 + 1.0) / (1.0 + k1 * c_max) >= SCORE_ONLY_MIN_PARTIAL
 
 
 def _i32(x) -> np.ndarray:
@@ -751,7 +798,7 @@ class Compiler:
                     boost, constant, k1, b, _bm25.BLOCKMAX)
         cached = self.stats.memo.get(memo_key)
         if cached is not None:
-            return cached
+            return _counted_text_plan(cached)
         ft = self.mapper.get_field(field)
         has_norms = ft is not None and ft.is_text \
             and meta.norm_row(field) is not None
@@ -763,20 +810,23 @@ class Compiler:
         # host↔device link per query. The field's norms travel with its
         # posting blocks (device image `post_norm`), so no norms row is named
         ids, ws, tids = [], [], []
+        weightless = False
         for t_i, (term, w) in enumerate(weighted_terms):
             tm = seg.get_term(field, term)
             if tm is None:
                 continue
+            weightless |= w == 0.0
             for blk_i in range(tm.start_block, tm.start_block + tm.num_blocks):
                 ids.append(blk_i)
                 ws.append(w)
                 tids.append(t_i)
         qb = pad_bucket(max(len(ids), 1), minimum=8)
         pad = qb - len(ids)
+        avgdl_eff = avgdl if avgdl > 0 else 1.0
         inputs = {
             "ids": _i32(ids + [-1] * pad),    # -1 = padding lane (no hit)
             "w": _f32(ws + [0.0] * pad),
-            "avgdl": _f32(avgdl if avgdl > 0 else 1.0),
+            "avgdl": _f32(avgdl_eff),
             "b": _f32(b_eff),
             "k1": _f32(k1),
             "min_hits": _i32(min_hits),
@@ -791,11 +841,20 @@ class Compiler:
                 self._blockmax_scale(seg, field, k1, b_eff, avgdl))
         # static records the distinct-term count: the candidate-buffer
         # kernel needs the max run length (= clause terms containing a doc)
-        # to window its exact segment-sum (executor.py)
-        plan = Plan("text", static=(bool(constant), len(weighted_terms)),
+        # to window its exact segment-sum (executor.py); and whether the
+        # dense kernel may take the matches from the scores (one scatter,
+        # not two). Every weight counts, a term this segment lacks too:
+        # the flag must not differ between the rows of one SPMD request.
+        # `weightless`: a posting here under a weight of 0.0 (statistics
+        # older than the segment) matches and scores nothing: counted
+        score_only = not weightless and text_clause_score_only(
+            [w for _, w in weighted_terms], min_hits, constant, boost, k1,
+            b_eff, avgdl_eff)
+        plan = Plan("text", static=(bool(constant), len(weighted_terms),
+                                    score_only),
                     inputs=inputs, scan_blocks=len(ids))
         self.stats.memo[memo_key] = plan    # RotatingMemo bounds itself
-        return plan
+        return _counted_text_plan(plan)
 
     def _blockmax_scale(self, seg: Segment, field: str, k1: float,
                         b_eff: float, avgdl: float) -> float:

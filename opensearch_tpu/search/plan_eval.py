@@ -85,9 +85,9 @@ def _eval_plan(plan: Plan, seg: Dict, inputs: List[Dict], cursor: List[int]):
         return (jnp.zeros(d_pad, jnp.float32), jnp.zeros(d_pad, jnp.bool_))
 
     if kind == "text":
-        constant = plan.static[0]
-        scores, hits = score_text_clause(seg, my, my["k1"])
-        matches = hits >= my["min_hits"]
+        constant, _, score_only = plan.static
+        scores, matches = score_text_clause(seg, my, my["k1"],
+                                            score_only=score_only)
         if constant:
             scores = jnp.where(matches, my["boost"], 0.0)
         else:

@@ -175,6 +175,67 @@ def test_force_host_loop_is_no_fallback(served):
     assert counters() == before
 
 
+@pytest.fixture(scope="module")
+def notes():
+    """A text index of 8 shards whose rows differ in everything a text
+    plan's traced inputs hold: document count, field length (avgdl), each
+    term's df (`gamma` is in the even shards alone)."""
+    node = start_node({"http.port": 0, "node.name": "spmd-text"})[0]
+    node.request("PUT", "/notes", {
+        "settings": {"number_of_shards": 8},
+        "mappings": {"properties": {"body": {"type": "text"}}}})
+    svc = node.indices.get("notes")
+    for s, shard in enumerate(svc.shards):
+        rng = np.random.default_rng(100 + s)
+        b = SegmentBuilder(svc.mapper, "s0")
+        for i in range(30 + 9 * s):
+            words = [w for w, p in (("alpha", 0.6), ("beta", 0.3),
+                                    ("gamma", 0.2 * (s % 2 == 0)))
+                     if rng.random() < p]
+            words += [f"w{int(x)}" for x in rng.integers(0, 40, 2 + 3 * s)]
+            b.add(svc.mapper.parse_document(f"s{s}-{i}",
+                                            {"body": " ".join(words)}))
+        seg = b.seal()
+        shard.engine.install_segments([seg], max_seq_no=seg.num_docs,
+                                      local_checkpoint=seg.num_docs)
+        shard._sync_reader()
+    return node
+
+
+@pytest.mark.parametrize("match,score_only", [
+    ("alpha gamma beta", True),
+    ({"query": "alpha beta", "operator": "and"}, False),
+    ({"query": "alpha gamma w3", "minimum_should_match": 2}, False)],
+    ids=["default-match", "operator-and", "minimum-should-match-2"])
+def test_a_text_query_over_rows_takes_the_spmd_program(notes, match,
+                                                       score_only):
+    """The `score_only` flag of a text clause (ISSUE 30) is in the rows'
+    plan structure and rests on nothing of a row: every row plans the
+    same flag, so the request is one SPMD program, and its page is the
+    host loop's."""
+    body = {"query": {"match": {"body": match}}, "size": 10}
+    flag = "score_only" if score_only else "counted"
+    planned = TELEMETRY.metrics.counter(f"search.text_clause.{flag}")
+    other = TELEMETRY.metrics.counter(
+        "search.text_clause." + ("counted" if score_only else "score_only"))
+    n_flag, n_other, before = planned.value, other.value, counters()
+    got = notes.request("POST", "/notes/_search", body)
+    after = counters()
+    assert after["search.spmd_queries"] == before["search.spmd_queries"] + 1
+    assert after["search.spmd_fallbacks"] == before["search.spmd_fallbacks"]
+    assert planned.value == n_flag + 8 and other.value == n_other
+    with spmd.force_host_loop():
+        want = notes.request("POST", "/notes/_search", body)
+    assert counters() == after
+    assert got["hits"]["total"] == want["hits"]["total"]
+    assert got["hits"]["total"]["value"] > 10
+    assert [h["_id"] for h in got["hits"]["hits"]] \
+        == [h["_id"] for h in want["hits"]["hits"]]
+    assert [h["_score"] for h in got["hits"]["hits"]] \
+        == pytest.approx([h["_score"] for h in want["hits"]["hits"]],
+                         rel=1e-6)
+
+
 def test_an_spmd_served_request_is_one_row_of_route_spans(served):
     node, server, docs = served
     TELEMETRY.tracer.spans.clear()
