@@ -46,7 +46,7 @@ WEEK_MS = 7 * 86400_000
 TS_BASE = 1700000000000
 # MS MARCO passage v1's passage count (BASELINE config 1)
 MSMARCO_PASSAGES = 8_841_823
-# SCALING_BMX_r01's fast-corpus parameters
+# utils/demo.py fast-corpus parameters: bursty tf, spread doc lengths
 FAST = dict(vocab_size=20000, avg_len=60, materialize_terms=64,
             burst_tf=30, burst_window=256, doc_len_cv=0.5)
 
@@ -654,8 +654,8 @@ class Smoke:
     # ------------------------------------------------------------ vectors
 
     def vectors_load(self):
-        """bench.py's clustered 128-d generator (SIFT-shaped), as one
-        sealed segment."""
+        """A clustered 128-d generator (SIFT-shaped), as one sealed
+        segment."""
         from opensearch_tpu.index.segment import Segment, VectorColumn
         n, dims = self.args.vector_docs, 128
         rng = np.random.RandomState(self.seed + 11)

@@ -6,10 +6,10 @@ Re-design of the reference's search admission stack —
 `HierarchyCircuitBreakerService`'s memory breakers — rebuilt around what
 this node actually measures. The PR 6 gate was a *static permit count*:
 admit until `max_concurrent`, then 429, blind to deadlines, tenants,
-queue depth and device memory. The open-loop baseline (BENCH_CONC_r01)
-shows why that collapses at saturation: every admitted request burns a
-slot until it finishes, so past the knee the node spends its wall
-serving requests that will miss their deadline anyway.
+queue depth and device memory. That collapses at saturation: every
+admitted request burns a slot until it finishes, so past the knee the
+node spends its wall serving requests that will miss their deadline
+anyway.
 
 `AdmissionController` keeps the permit gate as the final stage and
 layers three adaptive stages in FRONT of it, in a fixed pipeline order:
@@ -50,11 +50,10 @@ Every rejection renders the reference-shaped 429 body
 the tenant, and `retry_after_ms` derived from the live rolling queue
 estimate; the REST layer turns that into a real `Retry-After` header.
 
-No-op discipline (gate-lint registry rows; bench.py asserts the running
-instances): the adaptive stages are all OFF by default — `enabled =
-False`, `gate()` returns None — so the default node behaves exactly
-like the PR 6 static permit gate: one attribute load and a branch per
-disabled stage.
+No-op discipline (gate-lint registry rows): the adaptive stages are all
+OFF by default — `enabled = False`, `gate()` returns None — so the
+default node behaves exactly like the PR 6 static permit gate: one
+attribute load and a branch per disabled stage.
 """
 
 from __future__ import annotations

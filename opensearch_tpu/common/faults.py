@@ -11,8 +11,9 @@ module-level `ENABLED` guard:
         faults.fire("query.shard")
 
 The disabled fast path is ONE module attribute load and a falsy test —
-no dict lookups, no allocation, no function call (bench.py asserts this
-no-op identity, the same contract as the PR 4 disabled tracer). With
+no dict lookups, no allocation, no function call (tools/lint's
+gate-lint checks every call site, the same contract as the PR 4
+disabled tracer). With
 rules installed, `fire` consults the per-site rule list and raises /
 sleeps per the schedule.
 

@@ -12,12 +12,8 @@ telemetry/ledger.py): exactly the regions whose transfers a profile's
 decomposition can explain. Calls from tests, tools and bench probes are
 exempt — the contract binds the serving code, not its harnesses.
 
-Wired in two places:
-  - tests/conftest.py enables it for the whole tier-1 run, so ANY new
-    unattributed sync on the query path fails the suite;
-  - `bench.py --sanitize` enables it for a measured run, while the
-    default bench run ASSERTS it is fully uninstalled (the same no-op
-    contract as the tracer/injector/ledger asserts).
+Wired in one place: tests/conftest.py enables it for the whole tier-1
+run, so ANY new unattributed sync on the query path fails the suite.
 
 No-op discipline (gate-lint registered): the sanitizer is OFF by
 default; while disabled nothing is wrapped at all — `jax.device_get` is

@@ -178,10 +178,6 @@ class Node:
             raw = self.settings.get(key)
             return None if raw is None else float(raw)
 
-        def _tel_int(key: str):
-            raw = self.settings.get(key)
-            return None if raw is None else int(raw)
-
         _tail_thr = self.settings.get("telemetry.tail.threshold_ms")
         TELEMETRY.configure(
             data_path=data_path,
@@ -208,16 +204,12 @@ class Node:
             # top-N heavy-query registry, OFF by default like every
             # other gate (POST /_insights/_enable at runtime)
             insights=_tel_bool("telemetry.insights.enabled"),
-            # kernel profiler (ISSUE 19): sampled per-family device
-            # walls OFF by default (the executable census is always-on
-            # and takes no setting); roofline peaks are plain floats so
-            # a TPU node states its real ridge point
-            kernels=_tel_bool("telemetry.kernels.enabled"),
+            # kernel census (ISSUE 19): always on, no gate; the
+            # roofline peaks are plain floats that override the
+            # attached device's row of DEVICE_PEAKS
             kernels_peak_flops=_tel_float(
                 "telemetry.kernels.peak_flops"),
-            kernels_peak_bw=_tel_float("telemetry.kernels.peak_bw"),
-            kernels_sample_every=_tel_int(
-                "telemetry.kernels.sample_every"))
+            kernels_peak_bw=_tel_float("telemetry.kernels.peak_bw"))
         self.controller = RestController()
         from opensearch_tpu.rest.actions import register_all
         register_all(self)

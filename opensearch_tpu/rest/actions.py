@@ -2585,31 +2585,12 @@ def register_telemetry_actions(node, c):
         return {"acknowledged": True}
 
     def do_get_kernels(req):
-        # kernel-level device-compute profiler (ISSUE 19): the
-        # executable census (always-on), per-family sampled device
-        # walls and the roofline table — tools/kernel_report.py input
+        # the executable census (ISSUE 19; always on, compile time
+        # only) and the roofline table from XLA's flop and byte counts;
         # ?scopes=true adds each executable's {HLO instruction ->
         # stage} map, built on first demand (ISSUE 25)
         return {"kernels": TELEMETRY.kernels.snapshot(
             scopes=req.bool_param("scopes"))}
-
-    def do_kernels_enable(req):
-        k = TELEMETRY.kernels
-        every = req.param("sample_every")
-        if every is not None:
-            try:
-                k.sample_every = max(1, int(every))
-            except (TypeError, ValueError):
-                raise IllegalArgumentError(
-                    f"failed to parse [sample_every] with value "
-                    f"[{every!r}]")
-        k.enabled = True
-        return {"acknowledged": True, "enabled": True,
-                "sample_every": k.sample_every}
-
-    def do_kernels_disable(req):
-        TELEMETRY.kernels.enabled = False
-        return {"acknowledged": True, "enabled": False}
 
     def do_kernels_clear(req):
         TELEMETRY.kernels.clear()
@@ -2618,7 +2599,8 @@ def register_telemetry_actions(node, c):
     def do_telemetry_index(req):
         # the gate index (ISSUE 19 satellite): every gated subsystem's
         # enabled state + its REST face in one response — operators see
-        # which of the ten gates are on without probing each endpoint
+        # which of the nine gates are on without probing each endpoint
+        # (the kernel census has no gate: it is always on)
         from opensearch_tpu.common import faults
         subsystems = {
             "tracer": (TELEMETRY.tracer.enabled, "/_telemetry/traces"),
@@ -2633,8 +2615,6 @@ def register_telemetry_actions(node, c):
             "scheduler": (getattr(getattr(node, "wave_scheduler", None),
                                   "enabled", False), "/_scheduler"),
             "faults": (faults.ENABLED, "/_fault_injection"),
-            "kernels": (TELEMETRY.kernels.enabled,
-                        "/_telemetry/kernels"),
         }
         return {"subsystems": {
             name: {"enabled": bool(enabled), "endpoint": ep}
@@ -2696,9 +2676,6 @@ def register_telemetry_actions(node, c):
     c.register("POST", "/_telemetry/devices/_clear", do_devices_clear)
     c.register("GET", "/_telemetry", do_telemetry_index)
     c.register("GET", "/_telemetry/kernels", do_get_kernels)
-    c.register("POST", "/_telemetry/kernels/_enable", do_kernels_enable)
-    c.register("POST", "/_telemetry/kernels/_disable",
-               do_kernels_disable)
     c.register("POST", "/_telemetry/kernels/_clear", do_kernels_clear)
     c.register("GET", "/_insights", do_get_insights)
     c.register("GET", "/_insights/top_queries", do_top_queries)

@@ -191,8 +191,7 @@ class Plan:
     # posting blocks this node's kernel gathers (text clauses: the
     # query terms' real block lanes, padding excluded) — the always-on
     # scanned-bytes counters (telemetry/scan.py, ISSUE 14) read it per
-    # query as blocks × 128 lanes × 8 B, the exact formula
-    # tools/scaling_bench.py evaluates offline. NOT part of sig():
+    # query as blocks × 128 lanes × 8 B. NOT part of sig():
     # it is derived from the same inputs the signature already hashes.
     scan_blocks: int = 0
     # bytes this node's kernel scans OUTSIDE the posting/dense-lane

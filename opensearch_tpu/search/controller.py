@@ -261,12 +261,6 @@ def _note_controller_insights(query_spec, took_ms, req_scope) -> None:
         return
     sp, sd, spr = ins.take_scan()
     dev_ms = req_scope.device_get_ms if req_scope is not None else 0.0
-    # kernel-family join (ISSUE 19): the families the query phase
-    # recorded on this thread, each charged an even share of the
-    # request's device wall — the per-shape dominant-kernel breakdown
-    fams = ins.take_families()
-    kernels = {f: dev_ms / len(fams) for f in fams} \
-        if fams and dev_ms else None
     ins.note(
         label, kind=kind, took_ms=float(took_ms),
         device_ms=dev_ms,
@@ -275,7 +269,7 @@ def _note_controller_insights(query_spec, took_ms, req_scope) -> None:
         d2h_bytes=req_scope.d2h_bytes if req_scope is not None else 0,
         round_trips=req_scope.round_trips
         if req_scope is not None else 0,
-        co_batched=1, tenant=ins.current_tenant(), kernels=kernels)
+        co_batched=1, tenant=ins.current_tenant())
 
 
 def _publish_scope(scope, span, phase_times: Optional[dict]) -> None:
@@ -725,10 +719,6 @@ def _execute_search_impl(executors: List, body: Optional[dict],
                 # not a breakdown scalar: transfers[] per shard is the
                 # ledger's contract with the Profile API
                 shard_transfers = breakdown.pop("transfers", [])
-                # per-shard kernel attribution (ISSUE 19): the kernel
-                # families the shard's program dispatched, with their
-                # device-wall shares — same first-class treatment
-                shard_kernels = breakdown.pop("kernels", [])
                 profile_shards.append({
                     "id": f"[{ex.reader.index_name}][{shard_i}]",
                     "_query_ns": qt.duration_ns,
@@ -740,7 +730,6 @@ def _execute_search_impl(executors: List, body: Optional[dict],
                     }], "rewrite_time": 0, "collector": []}],
                     "aggregations": [],
                     "transfers": shard_transfers,
-                    "kernels": shard_kernels,
                 })
         with _PhaseTimer(trace, phases, "reduce"):
             candidates.sort(key=_compare_candidates(sort_specs))

@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import functools
 import json
-import os
 import queue
 import threading
 import time
@@ -580,9 +579,6 @@ from opensearch_tpu.telemetry.kernels import (  # noqa: E402
     note_compile as _note_compile, offpath_compiles, stage as _stage,
     timed_first_call as _timed_first_call)
 
-# kernel profiler handle (ISSUE 19): census registration is always-on
-# (compile-time only); the sampled dispatch timer rides the gate
-_KERNELS = TELEMETRY.kernels
 # the always-on span ring (telemetry/tracer.py, ISSUE 25): the envelope
 # and its waves record completed spans from the clock reads the
 # msearch.phase.* histograms already make
@@ -597,8 +593,8 @@ _SEARCH_PHASE_HISTS = {
 
 
 def _plan_family(plan: Plan, agg_plans=()) -> str:
-    """Kernel-family label for one compiled plan tree (the census/
-    timing vocabulary, telemetry/kernels.py): vector leaves win (their
+    """Kernel-family label for one compiled plan tree (the census'
+    vocabulary, telemetry/kernels.py): vector leaves win (their
     kernels dominate the program), then the agg envelope, then the
     dense BM25 kernel build_query_phase lowers to."""
     def walk(p):
@@ -650,8 +646,8 @@ def _plan_cost(plan: Plan, meta, batch: int = 1):
 
 # msearch phase accounting (?profile analog for the batch path): per-batch
 # milliseconds land in the always-on telemetry metrics registry as
-# per-phase histograms — visible on _nodes/stats, `bench.py --telemetry`
-# and tools/profile_host.py (replaces the old module-global accumulator)
+# per-phase histograms — visible on _nodes/stats and
+# tools/profile_host.py (replaces the old module-global accumulator)
 MSEARCH_PHASE_NAMES = ("parse", "compile_group", "stack_pack_dispatch",
                        "device_get", "respond")
 _PHASE_HISTS = {name: TELEMETRY.metrics.histogram(f"msearch.phase.{name}_ms")
@@ -659,10 +655,9 @@ _PHASE_HISTS = {name: TELEMETRY.metrics.histogram(f"msearch.phase.{name}_ms")
 
 # query-template interning (ISSUE 5): repeated-structure msearch batches
 # skip parse+compile via the per-reader (template, literals) bundle memo.
-# The env switch exists for A/B parity testing (tests/
-# test_template_interning.py), not as a serving configuration.
-TEMPLATE_INTERNING = os.environ.get(
-    "OPENSEARCH_TPU_DISABLE_INTERNING") != "1"
+# A/B parity tests (tests/test_template_interning.py) set the attribute;
+# it is no serving configuration.
+TEMPLATE_INTERNING = True
 _BUNDLE_HITS = TELEMETRY.metrics.counter("msearch.template.bundle_hits")
 _BUNDLE_MISSES = TELEMETRY.metrics.counter("msearch.template.bundle_misses")
 _INTERN_FALLBACKS = TELEMETRY.metrics.counter("msearch.template.fallbacks")
@@ -678,13 +673,8 @@ _INTERN_FALLBACKS = TELEMETRY.metrics.counter("msearch.template.fallbacks")
 # sizes stay power-of-two buckets so the warmup registry's (plan-struct,
 # shape-bucket, b_pad) signatures are reused across wave splits.
 
-# bench --waves / tests override; 0/None = the auto policy below.
-# OPENSEARCH_TPU_MSEARCH_WAVES seeds it for whole-process A/B runs.
-try:
-    FORCED_WAVES: Optional[int] = int(os.environ.get(
-        "OPENSEARCH_TPU_MSEARCH_WAVES", "0")) or None
-except ValueError:
-    FORCED_WAVES = None
+# tests set it; None = the auto policy below
+FORCED_WAVES: Optional[int] = None
 
 # below 2× this many batchable items a split cannot win: each extra wave
 # is an extra device_get round trip, and the host work it could hide is
@@ -716,7 +706,7 @@ def _overlap_capable() -> bool:
 
 def _effective_waves(n_batchable: int) -> int:
     """Wave-count policy for an envelope of `n_batchable` items:
-    FORCED_WAVES (bench --waves / env / tests) always wins; otherwise
+    FORCED_WAVES (tests) always wins; otherwise
     split only when every wave keeps MSEARCH_MIN_WAVE_ITEMS rows and
     the backend can actually overlap (see _overlap_capable)."""
     if FORCED_WAVES:
@@ -1715,10 +1705,6 @@ def _agg_envelope_runner(plan_sig, plan: Plan, meta: DeviceSegmentMeta,
             fn, family="agg_env", shape=_env_shape(layout, k, meta),
             key=key, cost=_plan_cost(plan, meta, _layout_batch(layout)))
         return (wrapped, out_layout, width)
-    kp = _KERNELS.gate()
-    if kp is not None:
-        return (kp.timed(hit[0], "agg_env", _env_shape(layout, k, meta)),
-                hit[1], hit[2])
     return hit
 
 
@@ -1784,11 +1770,6 @@ def _envelope_runner(plan_sig, plan: Plan, meta: DeviceSegmentMeta, k: int,
         return _timed_first_call(
             fn, family=fam, shape=_env_shape(layout, k, meta), key=key,
             cost=_plan_cost(plan, meta, _layout_batch(layout)))
-    kp = _KERNELS.gate()
-    if kp is not None:
-        fam = "bm25_candidate" \
-            if _envelope_kernel(plan) == "candidate" else _plan_family(plan)
-        return kp.timed(fn, fam, _env_shape(layout, k, meta))
     return fn
 
 
@@ -1849,10 +1830,6 @@ def _runner(plan_sig, plan: Plan, meta: DeviceSegmentMeta, k: int, sort_mode: st
            tuple(a.sig() for a in agg_plans))
     fn = _JIT_CACHE.get(key)
     if fn is not None:
-        kp = _KERNELS.gate()
-        if kp is not None:
-            return kp.timed(fn, _plan_family(plan, agg_plans),
-                            f"k{k}/d{meta.d_pad}/{sort_mode}")
         return fn
     fam = _plan_family(plan, agg_plans)
     fn = jit_family(build_query_phase(plan, meta, k, sort_mode, agg_plans),
@@ -1948,9 +1925,6 @@ def _batched_hybrid_runner(plans, meta: DeviceSegmentMeta, k: int,
             fn, family="hybrid_env", shape=_env_shape(layout, k, meta),
             key=key, cost=(sum(c[0] for c in cost),
                            sum(c[1] for c in cost)))
-    kp = _KERNELS.gate()
-    if kp is not None:
-        return kp.timed(fn, "hybrid_env", _env_shape(layout, k, meta))
     return fn
 
 
@@ -2133,10 +2107,6 @@ def _page_merger(sig, mode, k_page: int, stride: int, seg_statics,
     device column refs and returns ONE packed int32 page."""
     fn = _JIT_CACHE.get(sig)
     if fn is not None:
-        kp = _KERNELS.gate()
-        if kp is not None:
-            return kp.timed(fn, "page_merger",
-                            f"k{k_page}/s{stride}/n{len(seg_statics)}")
         return fn
     field_mode = mode[0] == "field"
     order = mode[2] if field_mode else None
@@ -2429,12 +2399,6 @@ class SearchExecutor:
             plan_scan_blocks, plan_scan_extra)
         scan_shard = str(getattr(self.reader, "shard_id", 0))
         q_posting = q_dense = 0
-        # kernel-family attribution (ISSUE 19): resolved from the first
-        # compiled plan only when a consumer wants it — the insights
-        # per-shape breakdown, the profiler, or a recording trace (the
-        # Profile API's per-shard `kernels` entry)
-        q_family = None
-        _want_family = rec or _INSIGHTS.enabled or _KERNELS.enabled
         from opensearch_tpu.indices.query_cache import FilterCacheContext
         for seg_i, (seg, (arrays, meta)) in enumerate(
                 zip(segments, device)):
@@ -2449,8 +2413,6 @@ class SearchExecutor:
                                      meta, compiler) if agg_nodes else []
             if rec:
                 plan_compile_ns += time.perf_counter_ns() - t0
-            if _want_family and q_family is None:
-                q_family = _plan_family(plan, agg_plans)
             # always-on scan accounting (telemetry/scan.py, ISSUE 14):
             # this path runs the DENSE kernel (build_query_phase) —
             # posting blocks gathered per the plan statics plus the
@@ -2509,10 +2471,6 @@ class SearchExecutor:
                 # the heat map just counted, accumulated thread-locally
                 # for the controller's per-shape note at request end
                 ins.add_scan(q_posting, q_dense)
-                if q_family is not None:
-                    # kernel-family join (ISSUE 19): same thread-local
-                    # carry, read back by _note_controller_insights
-                    ins.add_family(q_family)
 
         page_args = None
         if page_rows is not None and launched:
@@ -2558,19 +2516,6 @@ class SearchExecutor:
                 trace.set_attribute("bytes_to_device", scope.h2d_bytes)
                 trace.set_attribute("bytes_fetched", scope.d2h_bytes)
                 trace.set_attribute("transfers", scope.to_list())
-                if q_family is not None:
-                    # Profile API per-shard kernel attribution (ISSUE
-                    # 19): the shard's device wall against the family
-                    # that owns its program (+ the page merger when the
-                    # single-round-trip page assembled the response)
-                    fams = [q_family]
-                    if page_args is not None:
-                        fams.append("page_merger")
-                    trace.set_attribute("kernels", [
-                        {"family": f,
-                         "device_ms": round(
-                             scope.device_get_ms / len(fams), 3)}
-                        for f in fams])
                 trace.set_attribute("compiled", xla_compiles > 0)
                 if xla_compiles:
                     trace.set_attribute("xla_compiles", xla_compiles)
@@ -3423,12 +3368,6 @@ class SearchExecutor:
                     pruned_bytes=m.get("pruned", 0),
                     h2d_bytes=eh, d2h_bytes=ed, round_trips=er,
                     co_batched=co,
-                    # kernel-family breakdown (ISSUE 19): the item's
-                    # device-wall share against the family its group
-                    # program dispatched — the per-shape dominant-kernel
-                    # join GET /_insights/top_queries surfaces
-                    kernels={m["family"]: item_dev}
-                    if item_dev and m.get("family") else None,
                     # warm=None (hybrid) = no bundle verdict exists:
                     # count neither compiled nor warm
                     compiled=m["warm"] is False,
@@ -3649,8 +3588,7 @@ class SearchExecutor:
                 ins_items[i] = {
                     "label": structural_shape(body.get("query")),
                     "kind": "hash", "posting": 0, "dense": 0,
-                    "grouped": True, "warm": None, "interned": False,
-                    "family": "hybrid_env"}
+                    "grouped": True, "warm": None, "interned": False}
             struct = tuple(
                 tuple(p.sig() for p in plans) if plans is not None
                 else None for plans in plans_per_seg)
@@ -4053,22 +3991,10 @@ class SearchExecutor:
                 sp, sd = _scan_per_query[-1] \
                     if len(_scan_per_query) > n_scan0 else (0, 0)
                 label, kind = _item_shape(node, body)
-                plan0 = next((p for p in plans if p is not None), None)
-                fam = None
-                if plan0 is not None:
-                    # kernel family for the insights breakdown (ISSUE
-                    # 19): agg-bearing items dispatch the agg envelope;
-                    # plain items the candidate/dense kernel the runner
-                    # will pick (same predicate)
-                    fam = "agg_env" if agg_nodes else (
-                        "bm25_candidate"
-                        if _envelope_kernel(plan0) == "candidate"
-                        else _plan_family(plan0))
                 ins_items[i] = {"label": label, "kind": kind,
                                 "posting": sp, "dense": sd,
                                 "grouped": True, "warm": bundle_hit,
-                                "interned": tpl is not None,
-                                "family": fam}
+                                "interned": tpl is not None}
 
         from opensearch_tpu.telemetry.scan import SCAN
         SCAN.note_batch(self.reader.index_name,

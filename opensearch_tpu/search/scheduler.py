@@ -1,10 +1,9 @@
 """Async wave scheduler: coalesce concurrent users into shared device
 waves.
 
-ROADMAP item 1's centerpiece. The committed open-loop baseline
-(BENCH_CONC_r01.json) quantifies the prize: 8 concurrent clients each
-paying a full B=1 dispatch get a fraction of what the same box does
-when independent requests ride ONE interned envelope — the
+ROADMAP item 1's centerpiece. Concurrent clients each paying a full
+B=1 dispatch get a fraction of what the same box does when independent
+requests ride ONE interned envelope — the
 O(unique-templates) batched path PR 5 built and PR 9 turned into a
 double-buffered wave pipeline. Every request the REST layer serves
 inline burns a full dispatch; this module makes independent users
@@ -64,10 +63,9 @@ Invariants (pinned by tests/test_scheduler.py + tools/chaos_sweep.py):
     disabling the scheduler dispatches every queued request before the
     thread exits (no stranded waiter).
 
-No-op discipline (gate-lint registry row; bench.py asserts the running
-instance): `enabled = False` by default and `gate()` returns None —
-the disabled query path costs one attribute load and a branch, and the
-disabled scheduler owns no thread.
+No-op discipline (gate-lint registry row): `enabled = False` by default
+and `gate()` returns None — the disabled query path costs one attribute
+load and a branch, and the disabled scheduler owns no thread.
 """
 
 from __future__ import annotations

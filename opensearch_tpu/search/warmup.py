@@ -22,9 +22,9 @@ executable-level:
   says, else a fixed directory in the checkout), so a replayed compile
   after restart is a disk hit, not a fresh HLO build.
 
-Warmup stats surface on _nodes/stats (rest/actions.py) and bench.py
-reports warmup time as its own field — compile cost is moved off the
-query path and accounted for, never hidden.
+Warmup stats surface on _nodes/stats (rest/actions.py) and the
+benchmark reports warm-up time as its own metric (`warmup_s`) — compile
+cost is moved off the query path and accounted for, never hidden.
 """
 
 from __future__ import annotations
@@ -376,11 +376,10 @@ class Precompiler:
     cache misses), then flips the pending churn verdicts to
     `precompiled` via the ledger's verdict lifecycle.
 
-    No-op discipline (gate-lint row, bench.py pristine assert): OFF by
-    default, `gate()` returns None when disabled — the refresh path
-    pays one attribute load + branch. `POST /_warmup/_precompile`
-    (sweep()) works even while disabled: it is an explicit operator
-    trigger, not the hot path."""
+    No-op discipline (gate-lint row): OFF by default, `gate()` returns
+    None when disabled — the refresh path pays one attribute load +
+    branch. `POST /_warmup/_precompile` (sweep()) works even while
+    disabled: it is an explicit operator trigger, not the hot path."""
 
     def __init__(self):
         self.enabled = False

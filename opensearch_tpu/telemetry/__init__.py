@@ -32,7 +32,7 @@ Node wires it from settings (`telemetry.tracing.enabled`,
 `telemetry.tracing.ring_size`, `telemetry.tracing.jsonl`,
 `telemetry.transfers.enabled`, `telemetry.tail.enabled`,
 `telemetry.tail.threshold_ms`) and the data dir (`_state/traces.jsonl`,
-`_state/tail.jsonl`); tests and bench.py drive it directly.
+`_state/tail.jsonl`); tests drive it directly.
 """
 
 from __future__ import annotations
@@ -94,9 +94,8 @@ class TelemetryService:
         # None-returning gate() — the "which queries cost what" join
         # over interning + lifecycle + scan + ledger
         self.insights = INSIGHTS
-        # kernel profiler (ISSUE 19): executable census (always-on,
-        # compile-time-only writes) + gated sampled device walls +
-        # roofline classification per kernel family
+        # kernel census (ISSUE 19): executable records written at
+        # compile time only + roofline classification per kernel family
         self.kernels = KERNELS
 
     def configure(self, data_path: Optional[str] = None,
@@ -108,10 +107,8 @@ class TelemetryService:
                   devices: bool = False,
                   spmd_timeline: bool = False,
                   insights: bool = False,
-                  kernels: bool = False,
                   kernels_peak_flops: Optional[float] = None,
-                  kernels_peak_bw: Optional[float] = None,
-                  kernels_sample_every: Optional[int] = None) -> None:
+                  kernels_peak_bw: Optional[float] = None) -> None:
         """Bind to a node's settings/data dir. Called from Node.__init__;
         re-configuration by a later Node in the same process wins (the
         singleton is process-wide, like WARMUP)."""
@@ -124,13 +121,10 @@ class TelemetryService:
         self.device_ledger.enabled = bool(devices)
         self.spmd_timeline.enabled = bool(spmd_timeline)
         self.insights.enabled = bool(insights)
-        self.kernels.enabled = bool(kernels)
         if kernels_peak_flops is not None:
             self.kernels.peak_flops = float(kernels_peak_flops)
         if kernels_peak_bw is not None:
             self.kernels.peak_bw = float(kernels_peak_bw)
-        if kernels_sample_every is not None:
-            self.kernels.sample_every = max(1, int(kernels_sample_every))
         self.tracer.resize(ring_size)
         self.tracer.jsonl_path = None
         self.flight.jsonl_path = None

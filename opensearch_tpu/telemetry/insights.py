@@ -39,11 +39,10 @@ queries and candidate-kernel queries have ~10× different scan profiles
 recorder is that input, live.
 
 No-op discipline (the tracer/ledger/faults/flight contract, gate-lint
-registry row, asserted pristine by bench.py): OFF by default, `gate()`
-returns None — the disabled query path costs one attribute load and a
-branch per sub-request. Enabled cost is one lock + dict adds per
-completed sub-request (no per-hit or per-lane work), gated <2% by the
-analytic overhead check in bench.py --insights.
+registry row): OFF by default, `gate()` returns None — the disabled
+query path costs one attribute load and a branch per sub-request.
+Enabled cost is one lock + dict adds per completed sub-request (no
+per-hit or per-lane work).
 
 The same shape vocabulary also prices admission: the shape-aware
 `DeadlineShedder` pricing (common/admission.py, its own OFF-by-default
@@ -181,7 +180,7 @@ def _new_row(kind: str) -> dict:
             "h2d_bytes": 0, "d2h_bytes": 0, "round_trips": 0,
             "co_batched_sum": 0, "co_batched_max": 0, "coalesced": 0,
             "compiled": 0, "warm_hits": 0,
-            "tenants": {}, "kernels": {},
+            "tenants": {},
             "took": RollingEstimator(), "device": RollingEstimator()}
 
 
@@ -260,25 +259,6 @@ class QueryInsights:
         t.scan_pr = 0
         return out
 
-    def add_family(self, family: str) -> None:
-        """Accumulate one kernel-family label for the CURRENT request
-        (ISSUE 19): the executor's query phase records which family its
-        dispatched program belongs to; the controller's note point
-        reads it back on the same thread and splits the request's
-        device wall across the recorded families."""
-        t = self._tls
-        fams = getattr(t, "families", None)
-        if fams is None:
-            fams = t.families = []
-        if family not in fams:
-            fams.append(family)
-
-    def take_families(self) -> Tuple[str, ...]:
-        t = self._tls
-        out = tuple(getattr(t, "families", ()) or ())
-        t.families = None
-        return out
-
     # ------------------------------------------------------------- hot path
 
     def note(self, shape: str, kind: str = "template",
@@ -289,8 +269,7 @@ class QueryInsights:
              round_trips: int = 0, co_batched: int = 1,
              compiled: bool = False, warm_hit: bool = False,
              cached: bool = False, tenant: Optional[str] = None,
-             status: str = "ok",
-             kernels: Optional[Dict[str, float]] = None) -> None:
+             status: str = "ok") -> None:
         """Attribute one COMPLETED sub-request to its shape class. One
         lock acquire + dict adds; the two rolling estimators observe
         outside the lock (they carry their own)."""
@@ -330,12 +309,6 @@ class QueryInsights:
                 row["compiled"] += 1
             if warm_hit:
                 row["warm_hits"] += 1
-            if kernels:
-                # per-shape kernel-family device-ms breakdown (ISSUE
-                # 19): which executable family owns this shape's cost
-                krow = row["kernels"]
-                for fam, ms in kernels.items():
-                    krow[fam] = krow.get(fam, 0.0) + float(ms)
             t = tenant or "_default"
             tenants = row["tenants"]
             if t not in tenants and len(tenants) >= MAX_TENANTS_PER_SHAPE:
@@ -409,11 +382,6 @@ class QueryInsights:
             "compiled": row["compiled"],
             "warm_hits": row["warm_hits"],
             "tenants": dict(sorted(row["tenants"].items())),
-            "kernels": {f: round(ms, 3)
-                        for f, ms in sorted(row["kernels"].items())},
-            "dominant_kernel": max(row["kernels"],
-                                   key=row["kernels"].get)
-            if row["kernels"] else None,
         }
 
     def snapshot(self, top: bool = False) -> dict:

@@ -1,58 +1,43 @@
-"""Kernel-level device-compute profiler (ISSUE 19): executable census,
-XLA cost/roofline ledger, and per-family device-time attribution.
+"""Kernel-level executable census and the device's naming vocabulary
+(ISSUE 19, 25).
 
-The five committed observability layers measure host walls, transfer
-bytes, scan bytes and per-chip partials — nothing attributes device
-compute to the EXECUTABLES that spend it. This module is that sixth
-layer, in three parts:
+Kernel TIME has one source, the device trace the benchmark reads
+(`benchmark/`): this module takes no clock on the serving path. What it
+keeps is what a trace needs to be read, in two parts:
 
-1. **Executable census (always-on).** Every JIT-cache miss registers an
-   executable record — kernel-family label, cache-key fingerprint,
-   shape bucket, synchronous compile wall — harvested inside the
-   existing first-call timing wrapper (`timed_first_call`, moved here
-   from search/executor.py so the ops-layer jit sites can reach it
-   without an import cycle). Static cost comes from XLA's own
-   `lowered.cost_analysis()` (flops / bytes accessed, captured without
-   a second compile) where the backend provides it, and from the
-   analytic scan formulas (telemetry/scan.py) where it does not; the
-   `cost_source` field says which. Census writes happen ONLY at compile
-   time — the steady state (cache hit) takes no lock and allocates
-   nothing, the same discipline the <2% gate demands of every layer.
-   Census `compile_ms` totals reconcile with the always-on
+1. **Executable census (always-on, compile time only).** Every
+   JIT-cache miss registers an executable record — kernel-family label,
+   cache-key fingerprint, shape bucket, synchronous compile wall —
+   harvested inside the first-call timing wrapper (`timed_first_call`,
+   here so the ops-layer jit sites can reach it without an import
+   cycle). Static cost comes from XLA's own `lowered.cost_analysis()`
+   (flops / bytes accessed, captured without a second compile) where the
+   backend provides it, and from the analytic scan formulas
+   (telemetry/scan.py) where it does not; the `cost_source` field says
+   which. Census writes happen ONLY at compile time — a cache hit
+   returns the cached executable itself, takes no lock and allocates
+   nothing. Census `compile_ms` totals reconcile with the always-on
    `search.xla_compile_ms` histogram by construction: both are fed by
    the SAME `note_compile` call on the same wrapper.
 
-2. **Gated timed dispatch (`telemetry.kernels.enabled`, OFF by
-   default).** When on, runners wrap their cached executables in a
-   sampling timer: every Nth dispatch per family (``sample_every``)
-   runs synchronously under `jax.block_until_ready` and feeds a rolling
-   p50/p99 (telemetry/rolling.py) plus a per-family device-ms ledger.
-   The block is a measurement mechanism, not overhead — the wave's
-   result pull would absorb those waits — and sampling bounds the lost
-   dispatch overlap. Scaled totals (`sampled_ms * calls / sampled`)
-   conserve against the transfer ledger's wave collect walls: they
-   explain at least 90% of the clean-run collect wall (bench.py
-   asserts this per workload); any excess is the async pipeline's
-   dispatch/host overlap made measurable — the timer sees TOTAL
-   compute, the collect only the part no host work hid.
+2. **Roofline classification.** Arithmetic intensity flops/bytes (XLA's
+   counts; no clock in it) vs the ridge of the attached device's
+   published peaks (`DEVICE_PEAKS`, keyed by `device_kind`;
+   `telemetry.kernels.peak_flops` / `peak_bw` override) marks each
+   family compute- vs memory-bound. A device that is not in the table
+   is not classified.
 
-3. **Roofline classification.** Arithmetic intensity flops/bytes vs the
-   ridge of the attached device's published peaks (`DEVICE_PEAKS`, keyed
-   by `device_kind`; `telemetry.kernels.peak_flops` / `peak_bw` override)
-   marks each family compute- vs memory-bound. A device that is not in
-   the table is not classified.
-
-Kernel-family vocabulary (the label every census/timing row carries):
+Kernel-family vocabulary (the label every census row carries):
 ``bm25_candidate`` / ``bm25_dense`` (the two envelope kernels),
 ``agg_env`` (fused agg envelope + agg-bearing general path),
 ``hybrid_env`` (fused hybrid envelope), ``page_merger`` (single-round-
 trip result page), ``knn`` (vector scoring + IVF k-means build),
 ``maxsim`` / ``maxsim_adc`` (late-interaction exact / PQ-fused),
-``expand`` (delta-publish decompressors).
+``expand`` (delta-publish decompressors), ``spmd_query_phase`` (the
+SPMD program of parallel/distributed.py).
 
-Surfaced via `GET /_telemetry/kernels` (+ `_enable`/`_disable`/
-`_clear`), the `kernels` block of `GET /_nodes/stats`, Profile API
-per-shard `kernels` entries, and tools/kernel_report.py.
+Surfaced via `GET /_telemetry/kernels` (`?scopes=true`; `_clear`) and
+the `kernels` block of `GET /_nodes/stats`.
 
 **Names on the device (ISSUE 25).** This module also owns the two
 vocabularies a device trace is read by. `jit_family` names the function
@@ -85,9 +70,7 @@ import re
 import threading
 import time
 from contextlib import contextmanager
-from typing import Any, Callable, Dict, List, Optional, Tuple
-
-from opensearch_tpu.telemetry.rolling import RollingEstimator
+from typing import Any, Dict, List, Optional, Tuple
 
 KERNEL_FAMILIES = ("bm25_candidate", "bm25_dense", "agg_env",
                    "hybrid_env", "page_merger", "knn", "maxsim",
@@ -105,7 +88,6 @@ MAX_CENSUS_ENTRIES = 2048
 DEVICE_PEAKS: Dict[str, Tuple[float, float]] = {
     "TPU v5 lite": (197.0e12, 819.0e9),
 }
-DEFAULT_SAMPLE_EVERY = 16
 
 # the stages a device program is cut into (`stage` below). Text, dense
 # and candidate kernels: postings_gather (block lanes, tf, norms),
@@ -460,22 +442,11 @@ def _arg_struct(a):
         if hasattr(a, "dtype") else a
 
 
-def _family_row() -> dict:
-    return {"calls": 0, "sampled": 0, "sampled_ms": 0.0,
-            "est": RollingEstimator(), "shapes": {}}
-
-
 class KernelProfiler:
-    """The sixth gated observability layer (see module docstring).
-
-    Census methods are always-on but only run at compile time; the
-    per-dispatch timing rides the None-returning `gate()` discipline —
-    disabled, the hot path pays one attribute load and a branch, and
-    executables are returned UNWRAPPED (no timer closure at all)."""
+    """The executable census and its roofline classes (see module
+    docstring). Always on; every write happens at compile time."""
 
     def __init__(self):
-        self.enabled = False
-        self.sample_every = DEFAULT_SAMPLE_EVERY
         # None = take the device's row of DEVICE_PEAKS; a float is the
         # operator's override (telemetry.kernels.peak_* settings)
         self.peak_flops: Optional[float] = None
@@ -488,17 +459,6 @@ class KernelProfiler:
         # {instruction -> stage} map once built
         self._lowerable: Dict[str, tuple] = {}
         self._scopes: Dict[str, Dict[str, str]] = {}
-        self._exec_lock = threading.Lock()
-        self._families: Dict[str, dict] = {}
-
-    # ------------------------------------------------------------- gate
-
-    def gate(self) -> Optional["KernelProfiler"]:
-        """None when disabled — callers guard with `if k is not None`,
-        so the default query path never builds a timer closure."""
-        if not self.enabled:
-            return None
-        return self
 
     # ----------------------------------------------------------- census
 
@@ -604,60 +564,6 @@ class KernelProfiler:
         with self._census_lock:
             return {**failed, **self._scopes}
 
-    # ----------------------------------------------------------- timing
-
-    def timed(self, fn: Callable, family: str, shape: str = ""):
-        """Wrap a cached executable in the sampling timer (enabled path
-        only — reached through `gate()`). Every call counts; every Nth
-        call per family runs synchronously under block_until_ready and
-        feeds the rolling estimator + the per-family sampled-ms ledger."""
-
-        def run(*args):
-            if not self._tick(family, shape):
-                return fn(*args)
-            t0 = time.perf_counter_ns()
-            out = fn(*args)
-            import jax
-            from opensearch_tpu.telemetry import TELEMETRY
-            # the sampled sync is ledger-owned measurement by
-            # construction (PR 7 sanitizer contract): the wave's result
-            # pull would absorb this wait if the timer didn't take it
-            with TELEMETRY.ledger.attributed():
-                jax.block_until_ready(out)  # sync-ok: kernels.sample -- gated sampling timer owns this wall
-            self._note_exec(family, shape,
-                            (time.perf_counter_ns() - t0) / 1e6)
-            return out
-
-        run.exec_info = getattr(fn, "exec_info", None)
-        return run
-
-    def _tick(self, family: str, shape: str) -> bool:
-        """Count one dispatch; True when this call is the sampled one.
-        Deterministic (call-count modulus, first call always sampled) so
-        tests can pin the sample schedule under threaded load."""
-        with self._exec_lock:
-            row = self._families.get(family)
-            if row is None:
-                row = self._families[family] = _family_row()
-            row["calls"] += 1
-            srow = row["shapes"].get(shape)
-            if srow is None:
-                srow = row["shapes"][shape] = {
-                    "calls": 0, "sampled": 0, "sampled_ms": 0.0}
-            srow["calls"] += 1
-            n = max(1, int(self.sample_every))
-            return (row["calls"] - 1) % n == 0
-
-    def _note_exec(self, family: str, shape: str, ms: float) -> None:
-        with self._exec_lock:
-            row = self._families[family]
-            row["sampled"] += 1
-            row["sampled_ms"] += ms
-            srow = row["shapes"][shape]
-            srow["sampled"] += 1
-            srow["sampled_ms"] += ms
-        row["est"].observe(ms)
-
     # ---------------------------------------------------------- reading
 
     def _census_by_family(self) -> Dict[str, dict]:
@@ -704,62 +610,25 @@ class KernelProfiler:
     def snapshot(self, census: bool = True, scopes: bool = False) -> dict:
         """The `GET /_telemetry/kernels` body (and, with census=False,
         the compact `_nodes/stats` block): per-family census aggregates
-        + roofline verdicts + (when timing ran) sampled device walls
-        with the scaled total estimate. `scopes` adds each census
-        executable's {instruction -> stage} map (`?scopes=true`)."""
-        by_fam = self._census_by_family()
+        and roofline verdicts. `scopes` adds each census executable's
+        {instruction -> stage} map (`?scopes=true`)."""
         peak_flops, peak_bw = self.peaks()
         ridge = peak_flops / max(peak_bw, 1.0) \
             if peak_flops is not None and peak_bw is not None else None
-        with self._exec_lock:
-            fams = {f: {"calls": r["calls"], "sampled": r["sampled"],
-                        "sampled_ms": r["sampled_ms"],
-                        "shapes": {s: dict(sr)
-                                   for s, sr in r["shapes"].items()},
-                        "est": r["est"]}
-                    for f, r in self._families.items()}
         families = {}
-        for fam in sorted(set(by_fam) | set(fams)):
-            agg = by_fam.get(fam)
-            run = fams.get(fam)
-            flops = agg["flops"] if agg else None
-            nbytes = agg["bytes"] if agg else None
-            ai, bound = self._roofline(flops, nbytes, ridge)
-            row = {"compiles": agg["compiles"] if agg else 0,
-                   "compile_ms": round(agg["compile_ms"], 3)
-                   if agg else 0.0,
-                   "flops": flops, "bytes": nbytes,
-                   "arithmetic_intensity": round(ai, 4)
-                   if ai is not None else None,
-                   "bound": bound,
-                   "calls": run["calls"] if run else 0,
-                   "sampled": run["sampled"] if run else 0,
-                   "sampled_ms": round(run["sampled_ms"], 3)
-                   if run else 0.0}
-            if run and run["sampled"]:
-                # scaled estimate: sampled walls extrapolated over every
-                # dispatch — the number that conserves (within the bench
-                # bound) against the ledger's wave collect walls
-                row["device_ms_est"] = round(
-                    run["sampled_ms"] * run["calls"] / run["sampled"], 3)
-                row["p50_ms"] = _round(run["est"].quantile(0.5))
-                row["p99_ms"] = _round(run["est"].quantile(0.99))
-                row["shapes"] = {
-                    s: {"calls": sr["calls"], "sampled": sr["sampled"],
-                        "sampled_ms": round(sr["sampled_ms"], 3),
-                        "device_ms_est": round(
-                            sr["sampled_ms"] * sr["calls"]
-                            / sr["sampled"], 3) if sr["sampled"] else 0.0}
-                    for s, sr in run["shapes"].items()}
-            families[fam] = row
+        for fam, agg in sorted(self._census_by_family().items()):
+            ai, bound = self._roofline(agg["flops"], agg["bytes"], ridge)
+            families[fam] = {
+                "compiles": agg["compiles"],
+                "compile_ms": round(agg["compile_ms"], 3),
+                "flops": agg["flops"], "bytes": agg["bytes"],
+                "arithmetic_intensity": _round(ai), "bound": bound}
         with self._census_lock:
             n_census = len(self._census)
             dropped = self._census_dropped
             compile_total = sum(r["compile_ms"] for r in self._census)
             dump = list(self._census) if census else None
-        out = {"enabled": self.enabled,
-               "sample_every": self.sample_every,
-               "peak_flops": peak_flops, "peak_bw": peak_bw,
+        out = {"peak_flops": peak_flops, "peak_bw": peak_bw,
                "ridge_intensity": _round(ridge),
                "census": {"entries": n_census, "dropped": dropped,
                           "compile_ms_total": round(compile_total, 3)},
@@ -777,14 +646,12 @@ class KernelProfiler:
         return self.snapshot(census=False)
 
     def clear(self) -> None:
-        """Drop census + timing state (config and gate flag survive)."""
+        """Drop the census (the peak overrides survive)."""
         with self._census_lock:
             self._census = []
             self._census_dropped = 0
             self._lowerable = {}
             self._scopes = {}
-        with self._exec_lock:
-            self._families = {}
 
 
 def _round(v: Optional[float]) -> Optional[float]:

@@ -26,7 +26,7 @@ This module is that accounting contract:
   provides it.
 
 No-op discipline (same contract as the PR 4 tracer and the PR 6 fault
-injector, asserted by bench.py): the ledger is OFF by default and the
+injector, checked by tools/lint): the ledger is OFF by default and the
 hot-path guard is `LEDGER.scope(trace)` returning None — one attribute
 load and a branch, nothing else runs. Per-channel `round_trips` counts
 the transfer rounds a channel RODE (channels sharing one fused
@@ -150,7 +150,7 @@ class DeviceLedger:
     against, surfaced as `telemetry.devices` on `_nodes/stats`.
 
     No-op discipline (tracer/ledger/faults contract, gate-lint registry
-    row, asserted by bench.py): OFF by default, the per-query gate is
+    row): OFF by default, the per-query gate is
     `scope()` returning None — the disabled SPMD path costs one
     attribute load and a branch, and the disabled TransferLedger.record
     path never touches the per-device table.
@@ -663,8 +663,8 @@ class ChurnLedger:
     causes (every skeleton + bundle recompiles on the host) and the
     subset keyed to the removed (segment-uid, mapper-version) pairs.
 
-    No-op discipline (tracer/ledger/faults contract, gate-lint row,
-    asserted by bench.py): OFF by default, `scope()` returns None when
+    No-op discipline (tracer/ledger/faults contract, gate-lint row):
+    OFF by default, `scope()` returns None when
     disabled. `observe_shape` alone is live regardless (the
     inflight-wave-gauge contract): it is one lock + set-add per SEGMENT
     UPLOAD, never per query, and the verdict is only honest if the

@@ -44,14 +44,14 @@ neither records the request's *schedule*. This module is that contract:
   per-op and per-bulk ingest timelines (arrive/admit/parse/
   version_plan/translog_append/refresh_wait/respond) recorded into a
   bounded ring with rolling took percentiles, OFF by default behind the
-  same None-returning `timeline()` gate (gate-lint registry row,
-  asserted pristine by bench.py). The engine reads the thread-bound
+  same None-returning `timeline()` gate (gate-lint registry row).
+  The engine reads the thread-bound
   timeline via `current()` — write ops run start-to-finish on one
   thread, so ambient context is safe here (unlike the msearch
   envelope). Served by `GET /_telemetry/ingest`.
 
 No-op discipline (the tracer/ledger/faults contract, statically enforced
-by gate-lint's subsystem registry and asserted by bench.py): the
+by gate-lint's subsystem registry): the
 recorder is OFF by default and the hot-path gate is `timeline()`
 returning None — one attribute load and a branch, nothing else runs.
 Event appends are plain list appends (GIL-atomic): a timeline is written
@@ -460,7 +460,7 @@ class SpmdTimeline:
     ring; rendering is tools/tail_report.py's per-device table.
 
     No-op discipline (tracer/ledger/faults contract, gate-lint registry
-    row, asserted by bench.py): OFF by default, `gate()` returns None —
+    row): OFF by default, `gate()` returns None —
     the disabled SPMD path costs one attribute load and a branch."""
 
     def __init__(self):
@@ -482,7 +482,7 @@ class IngestRecorder:
     timelines (ISSUE 13), the FlightRecorder's ingest analog.
 
     No-op discipline (the tracer/ledger/faults contract, gate-lint
-    registry row, asserted by bench.py): OFF by default, the per-request
+    registry row): OFF by default, the per-request
     gate is `timeline()` returning None, and the engine-side ambient
     read `current()` tests the flag BEFORE touching thread-local state —
     the disabled write path costs one attribute load and a branch per

@@ -30,8 +30,8 @@ DEFAULT_BUCKETS_MS: Tuple[float, ...] = (
 class Counter:
     """A monotonically increasing named count. Lock-guarded: `value +=`
     is a read-modify-write the interpreter can interleave, and the
-    concurrent-clients serving path (bench.py --clients, ROADMAP item 2)
-    drives these from N threads — a drifting counter reads as a lost
+    concurrent-clients serving path (ROADMAP item 2) drives these from
+    N threads — a drifting counter reads as a lost
     request (tests/test_rolling_concurrent.py pins exactness)."""
 
     __slots__ = ("name", "value", "_lock")

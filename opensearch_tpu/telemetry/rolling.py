@@ -25,8 +25,8 @@ Thread-safety: `observe`/`quantile`/`reset` are lock-guarded. The old
 "lost float increments under the GIL are tolerable" stance broke once
 the decay path existed — two threads entering `_maybe_decay` in the
 same interval would BOTH scale the counts (a real distortion, not a
-lost sample), and the open-loop concurrent-clients bench (bench.py
---clients) drives N writer threads through every estimator. The lock is
+lost sample), and concurrent clients drive N writer threads through
+every estimator. The lock is
 uncontended in steady state and costs well under the per-observation
 bisect it guards (pinned by tests/test_rolling_concurrent.py).
 """

@@ -2,22 +2,18 @@
 
 ROADMAP item 4 defers block-max (WAND) pruning behind a measured
 trigger: "add on-device block-max skipping once scanned-bytes/query
-starts dominating" (BM25S, arxiv 2407.03618). tools/scaling_bench.py
-computed that number OFFLINE, once, at three corpus sizes
-(SCALING_raw.json) — this module is the LIVE
-version: per-query counters for the bytes each kernel class touches,
+starts dominating" (BM25S, arxiv 2407.03618). This module is that
+number LIVE: per-query counters for the bytes each kernel class touches,
 aggregated into a per-shard/per-segment heat map on `_nodes/stats`
 (`telemetry.scan`), so the go/no-go trigger is a standing dashboard
 number instead of an archaeology exercise.
 
-Two byte classes, matching the offline columns exactly (the committed
-acceptance: the live p50 at 100K docs must agree with the offline
-3.1 KB within 10%):
+Two byte classes, each computed from term metadata and plan statics
+(tests/test_device_ledger.py holds the live count to the formula):
 
 - **posting bytes** (candidate-buffer kernel): the query terms' posting
-  blocks — `blocks × 128 lanes × 8 B` (docs int32 + tf f32), the same
-  formula tools/scaling_bench.py evaluates offline from term metadata.
-  Counted from `Plan.scan_blocks`, a static the compiler records at
+  blocks — `blocks × 128 lanes × 8 B` (docs int32 + tf f32). Counted
+  from `Plan.scan_blocks`, a static the compiler records at
   plan build; per query this is one attribute read per plan node —
   no per-lane work, no device sync.
 - **dense-lane bytes** (dense kernel): `d_pad × 9 B` per clause

@@ -1,4 +1,4 @@
-"""Deterministic synthetic corpora for the graft entry, bench.py and tests.
+"""Deterministic synthetic corpora for the graft entry and tests.
 
 Generates an msmarco-passage-shaped workload (zipfian vocabulary, ~60-token
 passages) without shipping data: the reference's macro benchmarks point at

@@ -304,7 +304,7 @@ def test_interference_cli_end_to_end(tmp_path):
     assert bench_compare.main(["bench_compare.py", old_p, bad_p]) == 1
 
 
-# ----------------------------------------------- SCALING_MC shape (ISSUE 14)
+# ------------------------------------- multi-chip scaling shape (ISSUE 14)
 
 SCALE_OLD = {
     f"spmd_d{d}": {

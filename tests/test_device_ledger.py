@@ -331,8 +331,8 @@ def test_scheduler_splits_wave_wall_across_tenants():
 
 class TestScanAccounting:
     def test_envelope_matches_offline_posting_formula(self, ex):
-        """The live counter must agree EXACTLY with the offline formula
-        (tools/scaling_bench.py): sum over query terms of
+        """The live counter must agree EXACTLY with the formula computed
+        from term metadata: sum over query terms of
         num_blocks x 128 lanes x 8 B."""
         seg = ex.reader.segments[0]
         q = "w00003 w00007"

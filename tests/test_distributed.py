@@ -214,7 +214,7 @@ def test_dryrun_parity_bodies_4of4(eight_devices):
     """ISSUE 14 satellite: pin the multichip dryrun's FOUR hit-bearing
     parity cases at 4/4 (seeded, small scale, 16 rows packed 2/device).
 
-    Diagnosis of MULTICHIP_r05's committed `hit_parity=3/4`: a
+    Diagnosis of an early multichip record's `hit_parity=3/4`: a
     DENOMINATOR artifact, not rank divergence — the pre-PR-8 harness
     printed a hardcoded "/4" while its size:0 date_histogram body has
     no hits page to compare (its strict per-body asserts all passed,

@@ -1,44 +1,35 @@
-"""Kernel-level device-compute profiler (ISSUE 19): executable census,
-XLA cost/roofline ledger, per-family device-time attribution.
+"""Executable census and roofline classes (ISSUE 19; the sampled
+host-clock timer went in PR 31: kernel time comes from the device trace).
 
-Pins the acceptance behaviors:
-  - gate discipline: disabled by default, None-returning gate, clear()
-    keeps config while dropping state;
+Pins:
   - census/compile-histogram reconciliation: the census `compile_ms`
     total and the always-on `search.xla_compile_ms` histogram are fed
     by the SAME note_compile call, so window deltas match exactly;
-  - sampled-timing determinism: the call-count modulus makes the
-    sample schedule a pure function of the global per-family call
-    index — total sampled count is exact under 4-thread load;
-  - device-ms conservation: with sample_every=1 the timed walls plus
-    the residual result-pull wall reproduce the clean run's collect
-    wall (async dispatch means the collect absorbs compute when the
-    profiler is off);
-  - instrumentation-off differential: responses byte-identical (modulo
-    took) across off/on/off, and the disabled path records nothing;
-  - REST roundtrip (enable/disable/clear, the `GET /_telemetry` gate
-    index, `_nodes/stats` block) + node-setting wiring;
-  - insights kernel-breakdown join (per-shape kernels dict and the
-    dominant_kernel column);
+  - a cache hit of each of the executor's five executable caches is the
+    cached executable itself, and it carries `exec_info`;
+  - the REST face (GET, `?scopes=true`, `_clear`, the `GET /_telemetry`
+    gate index, the `_nodes/stats` block) + the two peak settings; the
+    timer's routes, keys and joins are gone;
+  - the two environment switches no longer reach the executor;
   - ops-layer compile visibility: the knn `_kmeans` and delta-publish
-    `_expand_fn` jit sites — formerly invisible — reach the compile
-    counters AND the census;
-  - tools/kernel_report.py smoke over every accepted input shape.
+    `_expand_fn` jit sites reach the compile counters AND the census.
 """
 
-import json
-import threading
-import time
+import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
 
+import opensearch_tpu.search.executor as executor_mod
+import opensearch_tpu.search.spmd as spmd_mod
 import opensearch_tpu.telemetry.kernels as kernels_mod
 from opensearch_tpu.search.executor import SearchExecutor, ShardReader
 from opensearch_tpu.telemetry import TELEMETRY
 from opensearch_tpu.telemetry.kernels import (
-    DEFAULT_SAMPLE_EVERY, DEVICE_PEAKS, KERNEL_FAMILIES, KERNELS,
-    KernelProfiler, fingerprint, timed_first_call)
+    DEVICE_PEAKS, KERNEL_FAMILIES, KERNELS, KernelProfiler, fingerprint,
+    timed_first_call)
 from opensearch_tpu.utils.demo import build_shards, query_terms
 
 
@@ -47,20 +38,6 @@ def executor():
     mapper, segments = build_shards(320, n_shards=2, vocab_size=180,
                                     avg_len=24, seed=11)
     return SearchExecutor(ShardReader(mapper, segments))
-
-
-@pytest.fixture()
-def kernels_on():
-    """Enable the profiler for one test at sample_every=1 (every
-    dispatch timed — zero extrapolation error), restore the pristine
-    default and clear state both ways."""
-    KERNELS.enabled = True
-    KERNELS.sample_every = 1
-    KERNELS.clear()
-    yield KERNELS
-    KERNELS.enabled = False
-    KERNELS.sample_every = DEFAULT_SAMPLE_EVERY
-    KERNELS.clear()
 
 
 def _bodies(n=10):
@@ -79,32 +56,34 @@ def _metric_window():
             KERNELS.snapshot()["census"]["entries"])
 
 
-# --------------------------------------------------------------- gate
+# ---------------------------------------------------------- singleton
+
+# what a family row and the body of `GET /_telemetry/kernels` hold: the
+# census' counts and the roofline classes, no clock but the compile wall
+FAMILY_KEYS = {"compiles", "compile_ms", "flops", "bytes",
+               "arithmetic_intensity", "bound"}
+BODY_KEYS = {"peak_flops", "peak_bw", "ridge_intensity", "census",
+             "families"}
+
 
 class TestGateDiscipline:
-    def test_default_off_and_gate_none(self):
-        fresh = KernelProfiler()
-        assert fresh.enabled is False
-        assert fresh.gate() is None
-        fresh.enabled = True
-        assert fresh.gate() is fresh
-
     def test_singleton_is_wired(self):
         assert TELEMETRY.kernels is KERNELS
-        assert KERNELS.sample_every == DEFAULT_SAMPLE_EVERY
+        # the census has no gate: nothing to turn on, no timer to wrap
+        # an executable in
+        for gone in ("enabled", "gate", "timed"):
+            assert not hasattr(KERNELS, gone)
+        assert set(KERNELS.snapshot()) == BODY_KEYS
 
     def test_clear_keeps_config_drops_state(self):
         p = KernelProfiler()
-        p.enabled = True
-        p.sample_every = 3
         p.peak_flops = 2.0e12
         p.peak_bw = 2.0e11
         p.census_note(None, (), "other", "s", "deadbeef", 1.5,
                       (10.0, 20.0))
-        p.timed(lambda: 1, "other", "s")()
+        assert p.snapshot()["census"]["entries"] == 1
         p.clear()
         snap = p.snapshot()
-        assert p.enabled is True and p.sample_every == 3
         assert snap["peak_flops"] == 2.0e12 and snap["peak_bw"] == 2.0e11
         assert snap["census"]["entries"] == 0
         assert snap["families"] == {}
@@ -114,8 +93,6 @@ class TestGateDiscipline:
 
 class TestCensus:
     def test_census_registers_on_first_call_always_on(self):
-        # census is ALWAYS-ON: the gate flag only guards timed dispatch
-        assert KERNELS.enabled is False
         import jax
         import jax.numpy as jnp
         miss0, cnt0, sum0, cms0, n0 = _metric_window()
@@ -216,161 +193,98 @@ class TestCensus:
         assert DEVICE_PEAKS["TPU v5 lite"] == (197.0e12, 819.0e9)
 
 
-# ------------------------------------------------------------- timing
+# --------------------------------------------------------- cache hits
 
-class TestSampledTiming:
-    def test_tick_modulus_deterministic(self):
-        p = KernelProfiler()
-        p.enabled = True
-        p.sample_every = 4
-        run = p.timed(lambda: 1, "other", "s")
-        for _ in range(10):
-            run()
-        fam = p.snapshot()["families"]["other"]
-        # calls 1, 5, 9 sampled (first call always is)
-        assert fam["calls"] == 10 and fam["sampled"] == 3
-        # est extrapolates the raw sampled walls over every dispatch
-        # (snapshot rounds sampled_ms after the division)
-        assert fam["device_ms_est"] == pytest.approx(
-            fam["sampled_ms"] * 10 / 3, abs=0.002)
-
-    def test_sampling_deterministic_under_threads(self):
-        # the modulus runs over the GLOBAL per-family call counter
-        # under the lock: total sampled count is exact no matter how
-        # 4 threads interleave
-        p = KernelProfiler()
-        p.enabled = True
-        p.sample_every = 4
-        run = p.timed(lambda: 1, "knn", "s0")
-        barrier = threading.Barrier(4)
-
-        def work():
-            barrier.wait()
-            for _ in range(25):
-                run()
-
-        threads = [threading.Thread(target=work) for _ in range(4)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        fam = p.snapshot()["families"]["knn"]
-        assert fam["calls"] == 100
-        assert fam["sampled"] == 25
-        assert fam["shapes"]["s0"]["calls"] == 100
-
-    def test_sample_every_one_no_extrapolation(self):
-        p = KernelProfiler()
-        p.enabled = True
-        p.sample_every = 1
-
-        def fn():
-            time.sleep(0.002)
-            return 1
-
-        run = p.timed(fn, "maxsim", "q8")
-        for _ in range(5):
-            run()
-        fam = p.snapshot()["families"]["maxsim"]
-        assert fam["sampled"] == fam["calls"] == 5
-        assert fam["device_ms_est"] == round(fam["sampled_ms"], 3)
-        assert fam["sampled_ms"] >= 5.0     # 5 sleeps of >=2ms
-        assert fam["p50_ms"] is not None and fam["p99_ms"] is not None
-        assert fam["shapes"]["q8"]["device_ms_est"] == \
-            fam["device_ms_est"]
+def _serve_general(executor):
+    # off the B=1 envelope and off the SPMD route: the host loop
+    with spmd_mod.force_host_loop():
+        executor.search({"query": {"match": {"body": "w00003"}},
+                         "size": 6}, _direct=True)
 
 
-# ------------------------------------------------------- conservation
-
-class TestConservation:
-    def test_timed_walls_conserve_against_collect_wall(self):
-        """The bench's A/B identity, pinned on a synthetic kernel heavy
-        enough to dominate fixed overheads: clean-arm collect wall
-        (async dispatch -> device_get absorbs compute) equals the
-        instrumented arm's timed wall + residual collect."""
-        import jax
-        import jax.numpy as jnp
-        n, chain, reps = 512, 6, 3
-
-        @jax.jit
-        def mm(x):
-            for _ in range(chain):
-                x = x @ x / jnp.float32(n)
-            return x
-
-        x = jnp.ones((n, n), dtype=jnp.float32)
-        jax.device_get(mm(x))           # compile + warm
-        clean = 0.0
-        for _ in range(reps):
-            out = mm(x)
-            t0 = time.perf_counter_ns()
-            jax.device_get(out)
-            clean += (time.perf_counter_ns() - t0) / 1e6
-        if clean < 5.0:
-            pytest.skip("dispatch not async on this backend: the "
-                        "collect wall does not absorb compute")
-        p = KernelProfiler()
-        p.enabled = True
-        p.sample_every = 1
-        run = p.timed(mm, "other", f"n{n}")
-        inst_collect = 0.0
-        for _ in range(reps):
-            out = run(x)                # blocks until ready (sampled)
-            t0 = time.perf_counter_ns()
-            jax.device_get(out)
-            inst_collect += (time.perf_counter_ns() - t0) / 1e6
-        kernel_ms = p.snapshot()["families"]["other"]["device_ms_est"]
-        drift = abs(kernel_ms + inst_collect - clean) / clean
-        assert drift < 0.5, (kernel_ms, inst_collect, clean)
-        # the timed wall owns most of the wait: the residual collect is
-        # just the copy
-        assert kernel_ms > inst_collect
+def _serve_envelope(executor):
+    executor.multi_search([dict(b) for b in _bodies(4)])
 
 
-# --------------------------------------------------- off differential
+def _serve_agg_envelope(executor):
+    executor.multi_search([
+        dict(b, aggs={"m": {"max": {"field": "views"}}})
+        for b in _bodies(4)])
 
-class TestOffDifferential:
-    @staticmethod
-    def _strip(res):
-        return [{k: v for k, v in r.items() if k != "took"}
-                for r in res["responses"]]
 
-    def test_disabled_path_is_byte_identical_and_silent(self, executor):
-        bodies = _bodies()
-        assert KERNELS.enabled is False
-        KERNELS.clear()
-        r_off = executor.multi_search([dict(b) for b in bodies])
+def _serve_page(executor):
+    executor_mod.RESULT_PAGE = True
+    try:
+        with spmd_mod.force_host_loop():
+            executor.search({"query": {"match": {"body": "w00003"}},
+                             "size": 5, "sort": [{"views": "asc"}],
+                             "docvalue_fields": ["views"]})
+    finally:
+        executor_mod.RESULT_PAGE = False
+
+
+def _serve_hybrid(_executor):
+    from opensearch_tpu.node import Node
+    node = Node()
+    node.request("PUT", "/hyb", {
+        "settings": {"number_of_shards": 1},
+        "mappings": {"properties": {
+            "title": {"type": "text"},
+            "vec": {"type": "knn_vector", "dimension": 4}}}})
+    for i in range(12):
+        node.request("PUT", f"/hyb/_doc/{i}", {
+            "title": f"red dog {i}" if i % 2 else f"blue cat {i}",
+            "vec": [0.1 * i, 0.2, 0.3, 0.05 * i]})
+    node.request("POST", "/hyb/_refresh")
+    body = {"query": {"hybrid": {"queries": [
+        {"match": {"title": "red dog"}},
+        {"knn": {"vec": {"vector": [0.5, 0.2, 0.3, 0.1], "k": 3}}}]}},
+        "size": 5}
+    node.indices.get("hyb").shards[0].executor.multi_search(
+        [dict(body), dict(body)])
+
+
+class TestCacheHits:
+    """The five executable caches of search/executor.py: the second
+    lookup of a key hands back the very object `_JIT_CACHE` holds, no
+    wrapper around it, and that object names its executable
+    (`exec_info`) for the `dispatch` span."""
+
+    @pytest.mark.parametrize("name,serve", [
+        ("_runner", _serve_general),
+        ("_envelope_runner", _serve_envelope),
+        ("_agg_envelope_runner", _serve_agg_envelope),
+        ("_batched_hybrid_runner", _serve_hybrid),
+        ("_page_merger", _serve_page)])
+    def test_second_lookup_is_the_cached_executable(
+            self, executor, monkeypatch, name, serve):
+        lookup = getattr(executor_mod, name)
+        seen = []
+
+        def spy(*args, **kwargs):
+            seen.append((args, kwargs))
+            return lookup(*args, **kwargs)
+
+        monkeypatch.setattr(executor_mod, name, spy)
+        serve(executor)
+        assert seen, f"the request never reached {name}"
+        args, kwargs = seen[-1]
+        hit = lookup(*args, **kwargs)
+        assert lookup(*args, **kwargs) is hit
+        cached = [v for v in executor_mod._JIT_CACHE.values() if v is hit]
+        assert len(cached) == 1, f"{name} returned no _JIT_CACHE entry"
+        fn = hit[0] if isinstance(hit, tuple) else hit
+        assert fn.exec_info.family in KERNEL_FAMILIES
+        assert len(fn.exec_info.fingerprint) == 8
+
+    def test_e2e_timed_families_are_known_vocabulary(self, executor):
+        # sizes no other test of the module asks for: fresh compile keys
+        executor.multi_search([dict(b, size=15 + i)
+                               for i, b in enumerate(_bodies(3))])
         snap = KERNELS.snapshot()
-        assert all(f["calls"] == 0 and f["sampled_ms"] == 0.0
-                   for f in snap["families"].values())
-        KERNELS.enabled = True
-        KERNELS.sample_every = 1
-        try:
-            r_on = executor.multi_search([dict(b) for b in bodies])
-            fams = KERNELS.snapshot()["families"]
-            assert any(f["calls"] > 0 for f in fams.values())
-        finally:
-            KERNELS.enabled = False
-            KERNELS.sample_every = DEFAULT_SAMPLE_EVERY
-        calls_after = {f: r["calls"] for f, r in
-                       KERNELS.snapshot()["families"].items()}
-        r_off2 = executor.multi_search([dict(b) for b in bodies])
-        assert self._strip(r_off) == self._strip(r_on) \
-            == self._strip(r_off2)
-        assert {f: r["calls"] for f, r in
-                KERNELS.snapshot()["families"].items()} == calls_after
-        KERNELS.clear()
-
-    def test_e2e_timed_families_are_known_vocabulary(self, executor,
-                                                     kernels_on):
-        executor.multi_search([dict(b) for b in _bodies()])
-        fams = kernels_on.snapshot()["families"]
-        dispatched = {f for f, r in fams.items() if r["calls"] > 0}
-        assert dispatched
-        assert dispatched <= set(KERNEL_FAMILIES)
-        for f in dispatched:
-            assert fams[f]["device_ms_est"] >= 0.0
-            assert fams[f]["sampled"] == fams[f]["calls"]
+        served = {r["family"] for r in snap["census"]["executables"]}
+        assert served and served <= set(KERNEL_FAMILIES)
+        assert set(snap["families"]) == served
 
 
 # ---------------------------------------------------------- REST face
@@ -387,109 +301,141 @@ class TestRestFace:
                       {"msg": f"profiled message number {i}"})
         n.request("POST", "/kern/_refresh")
         yield n
-        KERNELS.enabled = False
-        KERNELS.sample_every = DEFAULT_SAMPLE_EVERY
         KERNELS.clear()
 
     def test_telemetry_index_lists_ten_gates(self, node):
+        # nine since PR 31: the kernel census has no gate
         r = node.request("GET", "/_telemetry")
         assert r["_status"] == 200
         subs = r["subsystems"]
         assert set(subs) == {"tracer", "transfers", "devices", "tail",
                              "ingest", "churn", "insights", "scheduler",
-                             "faults", "kernels"}
+                             "faults"}
         for name, row in subs.items():
             assert isinstance(row["enabled"], bool)
             assert row["endpoint"].startswith("/_")
-        assert subs["kernels"]["enabled"] is False
-        assert subs["kernels"]["endpoint"] == "/_telemetry/kernels"
 
-    def test_roundtrip(self, node):
-        r = node.request("POST", "/_telemetry/kernels/_enable",
-                         sample_every=1)
-        assert r["_status"] == 200 and r["enabled"] is True
-        assert r["sample_every"] == 1
-        assert node.request("GET", "/_telemetry")["subsystems"][
-            "kernels"]["enabled"] is True
+    def _search(self, node):
         for term in ("profiled", "message", "number"):
             node.request("POST", "/kern/_search",
                          {"query": {"match": {"msg": term}}, "size": 3})
+
+    def test_roundtrip(self, node):
+        self._search(node)
         snap = node.request("GET", "/_telemetry/kernels")["kernels"]
-        assert snap["enabled"] is True
-        assert any(f["calls"] > 0 for f in snap["families"].values())
-        # full GET carries the per-executable dump; _nodes/stats does not
-        assert "executables" in snap["census"]
-        stats = node.request("GET", "/_nodes/stats")
-        kblock = stats["nodes"][node.node_id]["telemetry"]["kernels"]
-        assert kblock["enabled"] is True
-        assert "executables" not in kblock["census"]
+        assert snap["census"]["entries"] > 0
+        # full GET carries the per-executable dump, each record's map
+        # only on demand
+        assert all("scopes" not in rec
+                   for rec in snap["census"]["executables"])
+        scoped = node.request("GET", "/_telemetry/kernels",
+                              scopes="true")["kernels"]
+        assert all(isinstance(rec["scopes"], dict)
+                   for rec in scoped["census"]["executables"])
         r = node.request("POST", "/_telemetry/kernels/_clear")
         assert r["acknowledged"] is True
         snap = node.request("GET", "/_telemetry/kernels")["kernels"]
-        assert snap["census"]["entries"] == 0
-        assert all(f["calls"] == 0 for f in snap["families"].values())
-        r = node.request("POST", "/_telemetry/kernels/_disable")
-        assert r["enabled"] is False
-        assert KERNELS.gate() is None
+        assert snap["census"] == {"entries": 0, "dropped": 0,
+                                  "compile_ms_total": 0.0,
+                                  "executables": []}
+        assert snap["families"] == {}
 
-    def test_enable_rejects_bad_sample_every(self, node):
-        r = node.request("POST", "/_telemetry/kernels/_enable",
-                         sample_every="every-so-often")
-        assert r["_status"] == 400
+    @pytest.mark.parametrize("verb", ["_enable", "_disable"])
+    def test_timer_routes_are_unknown(self, node, verb):
+        unknown = node.request("POST", "/_telemetry/no_such_face/_enable")
+        r = node.request("POST", f"/_telemetry/kernels/{verb}")
+        assert r["_status"] == unknown["_status"] != 200
+        assert r["error"]["type"] == unknown["error"]["type"]
+
+    @pytest.mark.parametrize("face", ["get", "nodes_stats"])
+    def test_census_only_body(self, node, face):
+        self._search(node)
+        # the searches above compile nothing new once the process has
+        # their executables: one record of this test's own
+        KERNELS.census_note(None, (), "other", "s", "0" * 8, 1.0,
+                            (10.0, 20.0))
+        if face == "get":
+            body = node.request("GET", "/_telemetry/kernels")["kernels"]
+            for rec in body["census"]["executables"]:
+                assert {"family", "fingerprint", "shape", "compile_ms",
+                        "from_cache", "flops", "bytes",
+                        "cost_source"} == set(rec)
+        else:
+            stats = node.request("GET", "/_nodes/stats")
+            body = stats["nodes"][node.node_id]["telemetry"]["kernels"]
+            assert "executables" not in body["census"]
+        assert set(body) == BODY_KEYS
+        assert body["families"]
+        for row in body["families"].values():
+            assert set(row) == FAMILY_KEYS
+
+    def test_profile_has_no_kernel_entry(self, node):
+        res = node.request("POST", "/kern/_search", {
+            "profile": True, "query": {"match": {"msg": "profiled"}}})
+        shards = res["profile"]["shards"]
+        assert shards
+        for shard in shards:
+            assert "transfers" in shard
+            assert not [k for k in shard if "kernel" in k]
+            for search in shard["searches"]:
+                assert not [k for k in search["query"][0]["breakdown"]
+                            if "kernel" in k]
+
+    def test_insights_row_has_no_kernel_column(self, node):
+        from opensearch_tpu.telemetry.insights import INSIGHTS
+        INSIGHTS.enabled = True
+        INSIGHTS.clear()
+        try:
+            self._search(node)
+            node.request("POST", "/kern/_msearch", [
+                {}, {"query": {"match": {"msg": "message"}}}])
+            shapes = INSIGHTS.snapshot()["shapes"]
+            assert shapes
+            for row in shapes.values():
+                assert not [k for k in row if "kernel" in k]
+        finally:
+            INSIGHTS.enabled = False
+            INSIGHTS.clear()
 
     def test_node_setting_enables_and_sets_roofline(self):
+        # the two settings left: the roofline peaks
         from opensearch_tpu.node import Node
         try:
             Node(settings={
-                "telemetry.kernels.enabled": "true",
                 "telemetry.kernels.peak_flops": "2.5e12",
-                "telemetry.kernels.peak_bw": "5e11",
-                "telemetry.kernels.sample_every": "4"})
-            assert KERNELS.enabled is True
+                "telemetry.kernels.peak_bw": "5e11"})
             assert KERNELS.peak_flops == 2.5e12
             assert KERNELS.peak_bw == 5.0e11
-            assert KERNELS.sample_every == 4
+            assert KERNELS.snapshot()["ridge_intensity"] == 5.0
         finally:
-            KERNELS.enabled = False
-            KERNELS.sample_every = DEFAULT_SAMPLE_EVERY
             KERNELS.peak_flops = None
             KERNELS.peak_bw = None
             KERNELS.clear()
             Node()      # re-configure the singleton back to defaults
 
 
-# ------------------------------------------------------- insights join
+# ------------------------------------------------- environment switches
 
-class TestInsightsJoin:
-    def test_note_kernels_accumulates_and_names_dominant(self):
-        from opensearch_tpu.telemetry.insights import QueryInsights
-        ins = QueryInsights()
-        ins.enabled = True
-        ins.note("s1", kind="template", took_ms=1.0, device_ms=3.0,
-                 kernels={"bm25_dense": 2.0, "page_merger": 1.0})
-        ins.note("s1", kind="template", took_ms=1.0, device_ms=2.0,
-                 kernels={"bm25_dense": 2.0})
-        row = ins.snapshot()["shapes"]["s1"]
-        assert row["kernels"] == {"bm25_dense": 4.0, "page_merger": 1.0}
-        assert row["dominant_kernel"] == "bm25_dense"
+class TestEnvironmentSwitchesAreGone:
+    """PR 31 made the two whole-process A/B switches plain module
+    attributes: what the environment says no longer reaches them."""
 
-    def test_e2e_shape_rows_carry_kernel_breakdown(self, executor,
-                                                   kernels_on):
-        from opensearch_tpu.telemetry.insights import INSIGHTS
-        INSIGHTS.enabled = True
-        INSIGHTS.clear()
-        try:
-            executor.multi_search([dict(b) for b in _bodies()])
-            shapes = INSIGHTS.snapshot()["shapes"]
-            assert shapes
-            joined = [r for r in shapes.values() if r["kernels"]]
-            assert joined, "no shape row carried a kernel breakdown"
-            for r in joined:
-                assert r["dominant_kernel"] in KERNEL_FAMILIES
-                assert set(r["kernels"]) <= set(KERNEL_FAMILIES)
-        finally:
-            INSIGHTS.enabled = False
-            INSIGHTS.clear()
+    @pytest.mark.parametrize("switch,value,attribute,default", [
+        ("DISABLE_INTERNING", "1", "TEMPLATE_INTERNING", "True"),
+        ("MSEARCH_WAVES", "4", "FORCED_WAVES", "None")])
+    def test_import_ignores_the_environment(self, switch, value,
+                                            attribute, default):
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        env[f"OPENSEARCH_TPU_{switch}"] = value
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "import opensearch_tpu.search.executor as e; "
+             f"print(repr(e.{attribute}))"],
+            env=env, capture_output=True, text=True, timeout=300,
+            cwd=os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))))
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.strip().splitlines()[-1] == default
 
 
 # ------------------------------------------- ops compile visibility
@@ -545,87 +491,3 @@ class TestOpsCompileVisibility:
         np.testing.assert_array_equal(
             np.asarray(f2(jnp.arange(11, dtype=jnp.int32))), arr)
         assert _metric_window()[0] == miss1
-
-
-# ----------------------------------------------------- tool satellite
-
-class TestKernelReportTool:
-    def _tool(self):
-        import os
-        import sys
-        tools = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "tools")
-        if tools not in sys.path:
-            sys.path.insert(0, tools)
-        import kernel_report
-        return kernel_report
-
-    def _snapshot_doc(self):
-        return {"kernels": {
-            "enabled": True, "sample_every": 1,
-            "peak_flops": 1.0e12, "peak_bw": 1.0e11,
-            "ridge_intensity": 10.0,
-            "census": {"entries": 2, "dropped": 0,
-                       "compile_ms_total": 12.5,
-                       "executables": [
-                           {"family": "bm25_dense", "shape": "b8/k10",
-                            "fingerprint": "aa" * 4, "compile_ms": 10.0,
-                            "flops": 1.0e9, "bytes": 1.0e7,
-                            "cost_source": "xla"},
-                           {"family": "expand", "shape": "64x32",
-                            "fingerprint": "bb" * 4, "compile_ms": 2.5,
-                            "flops": 2.0e3, "bytes": 8.0e6,
-                            "cost_source": "analytic"}]},
-            "families": {
-                "bm25_dense": {
-                    "compiles": 1, "compile_ms": 10.0, "flops": 1.0e9,
-                    "bytes": 1.0e7, "arithmetic_intensity": 100.0,
-                    "bound": "compute", "calls": 10, "sampled": 10,
-                    "sampled_ms": 5.0, "device_ms_est": 5.0,
-                    "p50_ms": 0.5, "p99_ms": 0.6, "shapes": {}},
-                "expand": {
-                    "compiles": 1, "compile_ms": 2.5, "flops": 2.0e3,
-                    "bytes": 8.0e6, "arithmetic_intensity": 0.0003,
-                    "bound": "memory", "calls": 0, "sampled": 0,
-                    "sampled_ms": 0.0}}}}
-
-    def test_report_over_snapshot(self, tmp_path, capsys):
-        kr = self._tool()
-        path = tmp_path / "KERNELS.json"
-        path.write_text(json.dumps(self._snapshot_doc()))
-        assert kr.main(["kernel_report.py", str(path)]) == 0
-        out = capsys.readouterr().out
-        assert "2 kernel families" in out
-        # device-ms sort: the timed family ranks above the census-only
-        assert out.index("bm25_dense") < out.index("expand")
-        assert "ridge intensity" in out and "compute" in out
-        assert "aaaaaaaa" in out    # census fingerprint column
-
-    def test_assert_families_gate(self, tmp_path, capsys):
-        kr = self._tool()
-        path = tmp_path / "KERNELS.json"
-        path.write_text(json.dumps(self._snapshot_doc()))
-        assert kr.main(["kernel_report.py", "--assert-families", "3",
-                        str(path)]) == 1
-        assert "FAIL" in capsys.readouterr().out
-
-    def test_bench_rows_upconvert(self, tmp_path, capsys):
-        kr = self._tool()
-        path = tmp_path / "BENCH_KERNELS_r99.json"
-        rows = [
-            {"mode": "kernels_bm25_bm25_dense", "bench": "bm25",
-             "family": "bm25_dense", "calls": 12, "device_ms": 8.0,
-             "p50_ms": 0.7, "p99_ms": 0.9, "compiles": 1,
-             "compile_ms": 11.0, "flops": 1e9, "bytes": 1e7,
-             "arithmetic_intensity": 100.0, "bound": "compute"},
-            {"metric": "kernels_profile_cpu", "benches": 1}]
-        path.write_text("\n".join(json.dumps(r) for r in rows))
-        assert kr.main(["kernel_report.py", str(path)]) == 0
-        assert "bm25/bm25_dense" in capsys.readouterr().out
-
-    def test_no_block_found(self, tmp_path, capsys):
-        kr = self._tool()
-        path = tmp_path / "empty.json"
-        path.write_text('{"unrelated": 1}')
-        assert kr.main(["kernel_report.py", str(path)]) == 1
-        assert "no kernel-profiler block" in capsys.readouterr().out

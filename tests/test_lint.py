@@ -359,14 +359,48 @@ def test_repo_is_lint_clean():
 
 
 def test_entry_scripts_never_select_a_platform():
-    """bench.py and __graft_entry__.py run on the device jax gives them:
+    """The entry scripts run on the device jax gives them:
     no `jax_platforms` write, no FORCE_CPU switch. A run that finds no
     accelerator must fail, never quietly become a CPU number."""
-    for name in ("bench.py", "__graft_entry__.py", "chip_smoke.py"):
+    for name in ("__graft_entry__.py", "chip_smoke.py"):
         with open(os.path.join(REPO, name)) as f:
             src = f.read()
         for needle in ("jax_platforms", "FORCE_CPU"):
             assert needle not in src, f"{name} mentions {needle}"
+
+
+def test_the_kernel_census_is_no_gated_subsystem():
+    """The executable census is always on and its sampled timer is gone
+    (PR 31): the registry holds no row for telemetry/kernels.py."""
+    rows = gate_lint.GATED_SUBSYSTEMS
+    assert len(rows) == 23
+    assert not [r for r in rows if r[0].endswith("telemetry/kernels.py")]
+
+
+def test_one_benchmark_is_named():
+    """The benchmark is BENCHMARK.json with benchmark/run.py, and the
+    README says so; no source names the pre-chip driver or its records
+    (PR 31 deleted them)."""
+    import re
+    gone = re.compile("|".join((
+        "bench" r"\.py", "BENCH" r"_[A-Z]*_r0", "SCALING" "_",
+        "MULTICHIP" "_r0")))
+    with open(os.path.join(REPO, "README.md")) as f:
+        readme = f.read()
+    assert "benchmark/run.py" in readme and "BENCHMARK.json" in readme
+    hits = [f"README.md:{i}" for i, line in
+            enumerate(readme.splitlines(), 1) if gone.search(line)]
+    for top in ("opensearch_tpu", "tools", "tests", "benchmark"):
+        for root, _dirs, files in os.walk(os.path.join(REPO, top)):
+            for name in files:
+                if not name.endswith(".py"):
+                    continue
+                path = os.path.join(root, name)
+                with open(path) as f:
+                    hits += [f"{os.path.relpath(path, REPO)}:{i}"
+                             for i, line in enumerate(f, 1)
+                             if gone.search(line)]
+    assert hits == []
 
 
 def test_runner_cli_json_exit_zero():

@@ -262,7 +262,7 @@ class TestFiltersAndEdges:
 class TestPQ:
     def test_pq_recall_vs_exact(self):
         """compression: pq recall@10 ≥ 0.95 of exact over query sweeps
-        (the committed BENCH_MAXSIM_r01.json acceptance bound)."""
+        (ISSUE 18's acceptance bound)."""
         mapper_e, segs_e, seg_docs = build_reader(seed=20)
         mapper_p, segs_p, _ = build_reader(seed=20, compression="pq")
         ex_e = SearchExecutor(ShardReader(mapper_e, segs_e))

@@ -1,10 +1,9 @@
 """Concurrency contracts for the telemetry ingest paths (ISSUE 10
 satellite): RollingEstimator, metrics Histogram and Counter must not
-lose observations under N concurrent writer threads — the open-loop
-concurrent-clients bench (bench.py --clients) drives every one of them
-from worker threads, where an unguarded read-modify-write silently
-drops samples and a doubly-applied decay distorts the live p99 the
-future wave scheduler budgets against."""
+lose observations under N concurrent writer threads — concurrent
+clients drive every one of them from worker threads, where an unguarded
+read-modify-write silently drops samples and a doubly-applied decay
+distorts the live p99 the future wave scheduler budgets against."""
 
 import threading
 

@@ -1,7 +1,7 @@
 """Tier-1 smoke test for tools/transfer_report.py: the offline
 per-channel transfer report over ledger dumps (the
-`GET /_telemetry/transfers` response, a bare snapshot, and bench.py
---telemetry output lines)."""
+`GET /_telemetry/transfers` response, a bare snapshot, and JSONL
+records that carry one at telemetry.transfers)."""
 
 import json
 import os
@@ -55,8 +55,8 @@ def test_load_bare_snapshot(tmp_path):
 
 
 def test_load_bench_jsonl(tmp_path):
-    """bench.py --telemetry lines carry the snapshot at
-    telemetry.transfers; the first carrying line wins."""
+    """JSONL records carry the snapshot at telemetry.transfers; the
+    first carrying line wins."""
     path = tmp_path / "BENCH_test.json"
     with open(path, "w") as f:
         f.write(json.dumps({"metric": "other", "value": 1}) + "\n")

@@ -1,9 +1,9 @@
 #!/usr/bin/env python
 """Diff two bench dumps; fail on warm-latency regression.
 
-Input: two files of bench.py output records (BENCH_*.json /
-BENCH_CONC_*.json / BENCH_ALL.json style — one JSON object per line,
-each carrying "metric" or "mode" plus latency fields). Configs are
+Input: two JSONL dumps, one JSON object per line, each carrying
+"metric" or "mode" plus latency fields (nothing in the tree writes them
+since PR 31: ROADMAP D5b). Configs are
 matched by "mode" when present, else by the "metric" name with the
 trailing platform/shape suffix kept (the same config always renders the
 same metric string).
@@ -12,17 +12,16 @@ The gate: any config whose warm p50 ("warm_p50_ms", falling back to
 "p50_ms" for configs without a warmup pass) OR warm p99 regresses by
 more than --threshold (default 10%) fails the run with exit code 1 —
 the CI tripwire for "this PR made warm serving slower". The p99 side is
-what the open-loop concurrent-clients records (bench.py --clients →
-BENCH_CONC_*.json) exist for: a scheduler change can hold p50 while
-destroying the tail, and a p50-only gate would wave it through. Warm
+what the open-loop concurrent-clients records exist for: a scheduler
+change can hold p50 while destroying the tail, and a p50-only gate
+would wave it through. Warm
 p99 comes from "warm_p99_ms"; open-loop records (identified by their
 "clients" field) are warm by construction, so their "p99_ms" counts.
 Configs present in only one file are reported but never fail (bench
 sets grow PR over PR); configs without a p99 field skip the p99 gate.
 
-    python tools/bench_compare.py BENCH_old.json BENCH_new.json
+    python tools/bench_compare.py old.json new.json
     python tools/bench_compare.py --threshold 15 old.json new.json
-    python tools/bench_compare.py BENCH_CONC_r01.json BENCH_CONC_r02.json
 """
 
 from __future__ import annotations
@@ -46,9 +45,9 @@ INTERFERENCE_P99_PCT = 15.0
 
 # the multi-chip scaling gate (ISSUE 14): at EQUAL device count D,
 # per-chip scaling efficiency QPS(D)/(D·QPS(1)) may not drop by more
-# than this between two SCALING_MC rounds — "adding chips stopped
+# than this between two scaling rounds — "adding chips stopped
 # paying" fails the run even when absolute QPS moved with box state
-SCALING_EFFICIENCY_PCT = 15.0
+EFFICIENCY_DROP_PCT = 15.0
 
 # the insights gate (ISSUE 15): at EQUAL shape key, a shape class's
 # warm p99 may not degrade by more than this between two INSIGHTS
@@ -61,7 +60,7 @@ INSIGHTS_MIN_COUNT = 20
 # the late-interaction gate (ISSUE 18): at EQUAL config key, MaxSim
 # recall@10 may not drop by more than this (absolute) between rounds,
 # and the PQ arm's recall-vs-exact must clear the committed floor on
-# the new side unconditionally (BENCH_MAXSIM_r01.json acceptance)
+# the new side unconditionally (ISSUE 18's acceptance)
 MAXSIM_RECALL_DROP = 0.02
 MAXSIM_PQ_RECALL_FLOOR = 0.95
 
@@ -121,7 +120,7 @@ def warm_p50(rec: dict) -> Optional[float]:
 def warm_p99(rec: dict) -> Optional[float]:
     """Warm tail latency: explicit "warm_p99_ms", or bare "p99_ms" for
     open-loop concurrent-mode records (their measured window is warm by
-    construction — bench.py warms before the arrival schedule starts).
+    construction — the run warms before the arrival schedule starts).
     Cold-inclusive p99_ms on other configs deliberately does NOT count:
     its compile cliff is box-state noise, not a serving regression."""
     v = rec.get("warm_p99_ms")
@@ -157,7 +156,7 @@ def compare(old: Dict[str, dict], new: Dict[str, dict],
             continue
         if any(r is not None and "devices" in r
                and "per_chip_efficiency" in r for r in (o, n)):
-            # SCALING_MC points have their own gate (compare_scaling):
+            # multi-chip points have their own gate (compare_scaling):
             # per-chip EFFICIENCY is round-normalized (divided by the
             # same round's QPS(1)), where absolute warm latency on the
             # virtual-chip CPU box moves with box state
@@ -246,7 +245,7 @@ def compare(old: Dict[str, dict], new: Dict[str, dict],
 
 def _overload_records(recs: Dict[str, dict]) -> Dict[str, dict]:
     """The BENCH_OVERLOAD shape: offered-load ramp points carrying
-    `offered_rate` + `goodput_qps` (bench.py --overload-sweep)."""
+    `offered_rate` + `goodput_qps`."""
     return {k: r for k, r in recs.items()
             if isinstance(r.get("offered_rate"), (int, float))
             and isinstance(r.get("goodput_qps"), (int, float))}
@@ -321,7 +320,7 @@ def compare_overload(old: Dict[str, dict], new: Dict[str, dict],
 
 def _interference_records(recs: Dict[str, dict]) -> Dict[str, dict]:
     """The BENCH_INTERFERENCE shape: points carrying `ingest_rate` next
-    to search latency fields (bench.py --ingest-rate)."""
+    to search latency fields."""
     return {k: r for k, r in recs.items()
             if isinstance(r.get("ingest_rate"), (int, float))
             and isinstance(r.get("p99_ms"), (int, float))}
@@ -397,8 +396,8 @@ def compare_interference(old: Dict[str, dict], new: Dict[str, dict],
 
 
 def _scaling_records(recs: Dict[str, dict]) -> Dict[str, dict]:
-    """The SCALING_MC shape: multi-chip points carrying `devices` next
-    to a QPS `value` (bench.py --devices)."""
+    """The multi-chip scaling shape: points carrying `devices` next
+    to a QPS `value`."""
     return {k: r for k, r in recs.items()
             if isinstance(r.get("devices"), (int, float))
             and isinstance(r.get("value"), (int, float))}
@@ -408,7 +407,7 @@ def compare_scaling(old: Dict[str, dict], new: Dict[str, dict],
                     threshold_pct: float) -> Tuple[List[dict], List[str]]:
     """Gate two multi-chip scaling curves point-by-point at EQUAL
     device count: fail when per-chip efficiency QPS(D)/(D·QPS(1))
-    drops by more than SCALING_EFFICIENCY_PCT (the chips stopped
+    drops by more than EFFICIENCY_DROP_PCT (the chips stopped
     pulling their weight), or when straggler skew more than doubles
     past --threshold over a 1 ms floor (a chip went quietly lame).
     Single-chip points (D=1, efficiency 1.0 by construction) gate only
@@ -436,11 +435,11 @@ def compare_scaling(old: Dict[str, dict], new: Dict[str, dict],
             row["new_efficiency"] = ne
             de = 100.0 * (ne - oe) / oe
             row["efficiency_delta_pct"] = round(de, 1)
-            if de < -SCALING_EFFICIENCY_PCT:
+            if de < -EFFICIENCY_DROP_PCT:
                 status = "EFFICIENCY-REGRESSION"
                 failures.append(
                     f"{key}: per-chip efficiency {oe} -> {ne} "
-                    f"({de:.1f}% < -{SCALING_EFFICIENCY_PCT:g}% at "
+                    f"({de:.1f}% < -{EFFICIENCY_DROP_PCT:g}% at "
                     f"equal D)")
         os_, ns = o.get("straggler_skew_p50_ms"), \
             n.get("straggler_skew_p50_ms")
@@ -461,8 +460,8 @@ def compare_scaling(old: Dict[str, dict], new: Dict[str, dict],
 
 
 def _page_records(recs: Dict[str, dict]) -> Dict[str, dict]:
-    """The result-page A/B shape: arm records from bench.py --ab-page
-    carrying the `result_page` arm marker (BENCH_AB_PAGE*.json)."""
+    """The result-page A/B shape: arm records carrying the
+    `result_page` arm marker."""
     return {k: r for k, r in recs.items() if "result_page" in r}
 
 
@@ -518,7 +517,7 @@ def compare_page(old: Dict[str, dict], new: Dict[str, dict],
 
 def _insights_records(recs: Dict[str, dict]) -> Dict[str, dict]:
     """The INSIGHTS shape: records carrying an `insights` block with
-    per-shape rows (bench.py --insights)."""
+    per-shape rows."""
     return {k: r for k, r in recs.items()
             if isinstance(r.get("insights"), dict)
             and isinstance(r["insights"].get("shapes"), dict)}
@@ -635,7 +634,8 @@ def compare_maxsim(old: Dict[str, dict], new: Dict[str, dict],
 
 def _kernels_records(recs: Dict[str, dict]) -> Dict[str, dict]:
     """The BENCH_KERNELS shape: per-(bench, family) rows carrying a
-    kernel `family` next to a `device_ms` total (bench.py --kernels)."""
+    kernel `family` next to a `device_ms` total (rows nothing writes
+    since PR 31 took the sampled timer out: ROADMAP D5b)."""
     return {k: r for k, r in recs.items()
             if isinstance(r.get("family"), str) and "device_ms" in r}
 

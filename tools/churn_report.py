@@ -3,8 +3,8 @@
 
 Input: any JSON/JSONL artifact that carries churn records — a saved
 `GET /_telemetry/ingest` response ({"churn": {"records": [...]}}), a
-bare list of churn records, or bench.py interference output lines
-(records embedding a "churn_records" list). The table is the ISSUE 16
+bare list of churn records, or JSONL records embedding a
+"churn_records" list. The table is the ISSUE 16
 acceptance surface in one place: per refresh/merge, how many bytes the
 event actually shipped (delta publish), how many interned memo entries
 it invalidated vs kept (segment-keyed carry), and where each event's
@@ -12,7 +12,6 @@ recompile verdict LANDED (warm hit / precompiled off-path / paid on a
 serving thread).
 
     python tools/churn_report.py ingest_dump.json
-    python tools/churn_report.py BENCH_INTERFERENCE_r02.json
 """
 
 from __future__ import annotations

@@ -2,8 +2,8 @@
 """Top shapes by device-ms — render a query-insights dump as a table.
 
 Input (auto-detected), any of:
-  - INSIGHTS_r*.json (bench.py --insights output: one JSON record per
-    line, the insights block under "insights");
+  - a JSONL file, one record per line, the insights block under
+    "insights";
   - a saved `GET /_insights` response ({"insights": {...}});
   - a bare insights snapshot ({"shapes": {...}, "totals": {...}}).
 
@@ -12,10 +12,10 @@ The report answers the per-class questions ROADMAP items 3/4 need
 per-class cost): which shape classes own the device wall, what they
 scan, how well they coalesce, and who sends them.
 
-    python tools/insights_report.py INSIGHTS_r01.json
+    python tools/insights_report.py insights.json
     curl -s localhost:9200/_insights | python tools/insights_report.py -
-    python tools/insights_report.py --metric scan INSIGHTS_r01.json
-    python tools/insights_report.py --assert-shapes 3 INSIGHTS_r01.json
+    python tools/insights_report.py --metric scan insights.json
+    python tools/insights_report.py --assert-shapes 3 insights.json
 """
 
 from __future__ import annotations
@@ -85,7 +85,6 @@ def shape_rows(ins: dict, sort_key: str = "device_ms_total") \
             "warm": r.get("warm_hits", 0),
             "compiled": r.get("compiled", 0),
             "cached": r.get("cached", 0),
-            "kernel": r.get("dominant_kernel") or "-",
             "_scan_bytes": scan,
             "took_total_ms": round(float(r.get("took_total_ms", 0)), 1),
             "device_ms_total": float(r.get("device_ms_total", 0)),
@@ -98,7 +97,7 @@ def shape_rows(ins: dict, sort_key: str = "device_ms_total") \
 def render_shapes(rows: List[dict]) -> str:
     cols = ["shape", "kind", "count", "p50_ms", "p99_ms", "device_ms",
             "scan_kb", "transfer_kb", "co_batch", "warm", "compiled",
-            "cached", "kernel"]
+            "cached"]
     return _render([{c: r.get(c) for c in cols} for r in rows], cols)
 
 
@@ -151,8 +150,7 @@ def main(argv: List[str]) -> int:
     ins = load_insights(path)
     if ins is None:
         print("no insights block found (enable the recorder: "
-              "POST /_insights/_enable, then re-run traffic, or run "
-              "bench.py --clients N --insights)")
+              "POST /_insights/_enable, then re-run traffic)")
         return 1
     rows = shape_rows(ins, SORT_KEYS[metric])
     totals = ins.get("totals", {})

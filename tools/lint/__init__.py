@@ -2,8 +2,8 @@
 
 Four AST-based checkers enforce the invariants the ROADMAP item-1/item-2
 rewrites (on-device top-k + overlapped transfers, async wave scheduler)
-depend on — invariants that were previously enforced by convention and
-re-verified only dynamically (bench.py's no-op asserts):
+depend on — invariants that were previously enforced by convention
+only:
 
 - sync-lint          every host<->device sync site on the query path is
                      ledger-attributed or carries `# sync-ok: <channel>`

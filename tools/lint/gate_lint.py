@@ -1,6 +1,5 @@
 """gate-lint: OFF-by-default subsystems must follow the None-returning
-scope-gate pattern — the no-op discipline bench.py asserts dynamically,
-promoted to a static check.
+scope-gate pattern — the no-op discipline as a static check.
 
 The contract (PR 4 tracer, PR 6 fault injector, PR 7 transfer ledger,
 PR 8 sync sanitizer, PR 10 flight recorder): a subsystem that is OFF by
@@ -111,13 +110,6 @@ GATED_SUBSYSTEMS = (
     # the host numpy mirror (same f32 math, no device dispatch)
     ("opensearch_tpu/searchpipeline/processors.py", None,
      "MAXSIM_DEVICE_RESCORE", ()),
-    # ISSUE 19 kernel profiler: the sampled-dispatch timer is OFF by
-    # default behind a None-returning gate() — disabled, executables
-    # return UNWRAPPED (no timer closure); the executable census is
-    # always-on but writes only at compile time (never on the steady
-    # state), the inflight-wave-gauge contract, not this discipline
-    ("opensearch_tpu/telemetry/kernels.py", "KernelProfiler", "enabled",
-     ("gate",)),
     # ISSUE 20 block-max pruning: OFF by default — the pristine query
     # path compiles no tid/bscale inputs and masks nothing; the seal-
     # time bounds leaf is always present (upload cost, not query cost)

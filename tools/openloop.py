@@ -2,12 +2,12 @@
 """Open-loop concurrent-clients harness: Poisson arrivals, coordinated-
 omission-safe latency.
 
-The closed-loop bench (bench.py's batch/latency passes) measures "how
-fast can ONE caller pump requests" — it cannot see contention, and its
-latency numbers suffer coordinated omission: a stalled server delays the
-*sending* of the next request, so the stall's queueing damage never
-appears in the recorded distribution. This harness is the open-loop
-counterpart (ROADMAP item 2's acceptance instrument):
+A closed loop measures "how fast can ONE caller pump requests" — it
+cannot see contention, and its latency numbers suffer coordinated
+omission: a stalled server delays the *sending* of the next request, so
+the stall's queueing damage never appears in the recorded distribution.
+This harness is the open-loop counterpart (ROADMAP item 2's acceptance
+instrument):
 
 - arrivals follow a seeded Poisson process at `arrival_rate`/s — the
   request schedule is fixed BEFORE the run and never slows down because
@@ -21,9 +21,9 @@ counterpart (ROADMAP item 2's acceptance instrument):
   separately: it is the number the item-2 wave scheduler's admission
   control will be judged by.
 
-Pure stdlib; importable by bench.py (`--clients/--arrival-rate`) and by
-tests/test_openloop.py, which pins the coordinated-omission property
-against a synthetic server with an injected stall (common/faults.py).
+Pure stdlib; imported by tests/test_openloop.py, which pins the
+coordinated-omission property against a synthetic server with an
+injected stall (common/faults.py).
 """
 
 from __future__ import annotations

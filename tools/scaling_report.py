@@ -1,17 +1,17 @@
 #!/usr/bin/env python
-"""Render the multi-chip scaling-efficiency record (SCALING_MC_r*.json,
-bench.py --devices — ISSUE 14).
+"""Render a multi-chip scaling-efficiency record (ISSUE 14): JSONL, one
+point per device count.
 
 One row per device count D: serving QPS on the real segment-sharded
 SPMD path, per-chip scaling efficiency QPS(D)/(D·QPS(1)), straggler
 skew (max−median per-chip wall), analytic collective bytes/query over
 the ICI, and the live scanned-bytes counter (the block-max trigger
-metric — tools/scaling_bench.py's offline column, live). A per-device section
+metric). A per-device section
 breaks each point down by chip: partial wall, straggler hits, h2d
 bytes.
 
-    python tools/scaling_report.py SCALING_MC_r01.json
-    python tools/scaling_report.py --assert-efficiency 0.5 SCALING_MC_r01.json
+    python tools/scaling_report.py points.jsonl
+    python tools/scaling_report.py --assert-efficiency 0.5 points.jsonl
 
 --assert-efficiency F: exit 1 unless every multi-chip point (D > 1)
 holds per-chip efficiency >= F — the harness's own floor check, next
@@ -122,11 +122,10 @@ def main(argv: List[str]) -> int:
                 else float(rest.pop(0))
         else:
             args.append(a)
-    path = args[0] if args else "SCALING_MC_r01.json"
+    path = args[0] if args else "-"
     records = load_records(path)
     if not records:
-        print(f"no scaling points found in {path} "
-              f"(run: python bench.py --devices 1,2,4,8)")
+        print(f"no scaling points found in {path}")
         return 1
     print(f"multi-chip scaling ({path}): QPS(D) on the real SPMD "
           f"serving path, efficiency = QPS(D)/(D*QPS(1))")

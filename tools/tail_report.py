@@ -3,9 +3,8 @@
 lifecycle phases.
 
 Input (auto-detected), any of:
-  - the flight recorder's JSONL export (`<data>/_state/tail.jsonl`, or
-    bench.py --clients' BENCH_CONC_TAIL_*.jsonl) — one capture record
-    per line;
+  - the flight recorder's JSONL export (`<data>/_state/tail.jsonl`) —
+    one capture record per line;
   - a saved `GET /_telemetry/tail` response ({"captured": [...]});
   - a bare JSON array of capture records.
 
@@ -21,7 +20,7 @@ disjoint phase and counts.
 
     python tools/tail_report.py data/_state/tail.jsonl
     curl -s localhost:9200/_telemetry/tail | python tools/tail_report.py -
-    python tools/tail_report.py --assert-attribution 90 BENCH_CONC_TAIL_r01.jsonl
+    python tools/tail_report.py --assert-attribution 90 tail.jsonl
 """
 
 from __future__ import annotations

@@ -13,9 +13,8 @@ Input (auto-detected), any of:
   - a saved `GET /_telemetry/transfers` response
     ({"transfers": {...}, "device_memory": {...}});
   - a bare ledger snapshot ({"channels": ..., "device_get": ...});
-  - a bench.py --telemetry output line (the snapshot rides at
-    telemetry.transfers), or the BENCH_*.json file holding such lines
-    (the first line carrying a ledger is reported).
+  - a JSONL file of records that carry the snapshot at
+    telemetry.transfers (the first line carrying a ledger is reported).
 
     python tools/transfer_report.py transfers.json
     curl -s localhost:9200/_telemetry/transfers | python tools/transfer_report.py -
@@ -151,8 +150,8 @@ def main(argv: List[str]) -> int:
     snap = load_snapshot(path)
     if snap is None:
         print("no transfer ledger found (enable it: "
-              "POST /_telemetry/transfers/_enable — or bench.py "
-              "--telemetry — then re-run traffic and dump "
+              "POST /_telemetry/transfers/_enable, then re-run "
+              "traffic and dump "
               "GET /_telemetry/transfers)")
         return 1
     for line in summary_lines(snap):
