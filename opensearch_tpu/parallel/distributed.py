@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import threading
 import time
+from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -88,7 +89,9 @@ from opensearch_tpu.ops.bm25 import blockmax_keep_mask, score_text_clause
 from opensearch_tpu.ops.topk import NEG_INF, value_merge_key
 from opensearch_tpu.search.compile import Plan
 from opensearch_tpu.search.plan_eval import _eval_plan
-from opensearch_tpu.search.aggs.engine import eval_aggs
+from opensearch_tpu.search.aggs.engine import (BINS_RANK, BINS_TABLE,
+                                               eval_aggs)
+from opensearch_tpu.telemetry import TELEMETRY
 from opensearch_tpu.telemetry.kernels import (jit_family, stage,
                                               timed_first_call)
 
@@ -222,6 +225,20 @@ def align_agg_plans(per_shard: Sequence[Sequence[Any]]) -> None:
                 # allow_fused=False, so this is defense in depth)
                 raise ValueError(
                     f"fused agg kind [{kind}] cannot align across shards")
+            if kind == "bucket_num" \
+                    and len({p.static[3] for p in group}) > 1:
+                # a row whose rank -> bucket table happened to be the
+                # identity beside rows whose is not: it takes its own
+                # table back (an entry a rank of ITS column; stacked rows
+                # grow to the widest), so that the rows keep one
+                # structure. Rows compiled for one chip carry their
+                # tables among their inputs, so it does too
+                carried = any("table" in p.inputs for p in group)
+                for p in group:
+                    if p.static[3] == BINS_RANK:
+                        p.static = p.static[:3] + (BINS_TABLE,)
+                        if carried:
+                            p.inputs = dict(p.inputs, table=p.table_of())
             if kind in _CARD_KINDS:
                 card = max(p.static[1] for p in group)
                 for p in group:
@@ -235,6 +252,66 @@ def align_agg_plans(per_shard: Sequence[Sequence[Any]]) -> None:
                 raise ValueError("filter-agg query plans diverge across shards")
 
     walk(list(per_shard))
+
+
+# The lane -> bin vector `table[val_ords]` of a `histogram` or
+# `date_histogram` level rests on nothing of the request: the table is a
+# function of the level's bucketing and the segment's sorted unique
+# values, the rank column is sealed, and the request's own part reaches
+# the bins through the mask alone. So the SPMD route derives it once a
+# (shard set, field, bucketing) and keeps it on the mesh, int32
+# `[R_pad, n_pad]` sharded like the image's own columns. A shard set
+# holds at most this many, least recently used out: one vector is 4 B a
+# lane a row, and a key is a panel's (field, interval, offset or time
+# zone), none of which moves from one request of a dashboard to the
+# next. (A `range` bucket's bounds can, `now`-relative ones with every
+# request: those stay out of the memo, on the table their request
+# brings.) Levels planned on the SPMD route, by where their bins came
+# from, and vectors dropped (the memo's own LRU, or with their shard
+# set): always on, in `_nodes/stats`.
+MAX_LANE_BINS = 4
+LANE_BINS_HIT = TELEMETRY.metrics.counter("search.agg_lane_bins.hit")
+LANE_BINS_MISS = TELEMETRY.metrics.counter("search.agg_lane_bins.miss")
+LANE_BINS_IDENTITY = TELEMETRY.metrics.counter(
+    "search.agg_lane_bins.identity")
+LANE_BINS_EVICTED = TELEMETRY.metrics.counter(
+    "search.agg_lane_bins.evicted")
+
+
+def resident_lane_bins(searcher: "DistributedSearcher",
+                       shard_set: "HbmShardSet",
+                       per_shard: Sequence[Sequence[Any]]) -> List[Any]:
+    """Take the static side of the `bucket_num` levels out of the
+    request, in place, on rows `align_agg_plans` has brought to one
+    structure: a level whose table is the identity reads the rank column
+    itself (nothing to do here but count it); a `histogram` or
+    `date_histogram` level names a slot of the returned list, which
+    holds the shard set's resident lane -> bin vector of that (field,
+    bucketing): found (a hit builds, hashes, stacks and uploads no
+    table) or derived now from the rows' tables by one program over the
+    resident rank column (a miss). A `range` bucket keeps the table it
+    brought. `search_resident(lane_bins=...)` hands the list to the
+    served program, which is the same executable on the miss and on the
+    hit."""
+    slots: List[Any] = []
+
+    def walk(nodes: Sequence[Sequence[Any]]):
+        for group in zip(*nodes):
+            p0 = group[0]
+            if p0.kind == "bucket_num" and p0.static[3] == BINS_RANK:
+                LANE_BINS_IDENTITY.inc()
+            elif p0.kind == "bucket_num" and p0.table_of is not None:
+                field = p0.static[0]
+                slots.append(shard_set.lane_bins_of(
+                    (field,) + p0.bins_key,
+                    lambda: searcher.derive_lane_bins(
+                        shard_set, field, [p.table_of() for p in group])))
+                for p in group:
+                    p.static = p.static[:3] + (len(slots) - 1,)
+            walk([p.children for p in group])
+
+    walk(list(per_shard))
+    return slots
 
 
 def _count_agg_nodes(p) -> int:
@@ -292,17 +369,58 @@ class HbmShardSet:
         self.seg_stack = _device_put_sharded_tree(
             stack, searcher.mesh, searcher.axis)
         self.shapes = _tree_shapes(self.seg_stack)
-        # per-device HBM accounting (ISSUE 14): the stacked image's
-        # exact per-device split on the device-memory gauges — released
-        # by the residency cache (search/spmd.py) at eviction
-        from opensearch_tpu.telemetry import TELEMETRY
         self.nbytes = sum(
             int(np.prod(v.shape)) * np.dtype(v.dtype).itemsize
             for _, v in jax.tree_util.tree_flatten_with_path(
                 self.seg_stack)[0])
+        # the resident lane -> bin vectors of this set's rows, by (field,
+        # bucketing scalars): `resident_lane_bins`. They live and die
+        # with the set, so a refresh's new set starts with none
+        self._lane_bins: "OrderedDict[tuple, Any]" = OrderedDict()
+        self._lane_bins_lock = threading.Lock()
+        self._released = False
+        self._register()
+
+    def _register(self) -> None:
+        """Per-device HBM accounting (ISSUE 14): the stacked image and
+        the lane -> bin vectors beside it, by their exact per-device
+        split, as ONE entry of the device-memory gauges, which the
+        residency cache (search/spmd.py) releases at eviction."""
+        total = self.nbytes + sum(v.nbytes for v in self._lane_bins.values())
         TELEMETRY.device_memory.register(
-            "spmd_shard_sets", id(self), self.nbytes,
-            devices=mesh_device_split(self.mesh, self.nbytes))
+            "spmd_shard_sets", id(self), total,
+            devices=mesh_device_split(self.mesh, total))
+
+    def release(self) -> None:
+        """The residency cache dropped this set: the image and its
+        lane -> bin vectors leave the device-memory gauges together (a
+        request still running on the set adds to them no more)."""
+        with self._lane_bins_lock:
+            self._released = True
+            LANE_BINS_EVICTED.inc(len(self._lane_bins))
+            self._lane_bins.clear()
+        TELEMETRY.device_memory.release("spmd_shard_sets", id(self))
+
+    def lane_bins_of(self, key: tuple, derive):
+        """The resident vector under `key`, least recently used last;
+        `derive()` makes it on a miss, under the set's lock, so that
+        concurrent requests of one panel derive it once."""
+        with self._lane_bins_lock:
+            bins = self._lane_bins.get(key)
+            if bins is not None:
+                self._lane_bins.move_to_end(key)
+                LANE_BINS_HIT.inc()
+                return bins
+            LANE_BINS_MISS.inc()
+            bins = derive()
+            if self._released:
+                return bins
+            while len(self._lane_bins) >= MAX_LANE_BINS:
+                self._lane_bins.popitem(last=False)
+                LANE_BINS_EVICTED.inc()
+            self._lane_bins[key] = bins
+            self._register()
+            return bins
 
 
 class DistributedSearcher:
@@ -457,6 +575,42 @@ class DistributedSearcher:
             fn, family="spmd_query_phase",
             shape=f"r{self.n_shards}x{rpd}xd{d_pad}k{k}", key=key)
 
+    def derive_lane_bins(self, shard_set: HbmShardSet, field: str,
+                         tables: Sequence[np.ndarray]):
+        """`table[val_ords]` of every row of the set, -1 where the table
+        says no bucket or the lane is padding: int32 `[R_pad, n_pad]` on
+        the mesh, sharded like the image. One gather a row over the
+        resident rank column, what the served program did a request; the
+        rows' tables (`[u_pad]` each) are uploaded for it and dropped."""
+        col = shard_set.seg_stack["numeric"][field]
+        r_pad = self.n_shards * shard_set.rows_per_dev
+        # padding rows take row 0's, as their flat inputs do; a table
+        # grown to the widest holds ranks its row never reaches
+        stack = pad_stack_trees(
+            list(tables) + [tables[0]] * (r_pad - len(tables)))
+        key = ("lane_bins", stack.shape, tuple(col["val_ords"].shape))
+        fn = self._cache.get(key)
+        if fn is None:
+            def one_row(table, doc_ids, val_ords):
+                return jnp.where(doc_ids >= 0, table[val_ords], -1)
+
+            spec = P(self.axis)
+            mapped = _shard_map(jax.vmap(one_row), mesh=self.mesh,
+                                in_specs=(spec, spec, spec), out_specs=spec)
+
+            def agg_lane_bins(table, doc_ids, val_ords):
+                return mapped(table, doc_ids, val_ords)
+
+            fn = jax.jit(agg_lane_bins)     # module `jit_agg_lane_bins`
+            self._cache[key] = fn   # shared-state-ok: benign double-jit race; dict slot write is GIL-atomic
+            # a compile on the serving thread counts as one
+            # (search.xla_cache_miss); no census record: not a served
+            # program, and it runs once a (shard set, field, bucketing)
+            fn = timed_first_call(fn)
+        with _DISPATCH_LOCK:
+            stack = _device_put_sharded_tree(stack, self.mesh, self.axis)
+            return fn(stack, col["doc_ids"], col["val_ords"])
+
     def build_shard_set(self, shard_arrays: Sequence[Dict],
                         metas: Sequence[Any]) -> HbmShardSet:
         """Upload the shard segments to HBM once; reuse across queries."""
@@ -484,7 +638,8 @@ class DistributedSearcher:
                         agg_plans: Tuple = (),
                         sort_spec: Optional[Tuple[str, str]] = None,
                         device_scope=None, return_pruned: bool = False,
-                        marks: Optional[dict] = None):
+                        marks: Optional[dict] = None,
+                        lane_bins: Sequence[Any] = ()):
         """Run the distributed query phase against HBM-resident segments:
         only the flat plan inputs (query constants — term ids, weights,
         range bounds) travel host→device per query.
@@ -516,7 +671,11 @@ class DistributedSearcher:
         are made of (`time.monotonic()`): `dispatch` = (first literal
         upload, the jit call's return, bytes uploaded, the executable's
         `exec_info`) and `device_wait` = (start, end, bytes) of the
-        blocking pull of the result page."""
+        blocking pull of the result page.
+
+        `lane_bins`: what `resident_lane_bins` returned for these
+        `agg_plans`; already on the mesh, the program reads them as
+        `seg["lane_bins"][slot]`."""
         if len(flat_inputs) != shard_set.n_rows:
             raise ValueError(
                 f"{len(flat_inputs)} flat-input lists for a "
@@ -567,8 +726,11 @@ class DistributedSearcher:
                 # item-2 scheduler budgets against — only the
                 # conversions below (which block on compute + transfer,
                 # like the executor's device_get) are the collect wall
+                seg_stack = shard_set.seg_stack
+                if lane_bins:
+                    seg_stack = dict(seg_stack, lane_bins=list(lane_bins))
                 keys, scores, gids, total, pruned_rows, agg_outs = fn(
-                    shard_set.seg_stack, flat_stack, min_stack)
+                    seg_stack, flat_stack, min_stack)
             t_enqueued = time.monotonic()
             if device_scope is not None:
                 device_scope.devices = self.n_shards

@@ -21,6 +21,22 @@ five-reduction battery). Nesting uses the classic flattened-ordinal trick
 (parent_ord * child_card + child_ord), like the reference's bucketOrd
 composition.
 
+Where a numeric level's bins come from (kind `bucket_num`, `static[3]`;
+`_bucket_lookup_plan`). The lane -> bin vector `table[val_ords]` rests on
+nothing of the request, so no route should compute it a request:
+- a table that is the identity (`terms` on a numeric column) is never
+  gathered through, on any route: the rank column is the bin (BINS_RANK);
+- the SPMD route (search/spmd.py) keeps the vector of any other
+  `histogram`/`date_histogram` level resident on the mesh beside the
+  shard set's image, derived once a (shard set, field, bucketing):
+  parallel/distributed.py `resident_lane_bins`; the plan names its slot,
+  and its table is built on a miss alone (`AggPlan.table_of`);
+- the one-chip routes (host loop, agg envelope), and a `range` bucket on
+  every route (its bounds may move with every request), keep the table
+  among the request's inputs and gather a request (BINS_TABLE), except
+  for root leaves on one chip, whose lane bitmasks are precomputed and
+  closed over (`bucket_bits`).
+
 Approximation policy: the reference uses TDigest percentiles and HLL++
 cardinality; here both are EXACT, computed from per-bucket value-rank
 histograms / presence bitmaps (feasible because doc values are rank-encoded
@@ -35,7 +51,7 @@ from __future__ import annotations
 
 import re
 from dataclasses import dataclass, field as dc_field, replace as dc_replace
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 import jax
@@ -106,6 +122,14 @@ class AggPlan:
     query_plan: Optional[Plan] = None      # filter aggs
     query_plans: List[Plan] = dc_field(default_factory=list)  # adjacency
     render: Dict[str, Any] = dc_field(default_factory=dict)  # host-only
+    # a `histogram`/`date_histogram` level of kind bucket_num: the scalars
+    # that, with the field and the segment's sorted unique values, define
+    # its rank -> bucket table (interval and shift, or the calendar unit),
+    # and the function that builds the table, padded, when asked. What the
+    # SPMD route keys a level's resident lane -> bin vector by and derives
+    # it from: a hit neither hashes nor builds a table
+    bins_key: Optional[tuple] = None
+    table_of: Optional[Callable[[], np.ndarray]] = None
     # segment-static arrays CLOSED OVER by the device program instead of
     # riding the input envelope (fused bucket_bits/presence_bits kinds):
     # zero per-batch pack/upload bytes, zero in-program recompute. Content
@@ -169,7 +193,10 @@ class _Ctx:
     root: bool = False
     # False for cross-row tracing paths (SPMD): fused kinds embed
     # segment-specific constants in the executable, which a single program
-    # traced from row 0 would wrongly apply to every row
+    # traced from row 0 would wrongly apply to every row. That route
+    # compiles every row for every request and keeps a numeric level's
+    # bins resident, so it also leaves the level's rank -> bucket table
+    # unbuilt (`_bucket_lookup_plan`)
     fused: bool = True
 
 
@@ -211,20 +238,55 @@ def _ident_pairs(col) -> bool:
     return ident_pairs(col)
 
 
-def _bucket_lookup_plan(node: AggNode, ctx: _Ctx, kind: str,
-                        bucket_of_rank: np.ndarray, card: int,
-                        render: dict, children_card_mult: bool = True) -> AggPlan:
-    u_pad = pad_bucket(max(len(bucket_of_rank), 1), minimum=8)
-    table = np.full(u_pad, -1, dtype=np.int32)
-    table[:len(bucket_of_rank)] = bucket_of_rank
-    children = [_compile_node(c, ctx) for c in node.children]
-    col = (ctx.seg.ordinal_dv.get(node.field)
-           if kind == "bucket_ord" else _num_col(ctx, node.field))
-    return AggPlan(name=node.name, kind=kind,
-                   static=(node.field, card,
-                           col is not None and _ident_pairs(col)),
-                   inputs={"table": table},
-                   children=children, render=render)
+# Where a `bucket_num` level's lane -> bin vector comes from
+# (`static[3]`, so in `plan_struct` and the program's fingerprint):
+# - BINS_RANK: the rank -> bucket table is the identity (`terms` on a
+#   numeric column; a histogram whose every unique value opens its own
+#   bucket), so the rank column `val_ords` IS the bin: no table input, no
+#   gather. Every route that runs `_eval_agg`.
+# - BINS_TABLE: the table rides the request's inputs and the program
+#   gathers `table[val_ords]` over every lane: the one-chip routes (host
+#   loop, agg envelope) for any other table, and every `range` bucket.
+# - an int slot: the gather's result is segment-static, so the SPMD route
+#   (parallel/distributed.py `resident_lane_bins`) derives it once a
+#   (shard set, field, `bins_key`), keeps it on the mesh beside the shard
+#   set's image and hands it to the program as `seg["lane_bins"][slot]`;
+#   the plan carries no table, and builds none on a hit.
+BINS_RANK = "rank"
+BINS_TABLE = "table"
+
+
+def _rank_table(bucket_of_rank: np.ndarray) -> np.ndarray:
+    """A rank -> bucket table as the program takes it: int32, padded to a
+    shape bucket with -1 ("no bucket") past the column's last rank."""
+    n = len(bucket_of_rank)
+    table = np.full(pad_bucket(max(n, 1), minimum=8), -1, dtype=np.int32)
+    table[:n] = bucket_of_rank
+    return table
+
+
+def _bucket_lookup_plan(node: AggNode, ctx: _Ctx, card: int, render: dict,
+                        bucket_of_rank: Callable[[], np.ndarray],
+                        bins_key: tuple) -> AggPlan:
+    """A `histogram`/`date_histogram` level over a numeric column.
+    `bucket_of_rank()` builds the bucket of every unique value of the
+    segment's column, `card` is its last entry + 1. The table can be the
+    identity only where `card` is the number of unique values, so it is
+    built here only then (to look), and on the one-chip routes, which
+    take it among the request's inputs; a cross-row compile (the SPMD
+    route, `ctx.fused` False) leaves it to `AggPlan.table_of`."""
+    col = _num_col(ctx, node.field)
+    n = len(col.unique)
+    identity = card == n and np.array_equal(bucket_of_rank(), np.arange(n))
+    plan = AggPlan(name=node.name, kind="bucket_num",
+                   static=(node.field, card, _ident_pairs(col),
+                           BINS_RANK if identity else BINS_TABLE),
+                   children=[_compile_node(c, ctx) for c in node.children],
+                   render=render, bins_key=bins_key,
+                   table_of=lambda: _rank_table(bucket_of_rank()))
+    if not identity and ctx.fused:
+        plan.inputs["table"] = plan.table_of()
+    return plan
 
 
 # ------------------------------------------------- fused leaf bucketing
@@ -370,13 +432,15 @@ def _c_terms(node: AggNode, ctx: _Ctx) -> AggPlan:
     if col is None:
         return AggPlan(node.name, "empty", render={"body": node.body,
                                                    "kind": "terms", "keys": []})
-    card = max(len(col.unique), 1)
-    bucket_of_rank = np.arange(len(col.unique), dtype=np.int32)
+    # a rank is its own bucket: the table would be the identity
     ft = ctx.mapper.get_field(field)
-    keys = [_render_numeric_key(v, ft) for v in col.unique]
-    return _bucket_lookup_plan(node, ctx, "bucket_num", bucket_of_rank, card,
-                               render={"keys": keys, "body": node.body,
-                                       "kind": "terms"})
+    return AggPlan(node.name, "bucket_num",
+                   static=(field, max(len(col.unique), 1), _ident_pairs(col),
+                           BINS_RANK),
+                   children=[_compile_node(c, ctx) for c in node.children],
+                   render={"keys": [_render_numeric_key(v, ft)
+                                    for v in col.unique],
+                           "body": node.body, "kind": "terms"})
 
 
 def _render_numeric_key(v: float, ft) -> Any:
@@ -403,19 +467,24 @@ def _c_histogram(node: AggNode, ctx: _Ctx) -> AggPlan:
                                "interval": interval, "offset": offset,
                                "step": interval, "shift": -offset,
                                "keys": []})
+    # the first and the last bucket say `card` and the keys; the bucket of
+    # every unique value in between is built only where someone reads it
     lo_key = np.floor((col.unique[0] - offset) / interval)
-    buckets = np.floor((col.unique - offset) / interval) - lo_key
-    card = int(buckets[-1]) + 1
+    card = int(np.floor((col.unique[-1] - offset) / interval) - lo_key) + 1
+
+    def buckets():
+        return (np.floor((col.unique - offset) / interval)
+                - lo_key).astype(np.int32)
     keys = [float(lo_key + i) * interval + offset for i in range(card)]
     render = {"keys": keys, "body": node.body, "kind": "histogram",
               "step": interval, "shift": -offset}
     nv_pad = pad_bucket(max(len(col.doc_ids), 1))
     if _fused_gate(ctx, node, card, nv_pad):
-        lane_bins = buckets.astype(np.int64)[col.value_ords]
+        lane_bins = buckets().astype(np.int64)[col.value_ords]
         return _fused_bits_plan(node, ctx, col, "numeric", lane_bins, card,
                                 render)
-    return _bucket_lookup_plan(node, ctx, "bucket_num",
-                               buckets.astype(np.int32), card, render)
+    return _bucket_lookup_plan(node, ctx, card, render, buckets,
+                               bins_key=("histogram", interval, offset))
 
 
 def _calendar_boundaries(lo_ms: float, hi_ms: float, unit: str) -> List[int]:
@@ -491,12 +560,16 @@ def _c_date_histogram(node: AggNode, ctx: _Ctx) -> AggPlan:
         empty_render["calendar"] = True
     if col is None or len(col.unique) == 0:
         return AggPlan(node.name, "empty", render=empty_render)
+    # the first and the last bucket say `card` and the keys; the bucket of
+    # every unique value in between is built only where someone reads it
     if fixed is not None:
         step, _ = fixed
-        b_abs = np.floor((col.unique + shift) / step).astype(np.int64)
-        lo_key = int(b_abs[0])
-        buckets = b_abs - lo_key
-        card = int(buckets[-1]) + 1
+        lo_key = int(np.floor((col.unique[0] + shift) / step))
+        card = int(np.floor((col.unique[-1] + shift) / step)) - lo_key + 1
+
+        def buckets():
+            return (np.floor((col.unique + shift) / step).astype(np.int64)
+                    - lo_key).astype(np.int32)
         keys = [(lo_key + i) * step - shift for i in range(card)]
         render = {"keys": keys, "body": node.body, "kind": "date_histogram",
                   "step": step, "shift": shift}
@@ -504,8 +577,11 @@ def _c_date_histogram(node: AggNode, ctx: _Ctx) -> AggPlan:
         bounds = _calendar_boundaries(float(col.unique[0]) + shift,
                                       float(col.unique[-1]) + shift, unit)
         bounds = [b - shift for b in bounds]
-        buckets = np.searchsorted(np.asarray(bounds, dtype=np.float64),  # sync-ok: host -- compile-time bucket table from a Python list
-                                  col.unique, side="right") - 1
+
+        def buckets():
+            return (np.searchsorted(np.asarray(bounds, dtype=np.float64),  # sync-ok: host -- compile-time bucket table from a Python list
+                                    col.unique, side="right")
+                    - 1).astype(np.int32)
         card = len(bounds) - 1
         keys = bounds[:-1]
         render = {"keys": keys, "body": node.body, "kind": "date_histogram",
@@ -516,11 +592,13 @@ def _c_date_histogram(node: AggNode, ctx: _Ctx) -> AggPlan:
     render["keys_str"] = [format_date_millis(int(k)) for k in keys]
     nv_pad = pad_bucket(max(len(col.doc_ids), 1))
     if _fused_gate(ctx, node, card, nv_pad):
-        lane_bins = buckets.astype(np.int64)[col.value_ords]
+        lane_bins = buckets().astype(np.int64)[col.value_ords]
         return _fused_bits_plan(node, ctx, col, "numeric", lane_bins, card,
                                 render)
-    return _bucket_lookup_plan(node, ctx, "bucket_num",
-                               buckets.astype(np.int32), card, render)
+    return _bucket_lookup_plan(
+        node, ctx, card, render, buckets,
+        bins_key=("date_histogram", fixed[0] if fixed is not None else unit,
+                  shift))
 
 
 def _c_range(node: AggNode, ctx: _Ctx) -> AggPlan:
@@ -587,7 +665,8 @@ def _c_range(node: AggNode, ctx: _Ctx) -> AggPlan:
         table = np.full(u_pad, -1, dtype=np.int32)
         table[lo:hi] = 0
         sub_plans.append(AggPlan(f"{node.name}#{i}", "bucket_num",
-                                 static=(field, 1, _ident_pairs(col)),
+                                 static=(field, 1, _ident_pairs(col),
+                                         BINS_TABLE),
                                  inputs={"table": table},
                                  children=[_compile_node(c, ctx)
                                            for c in node.children]))
@@ -1303,16 +1382,25 @@ def _eval_agg(plan: AggPlan, seg: Dict, inputs: List[Dict], cursor: List[int],
         return
 
     if kind in ("bucket_ord", "bucket_num"):
-        field, card, ident = plan.static
+        field, card, ident = plan.static[:3]
         col = seg["ordinal" if kind == "bucket_ord" else "numeric"][field]
-        ords = col["ords"] if kind == "bucket_ord" else col["val_ords"]
         doc_ids = col["doc_ids"]
         valid = doc_ids >= 0
         safe_doc = jnp.where(valid, doc_ids, 0)
-        b = my["table"][ords] if kind == "bucket_num" else ords
+        # static side: which bin each (doc, value) pair lands in. A
+        # dictionary ordinal, and a rank whose table is the identity, is
+        # the bin; any other table says -1 for "no bucket"
+        bins = None if kind == "bucket_ord" else plan.static[3]
+        if bins is None:
+            b = col["ords"]
+        elif bins == BINS_RANK:
+            b = col["val_ords"]
+        elif bins == BINS_TABLE:
+            b = my["table"][col["val_ords"]]
+        else:
+            b = seg["lane_bins"][bins]
+        bin_ok = valid if bins in (None, BINS_RANK) else valid & (b >= 0)
         total = parent_card * card
-        # static side: which bin each (doc, value) pair lands in
-        bin_ok = valid & (b >= 0 if kind == "bucket_num" else True)
         base = 0
         if pbin is not None:
             pb = _take_doc(pbin, safe_doc, ident)

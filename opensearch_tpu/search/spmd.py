@@ -261,7 +261,8 @@ def spmd_query_phase(executors: List, body: dict, k: int,
 
 def _spmd_query_phase_raw(executors: List, body: dict, k: int,
                           extra_filters, rows):
-    from opensearch_tpu.parallel.distributed import plan_struct
+    from opensearch_tpu.parallel.distributed import (plan_struct,
+                                                     resident_lane_bins)
 
     t_plan = time.monotonic()
     node = dsl.parse_query(body.get("query"))
@@ -315,12 +316,6 @@ def _spmd_query_phase_raw(executors: List, body: dict, k: int,
     for p, aps in zip(plans[1:], agg_plans_rows[1:]):
         if (plan_struct(p), tuple(plan_struct(a) for a in aps)) != struct0:
             return note_fallback("plan_structure")
-    flat_rows = []
-    for plan, aps in zip(plans, agg_plans_rows):
-        flat = plan.flatten_inputs([])
-        for ap in aps:
-            ap.flatten_inputs(flat)
-        flat_rows.append(flat)
 
     from opensearch_tpu.search.executor import _parse_sort, _sort_value
     sort_specs = _parse_sort(body.get("sort"))
@@ -350,11 +345,23 @@ def _spmd_query_phase_raw(executors: List, body: dict, k: int,
     marks: dict = {}
     try:
         shard_set = _resident_shard_set(searcher, executors, rows)
+        # the static side of every `bucket_num` level leaves the request
+        # before its inputs are flattened: the plans name the shard
+        # set's resident lane -> bin vectors, and carry no table
+        lane_bins = resident_lane_bins(searcher, shard_set,
+                                       agg_plans_rows)
+        flat_rows = []
+        for plan, aps in zip(plans, agg_plans_rows):
+            flat = plan.flatten_inputs([])
+            for ap in aps:
+                ap.flatten_inputs(flat)
+            flat_rows.append(flat)
         keys, scores, row_idx, ords, total, agg_outs, pruned_rows = \
             searcher.search_resident(
                 shard_set, flat_rows, plans[0], k, min_score=min_score,
                 agg_plans=agg_plans_rows[0], sort_spec=sort_spec,
-                device_scope=cap, return_pruned=True, marks=marks)
+                device_scope=cap, return_pruned=True, marks=marks,
+                lane_bins=lane_bins)
     except (ValueError, KeyError):
         # e.g. a cross-index search whose rows have mismatched field
         # layouts (canonical_meta rejects them) — host loop handles it
@@ -537,5 +544,5 @@ def _resident_shard_set(searcher, executors, rows):
         # the residency cache owns the shard set's device-memory gauge
         # (HbmShardSet registers at build): release at eviction so the
         # spmd_shard_sets class tracks LIVE HBM, not history
-        TELEMETRY.device_memory.release("spmd_shard_sets", id(evicted))
+        evicted.release()
     return shard_set

@@ -328,3 +328,81 @@ def test_agg_on_unmapped_field(executor):
                          "y": {"sum": {"field": "ghost"}}})
     assert out["x"]["buckets"] == []
     assert out["y"]["value"] == 0
+
+
+# -------------------------------------------- identity rank -> bucket table
+
+def _compiled(executor, spec):
+    """(segment, device arrays, the plans `compile_aggs` gives it)."""
+    from opensearch_tpu.search.aggs.engine import compile_aggs
+    from opensearch_tpu.search.aggs.parse import parse_aggs
+    from opensearch_tpu.search.compile import Compiler
+    reader = executor.reader
+    compiler = Compiler(reader.mapper, reader.stats())
+    for seg, (arrays, meta) in zip(reader.segments, reader.device):
+        yield seg, arrays, compile_aggs(parse_aggs(spec), reader.mapper,
+                                        seg, meta, compiler)
+
+
+def test_terms_on_a_numeric_column_reads_the_rank_column(executor):
+    """ISSUE 32: the rank -> bucket table of `terms` on a numeric column
+    is the identity, so its plan says so, carries no table, and the
+    program takes the rank column for the bin: the answer is what the
+    same level gathered through its identity table gives (the parent's
+    program), on every one-chip route."""
+    import jax
+    import jax.numpy as jnp
+    from dataclasses import replace
+    from opensearch_tpu.search.aggs.engine import (BINS_RANK, BINS_TABLE,
+                                                   eval_aggs)
+    spec = {"q": {"terms": {"field": "qty", "order": {"_key": "asc"}},
+                  "aggs": {"p": {"sum": {"field": "price"}}}}}
+    got = agg(executor, spec)["q"]["buckets"]
+    assert [(b["key"], b["doc_count"], b["p"]["value"]) for b in got] \
+        == [(d["qty"], 1, d.get("price", 0.0)) for d in DOCS]
+    for seg, arrays, (plan,) in _compiled(executor, spec):
+        assert plan.kind == "bucket_num" and plan.static[3] == BINS_RANK
+        assert plan.inputs == {}
+        n = len(seg.numeric_dv["qty"].unique)
+        table = np.full(8, -1, np.int32)
+        table[:n] = np.arange(n)
+        parent = replace(plan, static=plan.static[:3] + (BINS_TABLE,),
+                         inputs={"table": table})
+
+        def run(p):
+            outs = []
+            flat = jax.tree_util.tree_map(jnp.asarray, p.flatten_inputs([]))
+            eval_aggs([p], arrays, flat, [0], arrays["live"], outs)
+            return outs
+        mine, theirs = run(plan), run(parent)
+        assert jax.tree_util.tree_structure(mine) \
+            == jax.tree_util.tree_structure(theirs)
+        for a, b in zip(jax.tree_util.tree_leaves(mine),
+                        jax.tree_util.tree_leaves(theirs)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("spec,identity", [
+    ({"histogram": {"field": "qty", "interval": 1}}, True),
+    ({"histogram": {"field": "qty", "interval": 2}}, False),
+    ({"date_histogram": {"field": "day", "calendar_interval": "month"}},
+     False)],
+    ids=["every-value-its-own-bucket", "two-values-a-bucket", "months"])
+def test_a_table_is_the_identity_by_what_it_holds(spec, identity):
+    """Decided from the table the compiler just built, not from the
+    aggregation's type: a histogram whose every unique value opens its
+    own bucket is the identity too, any other keeps its table (a
+    sub-aggregation keeps these off the fused root-leaf kinds)."""
+    from opensearch_tpu.search.aggs.engine import BINS_RANK, BINS_TABLE
+    ex = build_executor()
+    spec = {"h": dict(spec, aggs={"p": {"sum": {"field": "price"}}})}
+    for seg, arrays, (plan,) in _compiled(ex, spec):
+        assert plan.kind == "bucket_num" and plan.bins_key is not None
+        assert plan.static[3] == (BINS_RANK if identity else BINS_TABLE)
+        assert ("table" in plan.inputs) == (not identity)
+    field = next(iter(spec["h"].values()))["field"]
+    buckets = agg(ex, spec)["h"]["buckets"]
+    assert sum(b["doc_count"] for b in buckets) \
+        == sum(1 for d in DOCS if field in d)
+    assert sum(b["p"]["value"] for b in buckets) \
+        == sum(d.get("price", 0.0) for d in DOCS if field in d)

@@ -6,7 +6,11 @@ ring, `dispatch` naming the executable; the program is an XLA module
 that `spmd.eligible` admitted and that ends in the host loop is counted
 (`search.spmd_fallbacks`, by reason), and rows whose `date_histogram`s
 differ in bin count are no such request; a `Segment` over a lazy id
-sequence answers what one over a list answers.
+sequence answers what one over a list answers. A `bucket_num` level's
+lane -> bin vector leaves the request (ISSUE 32): derived once a (shard
+set, field, bucketing), kept on the mesh, counted
+(`search.agg_lane_bins.*`), released with its shard set; an identity
+table reads the rank column.
 """
 
 import http.client
@@ -75,20 +79,21 @@ def served():
     server.close()
 
 
-def expected(docs):
-    lo = BODY["query"]["range"]["@timestamp"]["gte"]
-    hi = BODY["query"]["range"]["@timestamp"]["lt"]
+def expected(docs, body=BODY, step=HOUR, shift=0):
+    lo = body["query"]["range"]["@timestamp"]["gte"]
+    hi = body["query"]["range"]["@timestamp"]["lt"]
     sel = [d for d in docs if lo <= d["@timestamp"] < hi]
     hours = {}
     for d in sel:
-        by = hours.setdefault(d["@timestamp"] // HOUR * HOUR, {})
+        key = (d["@timestamp"] + shift) // step * step - shift
+        by = hours.setdefault(key, {})
         c, s = by.get(d["status"], (0, 0))
         by[d["status"]] = (c + 1, s + d["size"])
     return len(sel), hours
 
 
-def check_response(resp, docs):
-    total, hours = expected(docs)
+def check_response(resp, docs, body=BODY, step=HOUR, shift=0):
+    total, hours = expected(docs, body, step, shift)
     assert resp["hits"]["total"] == {"value": total, "relation": "eq"}
     assert resp["_shards"]["failed"] == 0 and resp["timed_out"] is False
     buckets = resp["aggregations"]["by_hour"]["buckets"]
@@ -100,10 +105,10 @@ def check_response(resp, docs):
         assert got == {k: (c, float(s)) for k, (c, s) in want.items()}
 
 
-def counters():
+def counters(prefix="search.spmd_"):
     return {k: v for k, v in
             TELEMETRY.metrics.to_dict()["counters"].items()
-            if k.startswith("search.spmd_")}
+            if k.startswith(prefix)}
 
 
 def post(server, body):
@@ -340,3 +345,351 @@ def test_a_repeated_id_in_a_list_keeps_its_last_row():
     seg = segment_over(["a", "b", "a", None])
     assert seg.ord_of("a") == 2 and seg.ord_of("b") == 1
     assert seg.ord_of(None) is None
+
+
+# --------------------------------------------- resident lane -> bin vectors
+
+def lane_bins():
+    return {k.rsplit(".", 1)[1]: v
+            for k, v in counters("search.agg_lane_bins.").items()}
+
+
+def delta(after, before):
+    return {k: after[k] - before[k] for k in after if after[k] != before[k]}
+
+
+def drop_shard_sets():
+    """What a refresh does to every resident set at once."""
+    with spmd._SPMD_LOCK:
+        sets = list(spmd._SHARD_SETS.values())
+        spmd._SHARD_SETS.clear()
+    for shard_set in sets:
+        shard_set.release()
+
+
+def resident_bytes():
+    return TELEMETRY.device_memory.stats()["classes"].get(
+        "spmd_shard_sets", {"live_bytes": 0})["live_bytes"]
+
+
+def the_shard_set():
+    (shard_set,) = spmd._SHARD_SETS.values()
+    return shard_set
+
+
+def with_hist(body, **hist):
+    body = json.loads(json.dumps(body))
+    body["aggs"]["by_hour"]["date_histogram"] = dict(
+        {"field": "@timestamp"}, **hist)
+    return body
+
+
+def test_the_panel_on_the_miss_and_on_the_hit(served):
+    """The panel over rows of unequal `card`: the first request of a
+    shard set derives the hourly level's vector (a miss), every later
+    one finds it (a hit), `terms(status)` reads the rank column in both
+    (identity), and all of them answer what the host loop and the
+    references answer."""
+    from reference_impl import ref_date_histogram
+    node, server, docs = served
+    drop_shard_sets()
+    assert resident_bytes() == 0
+    before, seen = lane_bins(), []
+    for n, minutes in enumerate([21, 22, 23, 24]):
+        body = moved(BODY, minutes)
+        resp = post(server, body)
+        check_response(resp, docs, body)
+        r = body["query"]["range"]["@timestamp"]
+        assert {b["key"]: b["doc_count"]
+                for b in resp["aggregations"]["by_hour"]["buckets"]} \
+            == ref_date_histogram(
+                [d["@timestamp"] for d in docs
+                 if r["gte"] <= d["@timestamp"] < r["lt"]], fixed_ms=HOUR)
+        with spmd.force_host_loop():
+            want = node.request("POST", "/logs/_search", body)
+        assert resp["aggregations"] == want["aggregations"]
+        assert resp["hits"]["total"] == want["hits"]["total"]
+        assert delta(lane_bins(), before) == dict(
+            {"miss": 1, "identity": n + 1}, **({"hit": n} if n else {}))
+        seen.append(the_shard_set()._lane_bins[
+            ("@timestamp", "date_histogram", HOUR, 0)])
+    # one vector, the same array on the miss and on every hit: int32, a
+    # row a row of the image and a lane a lane of the rank column,
+    # sharded like it, on the device-memory ledger with the image
+    assert all(v is seen[0] for v in seen)
+    shard_set = the_shard_set()
+    ranks = shard_set.seg_stack["numeric"]["@timestamp"]["val_ords"]
+    assert seen[0].shape == ranks.shape and seen[0].dtype == np.int32
+    assert seen[0].sharding == ranks.sharding
+    assert resident_bytes() == shard_set.nbytes + seen[0].nbytes
+    # the same integers in the same lanes as the gather a request made
+    svc = node.indices.get("logs")
+    for row, shard in enumerate(svc.shards):
+        col = shard.engine.segments[0].numeric_dv["@timestamp"]
+        hours = col.unique.astype(np.int64) // HOUR
+        want = (hours - hours[0])[col.value_ords]
+        got = np.asarray(seen[0][row])
+        assert (got[:len(want)] == want).all() and (got[len(want):] == -1).all()
+    stats = next(iter(node.request("GET", "/_nodes/stats")["nodes"]
+                      .values()))["telemetry"]["metrics"]["counters"]
+    assert {k for k in stats if k.startswith("search.agg_lane_bins.")} \
+        == {f"search.agg_lane_bins.{k}"
+            for k in ("hit", "miss", "identity", "evicted")}
+
+
+def test_a_hit_builds_no_table(served, monkeypatch):
+    """The rows' rank -> bucket tables are built for the derivation, one
+    a row, and by no request that finds the vector."""
+    from opensearch_tpu.search.aggs import engine
+    node, server, docs = served
+    built = []
+    rank_table = engine._rank_table
+    monkeypatch.setattr(engine, "_rank_table",
+                        lambda b: built.append(len(b)) or rank_table(b))
+    drop_shard_sets()
+    check_response(post(server, moved(BODY, 26)), docs, moved(BODY, 26))
+    assert len(built) == len(node.indices.get("logs").shards)
+    for minutes in (27, 28):
+        check_response(post(server, moved(BODY, minutes)), docs,
+                       moved(BODY, minutes))
+    assert len(built) == len(node.indices.get("logs").shards)
+
+
+def test_another_bucketing_of_the_field_gets_its_own_vector(served):
+    """Keyed by the bucketing's scalars, not by the field alone; and the
+    memo is bounded: the least recently used vector goes, with its bytes."""
+    from opensearch_tpu.parallel.distributed import MAX_LANE_BINS
+    node, server, docs = served
+    drop_shard_sets()
+    check_response(post(server, moved(BODY, 31)), docs, moved(BODY, 31))
+    shard_set = the_shard_set()
+    one = next(iter(shard_set._lane_bins.values())).nbytes
+    before = lane_bins()
+    offsets = [10, 20, 30, 40][:MAX_LANE_BINS]
+    for n, minutes in enumerate(offsets):
+        body = with_hist(moved(BODY, 32 + n), fixed_interval="1h",
+                         offset=f"+{minutes}m")
+        for again in (False, True):
+            check_response(post(server, moved(body, 7 * again)), docs,
+                           moved(body, 7 * again), shift=-minutes * 60000)
+        assert ("@timestamp", "date_histogram", HOUR, -minutes * 60000) \
+            in shard_set._lane_bins
+    # each offset missed once and hit once; the hourly vector without an
+    # offset, the least recently used, made room for the last of them
+    assert delta(lane_bins(), before) == {
+        "miss": len(offsets), "hit": len(offsets), "evicted": 1,
+        "identity": 2 * len(offsets)}
+    assert len(shard_set._lane_bins) == MAX_LANE_BINS
+    assert ("@timestamp", "date_histogram", HOUR, 0) \
+        not in shard_set._lane_bins
+    assert resident_bytes() == shard_set.nbytes + MAX_LANE_BINS * one
+
+
+def test_a_dropped_shard_set_takes_its_vectors_along(served, notes,
+                                                     monkeypatch):
+    """The residency cache's eviction releases the set's image and its
+    vectors from the device-memory ledger in one; the set that replaces
+    it (a refresh) starts with none."""
+    node, server, docs = served
+    drop_shard_sets()
+    post(server, moved(BODY, 41))
+    old = the_shard_set()
+    assert len(old._lane_bins) == 1 and resident_bytes() > old.nbytes
+    monkeypatch.setattr(spmd, "_MAX_SHARD_SETS", 1)
+    before = lane_bins()
+    notes.request("POST", "/notes/_search",
+                  {"query": {"match": {"body": "alpha"}}})
+    new = the_shard_set()
+    assert new is not old and not old._lane_bins
+    assert resident_bytes() == new.nbytes
+    assert delta(lane_bins(), before) == {"evicted": 1}
+    post(server, moved(BODY, 42))       # evicts `new`, builds the set again
+    assert delta(lane_bins(), before) == {"evicted": 1, "miss": 1,
+                                          "identity": 1}
+    assert resident_bytes() == the_shard_set().nbytes \
+        + sum(v.nbytes for v in the_shard_set()._lane_bins.values())
+
+
+DAY = 86400000
+# a row whose every day holds one distinct timestamp (its daily table is
+# the identity) beside a row with two in one day (its table is not): by
+# which of them holds more unique values than the other's table has
+# entries (`u_pad` 8)
+MIXED_ROWS = {
+    "the-identity-row-is-the-narrower": [
+        [T0, T0 + DAY, T0 + 2 * DAY],
+        [T0 + 5, T0 + 7, T0 + 2 * DAY + 1, T0 + 3 * DAY]],
+    "the-identity-row-is-the-wider": [
+        [T0 + d * DAY for d in range(24)],
+        [T0 + 3 * DAY + 5, T0 + 3 * DAY + 7, T0 + 17 * DAY]],
+}
+BY_DAY = {"size": 0, "aggs": {"by_day": {
+    "date_histogram": {"field": "@timestamp", "fixed_interval": "1d"},
+    "aggs": {"bytes": {"sum": {"field": "size"}}}}}}
+
+
+def mixed_node(name, stamps):
+    node = start_node({"http.port": 0, "node.name": name})[0]
+    node.request("PUT", "/days", {"settings": {"number_of_shards": 2},
+                                  "mappings": MAPPING})
+    svc = node.indices.get("days")
+    for s, shard in enumerate(svc.shards):
+        b = SegmentBuilder(svc.mapper, "s0")
+        for i, t in enumerate(stamps[s]):
+            b.add(svc.mapper.parse_document(
+                f"s{s}-{i}", {"@timestamp": t, "status": 200 + s,
+                              "size": 10 * (i + 1)}))
+        seg = b.seal()
+        shard.engine.install_segments([seg], max_seq_no=seg.num_docs,
+                                      local_checkpoint=seg.num_docs)
+        shard._sync_reader()
+    return node, svc
+
+
+@pytest.mark.parametrize("stamps", list(MIXED_ROWS.values()),
+                         ids=list(MIXED_ROWS))
+def test_rows_that_differ_in_whether_their_table_is_the_identity(stamps):
+    """The identity row takes its own table back, an entry a rank of its
+    own column however narrow the other row's table, so the rows keep
+    one structure, the request is the SPMD program's, and every day of
+    both rows is counted as the host loop counts it."""
+    node, svc = mixed_node("spmd-mixed", stamps)
+    before, lanes = counters(), lane_bins()
+    got = node.request("POST", "/days/_search", BY_DAY)
+    after = counters()
+    assert after["search.spmd_queries"] == before["search.spmd_queries"] + 1
+    assert after["search.spmd_fallbacks"] == before["search.spmd_fallbacks"]
+    assert delta(lane_bins(), lanes) == {"miss": 1}
+    days = {}
+    for row in stamps:
+        for i, t in enumerate(row):
+            c, b = days.get(t // DAY * DAY, (0, 0.0))
+            days[t // DAY * DAY] = (c + 1, b + 10.0 * (i + 1))
+    assert {b["key"]: (b["doc_count"], b["bytes"]["value"])
+            for b in got["aggregations"]["by_day"]["buckets"]
+            if b["doc_count"]} == days
+    with spmd.force_host_loop():
+        want = node.request("POST", "/days/_search", BY_DAY)
+    assert got["aggregations"] == want["aggregations"]
+    assert delta(lane_bins(), lanes) == {"miss": 1}
+
+
+@pytest.mark.parametrize("stamps", list(MIXED_ROWS.values()),
+                         ids=list(MIXED_ROWS))
+def test_aligned_rows_compiled_for_one_chip_carry_their_own_tables(stamps):
+    """`align_agg_plans` on rows that carry their tables among their
+    inputs (a compile for one chip): the identity row's is the identity
+    over its own ranks, padded like any other."""
+    from opensearch_tpu.parallel.distributed import align_agg_plans
+    from opensearch_tpu.search.aggs.engine import BINS_TABLE, compile_aggs
+    from opensearch_tpu.search.aggs.parse import parse_aggs
+    from opensearch_tpu.search.compile import Compiler
+    node, svc = mixed_node("spmd-mixed-eager", stamps)
+    rows = []
+    for shard in svc.shards:
+        reader = shard.reader
+        (seg,), ((_, meta),) = reader.segments, reader.device
+        rows.append(compile_aggs(
+            parse_aggs(BY_DAY["aggs"]), svc.mapper, seg, meta,
+            Compiler(svc.mapper, reader.stats())))
+    assert "table" not in rows[0][0].inputs
+    align_agg_plans(rows)
+    for (plan,), row in zip(rows, stamps):
+        assert plan.static[3] == BINS_TABLE
+        table = plan.inputs["table"]
+        assert table.shape == (max(8, 1 << (len(set(row)) - 1).bit_length()),)
+    identity = rows[0][0].inputs["table"]
+    n = len(stamps[0])
+    assert (identity[:n] == np.arange(n)).all() and (identity[n:] == -1).all()
+
+
+def test_a_range_bucket_keeps_its_table_and_the_memo_its_vectors(served):
+    """A `range`/`date_range` bucket's bounds may move with every request
+    (`now`-relative ones do), so it brings its table as it did and is no
+    entry of the shard set's memo: no vector derived, none dropped,
+    whatever the bounds, and the answer is the host loop's."""
+    node, server, docs = served
+    drop_shard_sets()
+    post(server, moved(BODY, 61))
+    shard_set = the_shard_set()
+    keys, before = list(shard_set._lane_bins), lane_bins()
+    for n in range(6):
+        body = {"size": 0, "aggs": {"spans": {
+            "date_range": {"field": "@timestamp", "ranges": [
+                {"from": T0 + (n + i) * HOUR + 1,
+                 "to": T0 + (n + i + 9) * HOUR} for i in range(5)]},
+            "aggs": {"bytes": {"sum": {"field": "size"}}}}}}
+        got = post(server, body)
+        for b in got["aggregations"]["spans"]["buckets"]:
+            sel = [d for d in docs if b["from"] <= d["@timestamp"] < b["to"]]
+            assert (b["doc_count"], b["bytes"]["value"]) \
+                == (len(sel), float(sum(d["size"] for d in sel)))
+        with spmd.force_host_loop():
+            want = node.request("POST", "/logs/_search", body)
+        assert got["aggregations"] == want["aggregations"]
+    assert the_shard_set() is shard_set
+    assert list(shard_set._lane_bins) == keys
+    assert delta(lane_bins(), before) == {}
+
+
+def dispatched(node, server, body):
+    """The fingerprint of the one program a request dispatched."""
+    TELEMETRY.tracer.spans.clear()
+    post(server, body)
+    for _ in range(200):
+        ring = node.request("GET", "/_telemetry/spans")["spans"]
+        if any(s["name"] == "http.request" for s in ring):
+            break
+        time.sleep(0.01)
+    (fp,) = [s["attributes"]["fingerprint"] for s in ring
+             if s["name"] == "dispatch"]
+    return fp
+
+
+def lane_gathers(fp, lanes):
+    """The lowered program's `gather`s that produce a value a lane of a
+    row, and its flat request inputs' sizes in bytes."""
+    import re
+    import jax
+    fn, structs = TELEMETRY.kernels._lowerable[fp]
+    text = fn.lower(*structs).as_text()
+    results = re.findall(r'"?stablehlo\.gather"?\(.*-> tensor<([0-9x]+)x\w+>',
+                         text)
+    assert results      # top-k's gathers: the pattern finds gathers
+    wide = [r for r in results if str(lanes) in r.split("x")]
+    sizes = [int(np.prod(s.shape)) * s.dtype.itemsize
+             for s in jax.tree_util.tree_leaves(structs[1])]
+    return wide, sizes
+
+
+def test_the_served_program_gathers_no_lane_and_takes_no_table(
+        served, monkeypatch):
+    """The lowered `jit_spmd_query_phase` of the panel holds no gather
+    with a result a lane (the two 16.8M-lane passes of the benchmark's
+    four-chip cell), and a request's flat inputs are the range's bounds
+    alone; the same panel through the tables (what a request did before)
+    holds both, so the reading can tell them apart."""
+    from opensearch_tpu.parallel import distributed
+    from opensearch_tpu.search.aggs.engine import BINS_TABLE
+    node, server, docs = served
+    drop_shard_sets()
+    fp = dispatched(node, server, moved(BODY, 51))
+    lanes = the_shard_set().seg_stack["numeric"]["@timestamp"][
+        "val_ords"].shape[-1]
+    wide, sizes = lane_gathers(fp, lanes)
+    assert wide == [] and max(sizes) <= 64
+    # the hourly level through its table; `terms(status)` stays the rank
+    def through_tables(searcher, shard_set, per_shard):
+        def walk(plans):
+            for p in plans:
+                if p.table_of is not None and p.static[3] == BINS_TABLE:
+                    p.inputs = dict(p.inputs, table=p.table_of())
+                walk(p.children)
+        for plans in per_shard:
+            walk(plans)
+        return []
+    monkeypatch.setattr(distributed, "resident_lane_bins", through_tables)
+    fp_tables = dispatched(node, server, moved(BODY, 52))
+    assert fp_tables != fp
+    wide, sizes = lane_gathers(fp_tables, lanes)
+    assert len(wide) == 1 and max(sizes) > 64
