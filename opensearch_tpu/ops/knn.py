@@ -5,9 +5,16 @@ DenseVectorFieldMapper) with brute-force painless `script_score`; the k-NN
 plugin (opensearch-project/k-NN, out-of-repo — SURVEY.md §2.3 note) adds
 HNSW/IVF via native faiss/nmslib. Here both are TPU-native:
 
-- **Exact**: one [D, dims] × [dims] matmul on the MXU per (segment, query) —
-  with msearch batching it becomes [D, dims] × [dims, Q]. L2 uses the
-  ||x||² - 2x·q + ||q||² expansion so document norms are precomputed once.
+- **Exact**: one [D, dims] × [dims] product per (segment, query), f32 at
+  `F32_MATMUL`; an `_msearch` batch makes it [D, dims] × [dims, Q]. L2
+  uses the ||x||² - 2x·q + ||q||² expansion (the norms are summed again
+  by every query, a second pass over the column that innerproduct does
+  not make). Served on a v5e at 768-d, k=100, 2,000,000 rows a shard
+  (`d_pad` 2,097,152, 6.44 GB resident; `vectorsearch-knn-closed-8`,
+  PERF.md §5): XLA lowers the one-column product to a VPU
+  multiply-reduce that reads the column once, 8.5 ms a query (758 GB/s),
+  and to no MXU op; the two k=100 selections (the clause's here, the
+  page's after it) take 2.7 ms more.
 - **IVF**: k-means centroids (built at seal time, Lloyd's on device),
   inverted lists as a padded [nlist, max_len] int32 matrix. A query scores
   centroids, takes the top-nprobe lists, gathers their candidates, and

@@ -44,6 +44,14 @@ _MEMO_ROTATIONS = TELEMETRY.metrics.counter("search.memo_rotations")
 # vector or scatters a term count as well (ops/bm25.py score_text_clause)
 _TEXT_SCORE_ONLY = TELEMETRY.metrics.counter("search.text_clause.score_only")
 _TEXT_COUNTED = TELEMETRY.metrics.counter("search.text_clause.counted")
+# k-NN clauses planned, by the method they take (an unfiltered exact
+# scan, an IVF probe, an exact scan under a `filter`), and the vector
+# bytes the exact scans read: the whole padded column a clause (an IVF
+# probe reads its blocks of the packed copy, and adds nothing here)
+_KNN_CLAUSES = {m: TELEMETRY.metrics.counter(f"search.knn_clause.{m}")
+                for m in ("exact", "ivf", "filtered")}
+_KNN_SCANNED_BYTES = TELEMETRY.metrics.counter(
+    "search.knn_clause.scanned_bytes")
 
 
 def _counted_text_plan(plan: "Plan") -> "Plan":
@@ -1077,9 +1085,13 @@ class Compiler:
         children = []
         if node.filter is not None:
             children.append(self.compile(node.filter, seg, meta))
+        method = "ivf" if use_ivf else "exact"
+        _KNN_CLAUSES["filtered" if children else method].inc()
+        if not use_ivf:
+            _KNN_SCANNED_BYTES.inc(meta.d_pad * ft.dims * 4)
         return Plan("knn",
                     static=(node.field, int(node.k), ft.similarity_space,
-                            "ivf" if use_ivf else "exact", int(nprobe)),
+                            method, int(nprobe)),
                     inputs={"query": q, "boost": _f32(node.boost)},
                     children=children)
 

@@ -592,26 +592,33 @@ _SEARCH_PHASE_HISTS = {
     for name in ("parse", "query", "render")}
 
 
-def _plan_family(plan: Plan, agg_plans=()) -> str:
+def _vector_leaf(plan: Plan) -> Optional[Plan]:
+    """The first `knn` or `maxsim` leaf of a compiled plan tree, in
+    pre-order, or None: it names the program's family and, for k-NN,
+    its shape bucket."""
+    if plan.kind in ("knn", "maxsim"):
+        return plan
+    for c in plan.children:
+        leaf = _vector_leaf(c)
+        if leaf is not None:
+            return leaf
+    return None
+
+
+def _plan_family(plan: Plan, agg_plans=(), leaf=None) -> str:
     """Kernel-family label for one compiled plan tree (the census'
     vocabulary, telemetry/kernels.py): vector leaves win (their
     kernels dominate the program), then the agg envelope, then the
-    dense BM25 kernel build_query_phase lowers to."""
-    def walk(p):
-        if p.kind == "knn":
-            return "knn"
-        if p.kind == "maxsim":
-            comp = p.static[2] if len(p.static) > 2 else None
-            return "maxsim_adc" if comp == "pq" else "maxsim"
-        for c in p.children:
-            f = walk(c)
-            if f is not None:
-                return f
-        return None
-    fam = walk(plan)
-    if fam is not None:
-        return fam
-    return "agg_env" if agg_plans else "bm25_dense"
+    dense BM25 kernel build_query_phase lowers to. `leaf` is the tree's
+    `_vector_leaf` where the caller already has it."""
+    if leaf is None:
+        leaf = _vector_leaf(plan)
+    if leaf is None:
+        return "agg_env" if agg_plans else "bm25_dense"
+    if leaf.kind == "knn":
+        return "knn"
+    comp = leaf.static[2] if len(leaf.static) > 2 else None
+    return "maxsim_adc" if comp == "pq" else "maxsim"
 
 
 def _layout_batch(layout) -> int:
@@ -623,10 +630,16 @@ def _layout_batch(layout) -> int:
     return 0
 
 
-def _env_shape(layout, k: int, meta) -> str:
+def _env_shape(layout, k: int, meta, knn: Optional[Plan] = None) -> str:
     """Shape-bucket string for an envelope executable: padded batch,
     top-k and the segment's padded doc axis — the axes the compile key
-    buckets on."""
+    buckets on. A `knn` family program names what its scan reads and
+    selects instead, `d<d_pad>x<dims>k<k>` of its k-NN clause `knn`:
+    the page's k says nothing of the clause's, and the vector width is
+    the program's cost."""
+    if knn is not None:
+        return (f"b{_layout_batch(layout)}/d{meta.d_pad}"
+                f"x{knn.inputs['query'].shape[-1]}k{knn.static[1]}")
     return f"b{_layout_batch(layout)}/k{k}/d{meta.d_pad}"
 
 
@@ -1753,7 +1766,8 @@ def _envelope_runner(plan_sig, plan: Plan, meta: DeviceSegmentMeta, k: int,
         cand = _candidate_kernel_fits(plan.kind, n_terms, qb128)
         # the family names the XLA module (jit_<family>) and the census
         # record alike
-        fam = "bm25_candidate" if cand else _plan_family(plan)
+        leaf = None if cand else _vector_leaf(plan)
+        fam = "bm25_candidate" if cand else _plan_family(plan, leaf=leaf)
         if cand:
             # blockmax admission is a pure function of facts already in
             # the JIT key: the plan's input tree (treedef gains tid/
@@ -1768,7 +1782,9 @@ def _envelope_runner(plan_sig, plan: Plan, meta: DeviceSegmentMeta, k: int,
                             fam)
         _JIT_CACHE[key] = fn  # shared-state-ok: benign double-jit race; dict slot write is GIL-atomic
         return _timed_first_call(
-            fn, family=fam, shape=_env_shape(layout, k, meta), key=key,
+            fn, family=fam, key=key,
+            shape=_env_shape(layout, k, meta,
+                             leaf if fam == "knn" else None),
             cost=_plan_cost(plan, meta, _layout_batch(layout)))
     return fn
 

@@ -178,6 +178,27 @@ def ref_knn_l2_score(doc_vec: Sequence[float],
     return 1.0 / (1.0 + d2)
 
 
+def ref_knn_score(doc_vec: Sequence[float], query_vec: Sequence[float],
+                  space: str) -> float:
+    """k-NN plugin score of one document in `space`, float64, by the
+    plugin's rules: l2 1 / (1 + squared distance); cosinesimil
+    (1 + cos) / 2; innerproduct ip + 1 where ip >= 0, else
+    1 / (1 - ip)."""
+    x = [float(a) for a in doc_vec]
+    q = [float(b) for b in query_vec]
+    if space == "l2":
+        return ref_knn_l2_score(x, q)
+    ip = math.fsum(a * b for a, b in zip(x, q))
+    if space == "cosinesimil":
+        norms = math.sqrt(math.fsum(a * a for a in x)
+                          * math.fsum(b * b for b in q))
+        cos = ip / norms if norms > 0 else 0.0
+        return (1.0 + max(-1.0, min(1.0, cos))) / 2.0
+    if space == "innerproduct":
+        return ip + 1.0 if ip >= 0 else 1.0 / (1.0 - ip)
+    raise ValueError(f"unknown knn space [{space}]")
+
+
 def ref_maxsim_scores(segment_docs: Sequence[Sequence[Optional[Sequence[Sequence[float]]]]],
                       query_vectors: Sequence[Sequence[float]],
                       k: int) -> List[Dict[Tuple[int, int], float]]:
