@@ -13,8 +13,10 @@ HNSW/IVF via native faiss/nmslib. Here both are TPU-native:
   (`d_pad` 2,097,152, 6.44 GB resident; `vectorsearch-knn-closed-8`,
   PERF.md §5): XLA lowers the one-column product to a VPU
   multiply-reduce that reads the column once, 8.5 ms a query (758 GB/s),
-  and to no MXU op; the two k=100 selections (the clause's here, the
-  page's after it) take 2.7 ms more.
+  and to no MXU op; the clause's k=100 selection (`knn_select`) takes
+  1.3 ms more, and a clause that is the whole query takes its page from
+  those k winners (`knn_page`), not from a second selection over
+  `d_pad` lanes (another 1.4 ms until PR 34).
 - **IVF**: k-means centroids (built at seal time, Lloyd's on device),
   inverted lists as a padded [nlist, max_len] int32 matrix. A query scores
   centroids, takes the top-nprobe lists, gathers their candidates, and
@@ -76,24 +78,57 @@ def exact_knn_scores(vectors: jnp.ndarray, query: jnp.ndarray,
         return space_score(raw_similarity(vectors, query, space), space)
 
 
+def knn_select(scores: jnp.ndarray, eligible: jnp.ndarray, k: int):
+    """The clause's selection: the k best eligible docs of a dense score
+    vector, as (values, doc ordinals, valid), score-desc with ties by
+    lowest doc (`top_k`'s lowest-index rule). Fewer than k eligible docs
+    leave the tail slots `-inf` and not `valid`. The ONE `top_k` over
+    `[d_pad]` lanes a k-NN clause runs, whoever reads its winners."""
+    with stage("top_k"):
+        masked = jnp.where(eligible, scores, -jnp.inf)
+        top_vals, top_idx = jax.lax.top_k(
+            masked, min(int(k), int(scores.shape[0])))
+        return top_vals, top_idx, top_vals > -jnp.inf
+
+
 def knn_match_topk(scores: jnp.ndarray, eligible: jnp.ndarray,
                    k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Restrict a dense score vector to its top-k eligible docs.
 
     Returns (scores, matches): matches true only for the k best eligible
-    docs (score-desc, doc-asc tie-break via top_k's lowest-index rule)."""
+    docs (score-desc, doc-asc tie-break via top_k's lowest-index rule).
+    `knn_select` densified again, for every caller that reads `[d_pad]`
+    vectors: a clause under a `bool`/`hybrid`/`function_score`/`nested`
+    parent, the aggregating and sorting query phases, the hybrid and
+    SPMD programs. A plan whose ROOT is the clause, served score-sorted
+    and aggregation-free (search/executor.py build_batched_query_phase),
+    takes its page from the k winners instead (`knn_page`) and never
+    builds this pair."""
     d = scores.shape[0]
+    top_vals, top_idx, valid = knn_select(scores, eligible, k)
     with stage("top_k"):
-        masked = jnp.where(eligible, scores, -jnp.inf)
-        k_eff = min(int(k), int(d))
-        top_vals, top_idx = jax.lax.top_k(masked, k_eff)
-        valid = top_vals > -jnp.inf
         # invalid slots scatter out of bounds and are dropped — routing
         # them to index 0 would clobber a real winner at doc ord 0
         matches = jnp.zeros(d, jnp.bool_).at[
             jnp.where(valid, top_idx, d)].set(True, mode="drop")
         matches = matches & eligible
         return jnp.where(matches, scores, 0.0), matches
+
+
+def knn_page(scores: jnp.ndarray, idx: jnp.ndarray,
+             returnable: jnp.ndarray, size: int):
+    """The `size`-slot page of a clause's k winners: the returnable ones
+    by score descending, ties by lowest doc ordinal (two raw scores can
+    meet once boosted, and the clause's own order is by raw score), the
+    rest `-inf`. What a `top_k` over the densified `[d_pad]` vector
+    would select, from a sort of k pairs."""
+    with stage("top_k"):
+        neg, ords = jax.lax.sort(
+            (jnp.where(returnable, -scores, jnp.inf), idx), num_keys=2)
+        n = min(int(size), int(scores.shape[0]))
+        pad = int(size) - n
+        return (jnp.pad(-neg[:n], (0, pad), constant_values=-jnp.inf),
+                jnp.pad(ords[:n], (0, pad)))
 
 
 # ------------------------------------------------------------------- IVF ----

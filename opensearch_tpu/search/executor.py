@@ -48,13 +48,14 @@ from opensearch_tpu.ops.bm25 import (
 from opensearch_tpu.ops import device_segment as _devseg
 from opensearch_tpu.ops.device_segment import (
     DeviceSegmentMeta, refresh_live, tree_nbytes, upload_segment)
+from opensearch_tpu.ops.knn import knn_page
 from opensearch_tpu.ops.topk import (NEG_INF, f32_sortable, single_valued,
                                      value_merge_key)
 from opensearch_tpu.search import dsl
 from opensearch_tpu.search.compile import (Compiler, Plan, ShardStats,
                                            _PartialBundle, carry_memo,
                                            struct_fingerprint)
-from opensearch_tpu.search.plan_eval import _eval_plan
+from opensearch_tpu.search.plan_eval import _eval_plan, eval_knn_winners
 from opensearch_tpu.search.aggs.engine import compile_aggs, eval_aggs
 from opensearch_tpu.search.aggs.parse import parse_aggs
 from opensearch_tpu.search.aggs.reduce import decode_outputs, reduce_aggs
@@ -590,6 +591,11 @@ _SEARCH_TOOK = TELEMETRY.metrics.histogram("search.took_ms")
 _SEARCH_PHASE_HISTS = {
     name: TELEMETRY.metrics.histogram(f"search.phase.{name}_ms")
     for name in ("parse", "query", "render")}
+# query items dispatched through an envelope program that takes its page
+# from a root k-NN clause's own k winners (`_page_from_clause`); beside
+# search.knn_clause.exact / .ivf / .filtered (search/compile.py)
+_KNN_PAGE_FROM_CLAUSE = TELEMETRY.metrics.counter(
+    "search.knn_clause.page_from_clause")
 
 
 def _vector_leaf(plan: Plan) -> Optional[Plan]:
@@ -1569,6 +1575,22 @@ def _blockmax_admitted(plan, k: int) -> bool:
             and _envelope_kernel(plan) == "candidate")
 
 
+def _page_from_clause(plan: Plan) -> bool:
+    """Whether build_batched_query_phase takes the page from a k-NN
+    clause's own k winners: the clause is the whole query. Read off the
+    plan's root alone, which the JIT key's plan signature holds."""
+    return plan.kind == "knn"
+
+
+def _winners_total(valid, idx, seg, num_docs: int, scores, min_score):
+    """`_eligible_total` asked of a clause's k winners (each already
+    live): the ones a query may return, and how many they are."""
+    with _stage("eligible_total"):
+        returnable = valid & seg["root"][idx] & (idx < num_docs) \
+            & (scores >= min_score)
+        return returnable, jnp.sum(returnable.astype(jnp.int32))
+
+
 def build_batched_query_phase(plan: Plan, meta: DeviceSegmentMeta, k: int,
                               layout, treedef):
     """B same-shaped queries against one segment as ONE device program.
@@ -1577,14 +1599,28 @@ def build_batched_query_phase(plan: Plan, meta: DeviceSegmentMeta, k: int,
     queries one at a time per shard (SearchService.executeQueryPhase), here a
     whole _msearch batch vmaps over a leading query axis — gathers, BM25 and
     top-k all batch cleanly, so one host↔device round trip serves B queries.
-    Score-sorted, agg-free queries only (the common high-QPS shape)."""
+    Score-sorted, agg-free queries only (the common high-QPS shape).
+
+    A plan whose root is a `knn` clause (`_page_from_clause`; filtered or
+    not, exact or IVF) selects once: the page and the total come from the
+    clause's k winners (`eval_knn_winners`, `knn_page`), no `[d_pad]`
+    scores/matches/eligible vector is built and no second `top_k` runs
+    over `d_pad` lanes. Every other plan, a `knn` clause under a
+    `bool`/`boosting`/`function_score`/`nested` parent among them, is
+    evaluated densely and selected by `_topk_or_empty`."""
 
     def one(seg, flat_inputs, min_score):
+        k_eff = min(k, seg["live"].shape[0])
+        if _page_from_clause(plan):
+            scores, idx, valid = eval_knn_winners(plan, seg, flat_inputs)
+            returnable, total = _winners_total(
+                valid, idx, seg, meta.num_docs, scores, min_score)
+            top_scores, top_idx = knn_page(scores, idx, returnable, k_eff)
+            return _pack_row(top_scores, top_idx, total)
         cursor = [0]
         scores, matches = _eval_plan(plan, seg, flat_inputs, cursor)
         eligible, total = _eligible_total(matches, seg, meta.num_docs,
                                           scores, min_score)
-        k_eff = min(k, seg["live"].shape[0])
         top_scores, top_idx = _topk_or_empty(eligible, scores, k_eff)
         return _pack_row(top_scores, top_idx, total)
 
@@ -4063,6 +4099,8 @@ class SearchExecutor:
             min_scores = np.asarray(
                 [entry_by_i[i][5] for i in idxs]
                 + [np.inf] * pad_rows, dtype=np.float32)
+            from_clause = False     # a program of this group pages from
+            # its k-NN clause's winners (counted once an item, below)
             for seg_i, (seg, (arrays, meta)) in enumerate(
                     zip(segments, device)):
                 if seg.num_docs == 0:
@@ -4146,6 +4184,9 @@ class SearchExecutor:
                 pending.append((idxs, seg_i, k_seg, out, out_layout,
                                 agg_sig is None
                                 and _blockmax_admitted(plan0, k_seg)))
+                from_clause |= agg_sig is None and _page_from_clause(plan0)
+            if from_clause:
+                _KNN_PAGE_FROM_CLAUSE.inc(len(idxs))
         _t_end = time.monotonic()
         ph["stack_pack_dispatch"] += _t_end - _t_pack
         if span is not None:

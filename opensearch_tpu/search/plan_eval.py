@@ -15,6 +15,8 @@ from opensearch_tpu.common.errors import QueryShardError
 from opensearch_tpu.ops.topk import NEG_INF
 from opensearch_tpu.ops.bm25 import (
     ordinal_terms_match, range_match_on_ranks, score_text_clause)
+from opensearch_tpu.ops.knn import (
+    exact_knn_scores, ivf_knn_scores, knn_match_topk, knn_select)
 from opensearch_tpu.search.compile import Plan
 
 def _identity(score_mode: str) -> float:
@@ -69,6 +71,38 @@ def dense_numeric(seg: Dict, field: str, d_pad: int, missing: float = 0.0):
     counts = jnp.zeros(d_pad + 1, jnp.int32) \
         .at[idx].add(valid.astype(jnp.int32))[:d_pad]
     return value, col["exists"], counts
+
+
+def _knn_scores(plan: Plan, seg: Dict, inputs: List[Dict],
+                cursor: List[int], my: Dict):
+    """A `knn` clause's dense scores in its space and the docs its
+    selection may pick: present, live, passing the clause's `filter`
+    child and, on an IVF field, among the probed candidates."""
+    field, _k, space, method, nprobe = plan.static
+    col = seg["vector"][field]
+    eligible = col["exists"] & seg["live"]
+    if plan.children:
+        _, fmatches = _eval_plan(plan.children[0], seg, inputs, cursor)
+        eligible = eligible & fmatches
+    if method == "ivf":
+        scores, cand = ivf_knn_scores(
+            col["ivf_packed_vecs"], col["ivf_packed_ids"],
+            col["ivf_centroids"], col["ivf_block_centroid"],
+            seg["live"].shape[0], my["query"], space, nprobe)
+        return scores, eligible & cand
+    return exact_knn_scores(col["vectors"], my["query"], space), eligible
+
+
+def eval_knn_winners(plan: Plan, seg: Dict, inputs: List[Dict]):
+    """A plan whose root is a `knn` clause, as the clause's k winners
+    alone: (boosted scores, doc ordinals, valid), in the clause's order
+    (raw score descending, ties by lowest doc). No `[d_pad]` pair is
+    built: the caller takes its page from these k (ops/knn.py
+    `knn_page`)."""
+    my = inputs[0]          # the root's own inputs; its filter's follow
+    scores, eligible = _knn_scores(plan, seg, inputs, [1], my)
+    top_vals, top_idx, valid = knn_select(scores, eligible, plan.static[1])
+    return top_vals * my["boost"], top_idx, valid
 
 
 def _eval_plan(plan: Plan, seg: Dict, inputs: List[Dict], cursor: List[int]):
@@ -164,23 +198,8 @@ def _eval_plan(plan: Plan, seg: Dict, inputs: List[Dict], cursor: List[int]):
         return jnp.where(matches, my["boost"], 0.0), matches
 
     if kind == "knn":
-        from opensearch_tpu.ops.knn import (
-            exact_knn_scores, ivf_knn_scores, knn_match_topk)
-        field, k, space, method, nprobe = plan.static
-        col = seg["vector"][field]
-        eligible = col["exists"] & seg["live"]
-        if plan.children:
-            _, fmatches = _eval_plan(plan.children[0], seg, inputs, cursor)
-            eligible = eligible & fmatches
-        if method == "ivf":
-            scores, cand = ivf_knn_scores(
-                col["ivf_packed_vecs"], col["ivf_packed_ids"],
-                col["ivf_centroids"], col["ivf_block_centroid"], d_pad,
-                my["query"], space, nprobe)
-            eligible = eligible & cand
-        else:
-            scores = exact_knn_scores(col["vectors"], my["query"], space)
-        scores, matches = knn_match_topk(scores, eligible, k)
+        scores, eligible = _knn_scores(plan, seg, inputs, cursor, my)
+        scores, matches = knn_match_topk(scores, eligible, plan.static[1])
         return scores * my["boost"], matches
 
     if kind == "maxsim":
