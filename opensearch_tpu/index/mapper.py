@@ -70,11 +70,71 @@ _INT_BOUNDS = {
 }
 
 
-def parse_date_millis(value: Any, fmt: Optional[str] = None) -> int:
+# Java date-pattern letters a custom `format` may hold, with what
+# `strptime` reads them as and the unit (ms) they resolve; month and year
+# have no fixed length and round up by the calendar
+_JAVA_DATE_TOKENS = {"yyyy": ("%Y", "y"), "uuuu": ("%Y", "y"),
+                     "MM": ("%m", "M"), "dd": ("%d", 86_400_000),
+                     "HH": ("%H", 3_600_000), "mm": ("%M", 60_000),
+                     "ss": ("%S", 1000), "SSS": ("%f", 1)}
+_JAVA_DATE_RUN = re.compile(r"'([^']*)'|([A-Za-z])\2*|.", re.S)
+_ISO_PREFIX_UNIT = (
+    (re.compile(r"\d{4}"), "y"), (re.compile(r"\d{4}-\d{2}"), "M"),
+    (re.compile(r"\d{4}-\d{2}-\d{2}"), 86_400_000),
+    (re.compile(r"\d{4}-\d{2}-\d{2}[T ]\d{2}"), 3_600_000),
+    (re.compile(r"\d{4}-\d{2}-\d{2}[T ]\d{2}:\d{2}"), 60_000),
+    (re.compile(r"\d{4}-\d{2}-\d{2}[T ]\d{2}:\d{2}:\d{2}"), 1000))
+
+
+@functools.lru_cache(maxsize=256)
+def _java_date_pattern(pattern: str):
+    """(strptime format, finest unit) of a custom Java date pattern
+    (`dd/MM/yyyy`, `yyyy-MM-dd HH:mm:ss`), or None for a pattern with a
+    letter run this table does not hold: a built-in format's name
+    (`strict_date_optional_time`, `epoch_millis`) among them, which the
+    ISO path below reads."""
+    out, units = [], []
+    for m in _JAVA_DATE_RUN.finditer(pattern):
+        if m.group(1) is not None:
+            out.append(m.group(1).replace("%", "%%"))
+        elif m.group(2) is not None:
+            tok = _JAVA_DATE_TOKENS.get(m.group(0))
+            if tok is None:
+                return None
+            out.append(tok[0])
+            units.append(tok[1])
+        else:
+            out.append(m.group(0).replace("%", "%%"))
+    if not units:
+        return None
+    # the finest unit the pattern names, wherever it stands (dd/MM/yyyy)
+    approx_ms = {"y": 4e10, "M": 3e9}
+    return "".join(out), min(units, key=lambda u: approx_ms.get(u, u))
+
+
+def _end_of_unit(dt: "_dt.datetime", unit) -> int:
+    """Epoch ms of the last millisecond of the `unit` that `dt` opens."""
+    if unit == "y":
+        nxt = dt.replace(year=dt.year + 1)
+    elif unit == "M":
+        nxt = dt.replace(year=dt.year + dt.month // 12,
+                         month=dt.month % 12 + 1)
+    else:
+        return int(dt.timestamp() * 1000) + int(unit) - 1
+    return int(nxt.timestamp() * 1000) - 1
+
+
+def parse_date_millis(value: Any, fmt: Optional[str] = None,
+                      round_up: bool = False) -> int:
     """Parse a date into epoch milliseconds.
 
     Covers the reference's default `strict_date_optional_time||epoch_millis`
-    (index/mapper/DateFieldMapper.java DEFAULT_DATE_TIME_FORMATTER).
+    (index/mapper/DateFieldMapper.java DEFAULT_DATE_TIME_FORMATTER) and
+    custom Java patterns of year, month, day, hour, minute, second and
+    millisecond (`fmt` may join alternatives with `||`). `round_up`: the
+    parts the text leaves out are filled with their last value, not their
+    first (a range's `lte` and `gt` bounds: `21/01/2015` is the end of
+    that day; DateMathParser's roundUpProperty).
     """
     if isinstance(value, bool):
         raise MapperParsingError(f"failed to parse date field [{value}]")
@@ -82,6 +142,17 @@ def parse_date_millis(value: Any, fmt: Optional[str] = None) -> int:
         n = int(value)
         return n * 1000 if fmt == "epoch_second" else n
     text = str(value).strip()
+    for alt in (fmt or "").split("||"):
+        pat = _java_date_pattern(alt) if alt else None
+        if pat is None:
+            continue
+        try:
+            dt = _dt.datetime.strptime(text, pat[0]).replace(
+                tzinfo=_dt.timezone.utc)
+        except ValueError:
+            continue
+        return _end_of_unit(dt, pat[1]) if round_up \
+            else int(dt.timestamp() * 1000)
     if fmt in ("epoch_millis", "epoch_second") or re.fullmatch(r"-?\d{10,}", text):
         try:
             n = int(text)
@@ -98,6 +169,10 @@ def parse_date_millis(value: Any, fmt: Optional[str] = None) -> int:
                 dt = _dt.datetime.strptime(t, pattern)
             if dt.tzinfo is None:
                 dt = dt.replace(tzinfo=_dt.timezone.utc)
+            if round_up:
+                for shape, unit in _ISO_PREFIX_UNIT:
+                    if shape.fullmatch(text):
+                        return _end_of_unit(dt, unit)
             return int(dt.timestamp() * 1000)
         except ValueError:
             continue

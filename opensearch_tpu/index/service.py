@@ -192,8 +192,10 @@ class IndexService:
         ]
         self.creation_date = int(time.time() * 1000)
         window = int(self.settings.get("max_result_window", 10000))
+        from opensearch_tpu.indices.request_cache import enabled_by
         for shard in self.shards:
             shard.executor.max_result_window = window
+            shard.executor.request_cache_enabled = enabled_by(self.settings)
         # ingest-concurrent serving knobs (ISSUE 16), all OFF by
         # default: bounded merge windows ("index.merge.windowed" +
         # "index.merge.window_budget_ms") and segment-keyed memo carry

@@ -24,6 +24,16 @@ from opensearch_tpu.telemetry import TELEMETRY
 # _nodes/stats); module-level handles keep the hot path to one int add
 _CACHE_HITS = TELEMETRY.metrics.counter("request_cache.hits")
 _CACHE_MISSES = TELEMETRY.metrics.counter("request_cache.misses")
+# cacheable bodies that were not looked up because the index
+# (`index.requests.cache.enable: false`) or the request
+# (`?request_cache=false`) said so: once a lookup skipped, as hits and
+# misses count once a lookup made (a shard's on the host loop, an item's
+# on the envelope, a request's on the SPMD route)
+_CACHE_BYPASSED = TELEMETRY.metrics.counter("search.request_cache.bypassed")
+
+# the body key `?request_cache=true|false` travels under from the REST
+# layer to whichever route serves the request (internal, like `_dfs`)
+REQUEST_KEY = "_request_cache"
 
 
 class RequestCache:
@@ -122,6 +132,37 @@ def _has_now_date_math(obj) -> bool:
     if isinstance(obj, (list, tuple)):
         return any(_has_now_date_math(v) for v in obj)
     return False
+
+
+def enabled_by(settings: dict) -> bool:
+    """`index.requests.cache.enable` of an index's (normalised)
+    settings; true where it is not set, as upstream's default."""
+    raw = settings.get("requests.cache.enable")
+    if raw is None:
+        return True
+    from opensearch_tpu.common.settings import _parse_bool
+    return _parse_bool(raw, "index.requests.cache.enable")
+
+
+def admits(body: dict, index_enabled: bool,
+           query_now_safe: bool = False) -> bool:
+    """Whether this request is looked up in (and stored to) the cache:
+    the ONE decision every route asks (the host loop's shard query
+    phase, the msearch envelope, the SPMD route). A body `cacheable`
+    refuses is never cached; one it admits is cached where the request
+    says so (`?request_cache=`, IndicesService.canCache: the request's
+    word beats the index's) and else where the index's
+    `index.requests.cache.enable` does. Unlike upstream an explicit
+    `request_cache=true` does not extend to bodies with hits: the cached
+    value holds totals and aggregation partials, no page."""
+    if not cacheable(body, query_now_safe):
+        return False
+    wanted = body.get(REQUEST_KEY)
+    if wanted is None:
+        wanted = index_enabled
+    if not wanted:
+        _CACHE_BYPASSED.inc()
+    return bool(wanted)
 
 
 def cacheable(body: dict, query_now_safe: bool = False) -> bool:

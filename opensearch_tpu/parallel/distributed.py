@@ -90,7 +90,7 @@ from opensearch_tpu.ops.topk import NEG_INF, value_merge_key
 from opensearch_tpu.search.compile import Plan
 from opensearch_tpu.search.plan_eval import _eval_plan
 from opensearch_tpu.search.aggs.engine import (BINS_RANK, BINS_TABLE,
-                                               eval_aggs)
+                                               eval_aggs, plan_bin_room)
 from opensearch_tpu.telemetry import TELEMETRY
 from opensearch_tpu.telemetry.kernels import (jit_family, stage,
                                               timed_first_call)
@@ -236,13 +236,22 @@ def align_agg_plans(per_shard: Sequence[Sequence[Any]]) -> None:
                 carried = any("table" in p.inputs for p in group)
                 for p in group:
                     if p.static[3] == BINS_RANK:
-                        p.static = p.static[:3] + (BINS_TABLE,)
+                        p.static = p.static[:3] + (BINS_TABLE,) \
+                            + p.static[4:]
                         if carried:
                             p.inputs = dict(p.inputs, table=p.table_of())
             if kind in _CARD_KINDS:
                 card = max(p.static[1] for p in group)
                 for p in group:
                     p.static = (p.static[0], card) + tuple(p.static[2:])
+                # likewise the lanes a bucket can hold (`_bin_room`, the
+                # last static of a level with sub-aggregations): the
+                # widest row's, which bounds every row's
+                rooms = [plan_bin_room(p) for p in group]
+                if any(r is not None for r in rooms):
+                    room = max(r for r in rooms if r is not None)
+                    for p in group:
+                        p.static = p.static[:-1] + (room,)
             elif any(p.static != group[0].static for p in group):
                 raise ValueError(
                     f"agg statics diverge across shards for kind {kind}")
@@ -307,7 +316,8 @@ def resident_lane_bins(searcher: "DistributedSearcher",
                     lambda: searcher.derive_lane_bins(
                         shard_set, field, [p.table_of() for p in group])))
                 for p in group:
-                    p.static = p.static[:3] + (len(slots) - 1,)
+                    p.static = p.static[:3] + (len(slots) - 1,) \
+                        + p.static[4:]
             walk([p.children for p in group])
 
     walk(list(per_shard))

@@ -731,6 +731,12 @@ def register_search_actions(node, c):
                 **({"includes": includes.split(",")} if includes else {}),
                 **({"excludes": excludes.split(",")} if excludes else {})}
         as_int = req.param("rest_total_hits_as_int") == "true"
+        if req.param("request_cache") is not None \
+                and not req.param("scroll"):
+            # the request's word beats index.requests.cache.enable
+            # (indices/request_cache.py `admits`)
+            from opensearch_tpu.indices.request_cache import REQUEST_KEY
+            body[REQUEST_KEY] = req.bool_param("request_cache", True)
         if req.param("scroll"):
             if int(body.get("size", 10)) == 0:
                 raise IllegalArgumentError(
@@ -1349,6 +1355,11 @@ def register_indices_actions(node, c):
                 for shard in svc.shards:
                     shard.executor.max_result_window = \
                         int(updates["max_result_window"])
+            if "requests.cache.enable" in updates:
+                from opensearch_tpu.indices.request_cache import enabled_by
+                for shard in svc.shards:
+                    shard.executor.request_cache_enabled = \
+                        enabled_by(svc.settings)
         return {"acknowledged": True}
 
     def do_refresh(req):

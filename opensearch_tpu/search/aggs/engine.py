@@ -66,6 +66,7 @@ from opensearch_tpu.search import dsl
 from opensearch_tpu.search.aggs.parse import AggNode
 from opensearch_tpu.search.compile import Compiler, Plan, _resolve_date_math
 from opensearch_tpu.search.plan_eval import _eval_plan
+from opensearch_tpu.telemetry import TELEMETRY
 
 MAX_AGG_BINS = 1 << 24  # guard for presence/histogram bitmaps
 POS_INF = np.float32(np.inf)
@@ -95,6 +96,23 @@ AGG_POPCOUNT_MAX_ELEMS = 1 << 30
 AGG_SUM_WAYS = 64
 # ...as long as bins x ways stays under this many accumulators
 AGG_SUM_MAX_ACCUMULATORS = 1 << 22
+# A partial's drift grows with what it adds up, and on real columns it
+# is no random walk: amounts on a lattice (fares in half dollars, prices
+# in cents) round the same way add after add once the partial's ulp
+# passes the lattice's step. 64 ways over a bin of 10^7 such addends
+# read 1e-5 of float64, 256 read 1.3e-6, 1,024 6e-7, 4,096 under 1.3e-7
+# (the nyc_taxis cell's 1-2 mile bucket; the CPU backend's order and the
+# chip's agree). So the ways grow with the lanes ONE BIN CAN HOLD, which
+# is segment-static (`_bin_room`): doubled from the above until no
+# partial can be fed more than this many lanes...
+AGG_SUM_LANES_A_PARTIAL = 1 << 12
+# ...while every query of the batch together stays under this many
+# accumulators (4,096 ways x 10,000 bins is 164 MB of float32)
+AGG_SUM_MAX_GROWN = 1 << 26
+# A bin's room is kept as a power of two, and never under what the base
+# ways already cover: rows of one index then agree on it (one SPMD
+# structure) unless a bin of one of them holds over 262,144 lanes
+AGG_BIN_ROOM_MIN = AGG_SUM_WAYS * AGG_SUM_LANES_A_PARTIAL
 
 # Input arrays that are segment/node-static by construction (host-computed
 # lookup tables): their CONTENT is part of the plan signature, so a batched
@@ -230,6 +248,28 @@ def compile_aggs(nodes: List[AggNode], mapper: MapperService, seg: Segment,
     return plans
 
 
+# Bucket levels the one-chip routes planned (host loop: once a segment
+# compiled; agg envelope: once an item and segment program dispatched, a
+# plan-memo hit included), by where the level's bins come from: the rank
+# column itself (BINS_RANK), a rank -> bucket table gathered through a
+# request (BINS_TABLE), lane bitmasks closed over by the executable
+# (`bucket_bits`). The SPMD route counts its levels as
+# `search.agg_lane_bins.*` (parallel/distributed.py).
+_BIN_SOURCES = {src: TELEMETRY.metrics.counter(f"search.agg_bins.level.{src}")
+                for src in ("rank", "table", "bits")}
+
+
+def note_bin_sources(plans: List["AggPlan"], times: int = 1) -> None:
+    for p in plans:
+        if p.kind == "bucket_bits":
+            _BIN_SOURCES["bits"].inc(times)
+        elif p.kind == "bucket_num" and p.static[3] in (BINS_RANK,
+                                                        BINS_TABLE):
+            _BIN_SOURCES[p.static[3]].inc(times)
+        if p.children:
+            note_bin_sources(p.children, times)
+
+
 def _num_col(ctx: _Ctx, field: str):
     return ctx.seg.numeric_dv.get(field)
 
@@ -265,6 +305,35 @@ def _rank_table(bucket_of_rank: np.ndarray) -> np.ndarray:
     return table
 
 
+def _bin_room(ctx: _Ctx, node: AggNode, key: tuple,
+              populations: Callable[[], np.ndarray]) -> Optional[int]:
+    """The lanes ONE bucket of this level can hold, whatever the query:
+    the largest population among its buckets (`populations()`, counted
+    from the sealed column), as a power of two no smaller than
+    AGG_BIN_ROOM_MIN. Segment-static, so found once a (segment, field,
+    bucketing) and in the plan's `static`; what `_sum_ways` sizes the
+    float sums under this level by. None for a level nothing is summed
+    under (no sub-aggregation)."""
+    if not node.children:
+        return None
+    memo = ctx.seg.__dict__.setdefault("_agg_bin_room", {})
+    room = memo.get(key)
+    if room is None:
+        pops = populations()
+        room = memo[key] = pad_bucket(int(pops.max()) if len(pops) else 0,
+                                      minimum=AGG_BIN_ROOM_MIN)
+    return room
+
+
+def plan_bin_room(plan: "AggPlan") -> Optional[int]:
+    """A bucket level's `_bin_room`, where its plan carries one: the
+    last entry of a `bucket_num` (fifth) or `bucket_ord` (fourth)
+    `static`."""
+    n = {"bucket_num": 4, "bucket_ord": 3}.get(plan.kind)
+    return plan.static[n] if n is not None and len(plan.static) > n \
+        else None
+
+
 def _bucket_lookup_plan(node: AggNode, ctx: _Ctx, card: int, render: dict,
                         bucket_of_rank: Callable[[], np.ndarray],
                         bins_key: tuple) -> AggPlan:
@@ -278,9 +347,13 @@ def _bucket_lookup_plan(node: AggNode, ctx: _Ctx, card: int, render: dict,
     col = _num_col(ctx, node.field)
     n = len(col.unique)
     identity = card == n and np.array_equal(bucket_of_rank(), np.arange(n))
+    room = _bin_room(
+        ctx, node, (node.field,) + bins_key,
+        lambda: np.bincount(bucket_of_rank()[col.value_ords],
+                            minlength=card))
     plan = AggPlan(name=node.name, kind="bucket_num",
                    static=(node.field, card, _ident_pairs(col),
-                           BINS_RANK if identity else BINS_TABLE),
+                           BINS_RANK if identity else BINS_TABLE, room),
                    children=[_compile_node(c, ctx) for c in node.children],
                    render=render, bins_key=bins_key,
                    table_of=lambda: _rank_table(bucket_of_rank()))
@@ -423,8 +496,10 @@ def _c_terms(node: AggNode, ctx: _Ctx) -> AggPlan:
     if ocol is not None:
         card = max(len(ocol.dictionary), 1)
         children = [_compile_node(c, ctx) for c in node.children]
+        room = _bin_room(ctx, node, (field, "ord"),
+                         lambda: np.bincount(ocol.ords, minlength=card))
         return AggPlan(node.name, "bucket_ord",
-                       static=(field, card, _ident_pairs(ocol)),
+                       static=(field, card, _ident_pairs(ocol), room),
                        children=children,
                        render={"keys": list(ocol.dictionary), "body": node.body,
                                "kind": "terms"})
@@ -434,9 +509,11 @@ def _c_terms(node: AggNode, ctx: _Ctx) -> AggPlan:
                                                    "kind": "terms", "keys": []})
     # a rank is its own bucket: the table would be the identity
     ft = ctx.mapper.get_field(field)
+    room = _bin_room(ctx, node, (field, "rank"),
+                     lambda: np.bincount(col.value_ords))
     return AggPlan(node.name, "bucket_num",
                    static=(field, max(len(col.unique), 1), _ident_pairs(col),
-                           BINS_RANK),
+                           BINS_RANK, room),
                    children=[_compile_node(c, ctx) for c in node.children],
                    render={"keys": [_render_numeric_key(v, ft)
                                     for v in col.unique],
@@ -1173,7 +1250,7 @@ _COMPILERS = {
 # ---------------------------------------------------------------- device eval
 
 def eval_aggs(plans: List[AggPlan], seg: Dict, inputs: List[Dict],
-              cursor: List[int], mask, outs: List):
+              cursor: List[int], mask, outs: List, batch: int = 1):
     """Trace the collection program. mask: eligible docs [Dp] bool (the
     query's result set). Appends each node's partial arrays dict to
     `outs` in traversal order.
@@ -1192,7 +1269,10 @@ def eval_aggs(plans: List[AggPlan], seg: Dict, inputs: List[Dict],
     # root context sentinels: pbin=None ⇒ every doc is in bucket 0 (no
     # per-doc gather needed), pmask=None ⇒ no accumulated dynamic parent
     # constraint (skips a gather + AND per agg node on the hot path)
-    ctx = (None, None, 1, True)
+    # room: (the lanes one bin of the context can hold, None while that
+    # is all of them; the `batch` queries the caller vmaps this trace
+    # over): static, and all `_scatter_sum` reads of it
+    ctx = (None, None, 1, True, (None, batch))
     for plan in plans:
         _eval_agg(plan, seg, inputs, cursor, mask, ctx, outs)
 
@@ -1204,24 +1284,36 @@ def _pack_bits(ok):
     return (x * w).sum(-1).astype(jnp.uint32)
 
 
-def _sum_ways(total: int) -> int:
-    """How many interleaved partial accumulators a float bin gets: the
-    largest power of two up to AGG_SUM_WAYS that keeps bins x ways under
-    AGG_SUM_MAX_ACCUMULATORS (1: the plain scatter-add)."""
+def _sum_ways(total: int, room: int, batch: int = 1) -> int:
+    """How many interleaved partial accumulators a float bin gets when
+    one bin can hold `room` of the lanes scattered into `total` bins:
+    the largest power of two up to AGG_SUM_WAYS that keeps bins x ways
+    under AGG_SUM_MAX_ACCUMULATORS (1: the plain scatter-add), doubled
+    until no partial can be fed more than AGG_SUM_LANES_A_PARTIAL lanes,
+    for as long as the `batch` queries of the program together stay
+    under AGG_SUM_MAX_GROWN accumulators."""
     ways = AGG_SUM_WAYS
     while ways > 1 and total * ways > AGG_SUM_MAX_ACCUMULATORS:
         ways //= 2
+    while ways * AGG_SUM_LANES_A_PARTIAL < room \
+            and batch * total * ways * 2 <= AGG_SUM_MAX_GROWN:
+        ways *= 2
     return ways
 
 
-def _scatter_sum(safe, total: int, v, dt):
+def _scatter_sum(safe, total: int, v, dt, room=(None, 1)):
     """Σ of `v` into `total` bins by scatter-add; `safe` [n] int32 holds
     the bin of each lane, `total` for a lane that drops. Integer sums
     are exact in any order. A float sum goes through `_sum_ways`
     interleaved partials a bin and a pairwise tree over them (see
     AGG_SUM_WAYS): the same number of lanes scattered, blockwise float32
-    partial sums instead of one sequential accumulation."""
-    ways = _sum_ways(total) if jnp.issubdtype(dt, jnp.floating) else 1
+    partial sums instead of one sequential accumulation. `room`: (the
+    lanes one bin can hold, where the caller knows a bound under all of
+    them; the queries the program batches)."""
+    lanes_a_bin, batch = room
+    ways = _sum_ways(total, min(lanes_a_bin or safe.shape[0],
+                                safe.shape[0]), batch) \
+        if jnp.issubdtype(dt, jnp.floating) else 1
     if ways == 1:
         return jnp.zeros(total, dt).at[safe].add(v.astype(dt), mode="drop")
     lane = jnp.arange(safe.shape[0], dtype=safe.dtype) & (ways - 1)
@@ -1233,7 +1325,8 @@ def _scatter_sum(safe, total: int, v, dt):
     return part[:, 0]
 
 
-def _binned_sums(bin_lanes, total: int, contribs, static_bins: bool):
+def _binned_sums(bin_lanes, total: int, contribs, static_bins: bool,
+                 room=(None, 1)):
     """Per-bin Σ of each (values, out_dtype) contrib. bin_lanes: [n]
     int32; entries outside [0, total) drop. Contribs carry the DYNAMIC
     eligibility (ineligible lanes contribute 0); bin_lanes carries the
@@ -1283,11 +1376,11 @@ def _binned_sums(bin_lanes, total: int, contribs, static_bins: bool):
                          bin_lanes, total)
         for i in rest:
             v, dt = contribs[i]
-            out[i] = _scatter_sum(safe, total, v, dt)
+            out[i] = _scatter_sum(safe, total, v, dt, room)
         return out
     safe = jnp.where((bin_lanes >= 0) & (bin_lanes < total),
                      bin_lanes, total)
-    return [_scatter_sum(safe, total, v, dt) for v, dt in contribs]
+    return [_scatter_sum(safe, total, v, dt, room) for v, dt in contribs]
 
 
 def _pairs_context(seg, col, mask, parent_eff, d_pad):
@@ -1302,7 +1395,7 @@ def _pairs_context(seg, col, mask, parent_eff, d_pad):
 def _ctx_parent_eff(ctx, d_pad):
     """Collapse the factored context back to the dense parent ordinal
     vector ([Dp] int32, -1 = no bucket) for kinds on the scatter path."""
-    pbin, pmask, pcard, _ = ctx
+    pbin, pmask, pcard = ctx[:3]
     if pbin is None and pmask is None:
         return jnp.zeros(d_pad, jnp.int32)
     if pbin is None:
@@ -1346,12 +1439,12 @@ def _eval_agg(plan: AggPlan, seg: Dict, inputs: List[Dict], cursor: List[int],
     cursor[0] += 1
     d_pad = seg["live"].shape[0]
     kind = plan.kind
-    pbin, pmask, parent_card, pstatic = ctx
+    pbin, pmask, parent_card, pstatic, room = ctx
 
     if kind == "empty":
         outs.append({})
         child_ctx = (jnp.full(d_pad, -1, jnp.int32), pmask, parent_card,
-                     True)
+                     True, room)
         for c in plan.children:
             _eval_agg(c, seg, inputs, cursor, mask, child_ctx, outs)
         return
@@ -1429,8 +1522,13 @@ def _eval_agg(plan: AggPlan, seg: Dict, inputs: List[Dict], cursor: List[int],
                 child_bin = jnp.full(d_pad, -1, jnp.int32).at[
                     jnp.where(bin_ok, safe_doc, d_pad)].max(
                     jnp.where(bin_ok, bin_lanes, -1), mode="drop")
+            # a child's bin is a part of one of this level's buckets
+            # and of its parent's: it holds no more lanes than either
+            own = plan_bin_room(plan)
+            child_room = room if own is None \
+                else (min(room[0] or own, own), room[1])
             child_ctx = (child_bin, _and_pmask(pmask, mask), total,
-                         pstatic)
+                         pstatic, child_room)
             for c in plan.children:
                 _eval_agg(c, seg, inputs, cursor, mask, child_ctx, outs)
         return
@@ -1446,7 +1544,7 @@ def _eval_agg(plan: AggPlan, seg: Dict, inputs: List[Dict], cursor: List[int],
                                  [(own_dyn, jnp.int32)], pstatic)
         outs.append({"counts": counts})
         child_ctx = (pbin, _and_pmask(pmask, mask & matches), parent_card,
-                     pstatic)
+                     pstatic, room)
         for c in plan.children:
             _eval_agg(c, seg, inputs, cursor, mask, child_ctx, outs)
         return
@@ -1486,7 +1584,7 @@ def _eval_agg(plan: AggPlan, seg: Dict, inputs: List[Dict], cursor: List[int],
                                  [(own_dyn, jnp.int32)], pstatic)
         outs.append({"counts": counts})
         child_ctx = (miss_bin, _and_pmask(pmask, mask), parent_card,
-                     pstatic)
+                     pstatic, room)
         for c in plan.children:
             _eval_agg(c, seg, inputs, cursor, mask, child_ctx, outs)
         return
@@ -1507,7 +1605,8 @@ def _eval_agg(plan: AggPlan, seg: Dict, inputs: List[Dict], cursor: List[int],
                                  pstatic)
         outs.append({"counts": counts})
         child_bin = jnp.where(bin_ok, bin_lanes, -1)
-        child_ctx = (child_bin, _and_pmask(pmask, mask), total, pstatic)
+        child_ctx = (child_bin, _and_pmask(pmask, mask), total, pstatic,
+                     room)
         for c in plan.children:
             _eval_agg(c, seg, inputs, cursor, mask, child_ctx, outs)
         return
@@ -1530,7 +1629,8 @@ def _eval_agg(plan: AggPlan, seg: Dict, inputs: List[Dict], cursor: List[int],
                           jnp.float32))
         if parts:
             sums = _binned_sums(bin_lanes, parent_card,
-                                [(v, dt) for _, v, dt in parts], pstatic)
+                                [(v, dt) for _, v, dt in parts], pstatic,
+                                room)
             for (nm, _, _), v in zip(parts, sums):
                 out[nm] = v
         eff = jnp.where(okm & (bin_lanes < parent_card), bin_lanes,
@@ -1572,7 +1672,7 @@ def _eval_agg(plan: AggPlan, seg: Dict, inputs: List[Dict], cursor: List[int],
         if gemm_parts:
             sums = _binned_sums(bin_lanes, parent_card,
                                 [(c, dt) for _, c, dt in gemm_parts],
-                                pstatic)
+                                pstatic, room)
             for (name, _, _), s in zip(gemm_parts, sums):
                 out[name] = s
         # min/max have no matmul form — masked scatter reductions
@@ -1603,7 +1703,7 @@ def _eval_agg(plan: AggPlan, seg: Dict, inputs: List[Dict], cursor: List[int],
             if parts:
                 sums_m = _binned_sums(bin_m, parent_card,
                                       [(vv, dt) for _, vv, dt in parts],
-                                      pstatic)
+                                      pstatic, room)
                 for (nm, _, _), vv in zip(parts, sums_m):
                     out[nm] = out[nm] + vv
             eff_m = jnp.where(okm & (bin_m < parent_card), bin_m,
@@ -1688,7 +1788,7 @@ def _eval_agg(plan: AggPlan, seg: Dict, inputs: List[Dict], cursor: List[int],
         sum_wv, sum_w = _binned_sums(
             bin_lanes, parent_card,
             [(jnp.where(ok_dyn, v * w, 0.0), jnp.float32),
-             (jnp.where(ok_dyn, w, 0.0), jnp.float32)], pstatic)
+             (jnp.where(ok_dyn, w, 0.0), jnp.float32)], pstatic, room)
         outs.append({"sum_wv": sum_wv, "sum_w": sum_w})
         return
 
@@ -1711,8 +1811,10 @@ def _eval_agg(plan: AggPlan, seg: Dict, inputs: List[Dict], cursor: List[int],
         counts = jnp.zeros(parent_card, jnp.int32).at[eff].add(
             own.astype(jnp.int32), mode="drop")
         outs.append({"counts": counts})
+        # child rows are another doc space: a parent bin's room says
+        # nothing of how many of them it holds
         child_ctx = (child_eff, jnp.ones(d_pad, jnp.bool_), parent_card,
-                     False)
+                     False, (None, room[1]))
         for c in plan.children:
             _eval_agg(c, seg, inputs, cursor, own, child_ctx, outs)
         return
@@ -1744,7 +1846,7 @@ def _eval_agg(plan: AggPlan, seg: Dict, inputs: List[Dict], cursor: List[int],
             jnp.where(sel, parent_eff, -1), mode="drop")
         own = root_eff >= 0
         child_ctx = (root_eff, jnp.ones(d_pad, jnp.bool_), parent_card,
-                     False)
+                     False, (None, room[1]))
         for c in plan.children:
             _eval_agg(c, seg, inputs, cursor, own, child_ctx, outs)
         return

@@ -294,6 +294,25 @@ def _hist_eb_keys(render: dict, body: dict):
             (None if hi is None else key_of(hi)))
 
 
+def _collected_span(keyed_counts, eb_keys):
+    """(lo, hi): the keys between which a `min_doc_count` 0 histogram
+    renders its buckets, from (key, doc_count) pairs in key order: the
+    first and the last non-empty bucket, widened to `extended_bounds`;
+    None where there is neither."""
+    lo = hi = None
+    for key, n in keyed_counts:
+        if n > 0:
+            lo = key if lo is None else lo
+            hi = key
+    if eb_keys is not None:
+        eb_lo, eb_hi = eb_keys
+        if eb_lo is not None:
+            lo = eb_lo if lo is None else min(lo, eb_lo)
+        if eb_hi is not None:
+            hi = eb_hi if hi is None else max(hi, eb_hi)
+    return None if lo is None else (lo, hi)
+
+
 def _trim_zero_edges(buckets: List[dict], min_doc_count: int,
                      eb_keys) -> List[dict]:
     """Histogram buckets exist between the min and max COLLECTED buckets
@@ -304,18 +323,11 @@ def _trim_zero_edges(buckets: List[dict], min_doc_count: int,
     first and last non-empty bucket only)."""
     if min_doc_count != 0 or not buckets:
         return buckets
-    nz = [i for i, b in enumerate(buckets) if b["doc_count"] > 0]
-    lo = buckets[nz[0]]["key"] if nz else None
-    hi = buckets[nz[-1]]["key"] if nz else None
-    if eb_keys is not None:
-        eb_lo, eb_hi = eb_keys
-        if eb_lo is not None:
-            lo = eb_lo if lo is None else min(lo, eb_lo)
-        if eb_hi is not None:
-            hi = eb_hi if hi is None else max(hi, eb_hi)
-    if lo is None:
+    span = _collected_span(((b["key"], b["doc_count"]) for b in buckets),
+                           eb_keys)
+    if span is None:
         return []
-    return [b for b in buckets if lo <= b["key"] <= hi]
+    return [b for b in buckets if span[0] <= b["key"] <= span[1]]
 
 
 def _merge_histogram(entries: List[Tuple[Decoded, int]]) -> Dict[str, Any]:
@@ -397,6 +409,16 @@ def _merge_histogram(entries: List[Tuple[Decoded, int]]) -> Dict[str, Any]:
                     k = base_key + q * step
                 all_keys = sorted(acc.keys())
 
+    if min_doc_count == 0:
+        # cut to the collected span BEFORE the sub-aggregations are
+        # merged: the key table spans the column's whole range (a
+        # distance column with a tail: 10,000 buckets), the page the
+        # query's own (50), and a child merge a bucket is the cost
+        span = _collected_span(((k, acc[k]["doc_count"])
+                                for k in all_keys), eb_keys)
+        if span is None:
+            return {"buckets": []}
+        all_keys = [k for k in all_keys if span[0] <= k <= span[1]]
     first = entries[0][0]
     buckets = []
     for key in all_keys:
@@ -415,7 +437,7 @@ def _merge_histogram(entries: List[Tuple[Decoded, int]]) -> Dict[str, Any]:
             else:
                 bucket[child.plan.name] = _render_empty(child.plan.render)
         buckets.append(bucket)
-    return {"buckets": _trim_zero_edges(buckets, min_doc_count, eb_keys)}
+    return {"buckets": buckets}
 
 
 def _merge_ranges(entries: List[Tuple[Decoded, int]]) -> Dict[str, Any]:

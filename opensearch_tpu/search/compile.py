@@ -23,7 +23,8 @@ import numpy as np
 
 from opensearch_tpu.common.errors import (
     IllegalArgumentError, ParsingError, QueryShardError)
-from opensearch_tpu.index.mapper import MapperService, MappedFieldType
+from opensearch_tpu.index.mapper import (MapperService, MappedFieldType,
+                                        parse_date_millis)
 from opensearch_tpu.index.segment import (LENGTH_TABLE, SEAL_B, SEAL_K1,
                                           Segment, pad_bucket)
 from opensearch_tpu.ops import bm25 as _bm25
@@ -1038,6 +1039,12 @@ class Compiler:
                 return float(value)
             if ft.is_date and isinstance(value, str) and ("now" in value or "||" in value):
                 value = _resolve_date_math(value, round_up=round_up)
+            if ft.is_date and isinstance(value, str):
+                # the range's own `format` beats the field's; an `lte`
+                # or `gt` bound that leaves the finer parts out is the
+                # END of what it names (`21/01/2015`: that whole day)
+                return float(parse_date_millis(
+                    value, node.fmt or ft.fmt, round_up=round_up))
             return ft.to_comparable(value)
 
         lo_rank = 0

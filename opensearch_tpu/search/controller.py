@@ -165,6 +165,7 @@ SEARCH_BODY_KEYS = frozenset({
     "min_compatible_shard_node", "knn", "stats",
     "allow_partial_search_results",
     "_dfs",                       # internal: DFS-merged statistics
+    "_request_cache",             # internal: ?request_cache=true|false
 })
 
 

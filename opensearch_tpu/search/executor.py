@@ -56,7 +56,8 @@ from opensearch_tpu.search.compile import (Compiler, Plan, ShardStats,
                                            _PartialBundle, carry_memo,
                                            struct_fingerprint)
 from opensearch_tpu.search.plan_eval import _eval_plan, eval_knn_winners
-from opensearch_tpu.search.aggs.engine import compile_aggs, eval_aggs
+from opensearch_tpu.search.aggs.engine import (compile_aggs, eval_aggs,
+                                               note_bin_sources)
 from opensearch_tpu.search.aggs.parse import parse_aggs
 from opensearch_tpu.search.aggs.reduce import decode_outputs, reduce_aggs
 from opensearch_tpu.telemetry import TELEMETRY
@@ -596,6 +597,10 @@ _SEARCH_PHASE_HISTS = {
 # search.knn_clause.exact / .ivf / .filtered (search/compile.py)
 _KNN_PAGE_FROM_CLAUSE = TELEMETRY.metrics.counter(
     "search.knn_clause.page_from_clause")
+# query items dispatched through the aggregating envelope program
+# (`jit_agg_env`: build_batched_agg_query_phase), once an item whatever
+# its segments
+_AGG_ENV_QUERIES = TELEMETRY.metrics.counter("search.agg_env.queries")
 
 
 def _vector_leaf(plan: Plan) -> Optional[Plan]:
@@ -636,16 +641,22 @@ def _layout_batch(layout) -> int:
     return 0
 
 
-def _env_shape(layout, k: int, meta, knn: Optional[Plan] = None) -> str:
+def _env_shape(layout, k: int, meta, knn: Optional[Plan] = None,
+               agg_bins: Optional[int] = None) -> str:
     """Shape-bucket string for an envelope executable: padded batch,
     top-k and the segment's padded doc axis — the axes the compile key
     buckets on. A `knn` family program names what its scan reads and
     selects instead, `d<d_pad>x<dims>k<k>` of its k-NN clause `knn`:
     the page's k says nothing of the clause's, and the vector width is
-    the program's cost."""
+    the program's cost. An `agg_env` family program names its lanes and
+    its bins, `d<d_pad>/bins<agg_bins>`: every bin of every partial
+    array a query's row carries back (counts, and a metric's cnt, sum,
+    min, max each), which with the lanes is what its reductions cost."""
     if knn is not None:
         return (f"b{_layout_batch(layout)}/d{meta.d_pad}"
                 f"x{knn.inputs['query'].shape[-1]}k{knn.static[1]}")
+    if agg_bins is not None:
+        return f"b{_layout_batch(layout)}/d{meta.d_pad}/bins{agg_bins}"
     return f"b{_layout_batch(layout)}/k{k}/d{meta.d_pad}"
 
 
@@ -1258,7 +1269,8 @@ def build_query_phase(plan: Plan, meta: DeviceSegmentMeta, k: int,
 
     def run(seg, flat_inputs, sort_key_arr, min_score):
         cursor = [0]
-        scores, matches = _eval_plan(plan, seg, flat_inputs, cursor)
+        scores, matches = _eval_query(plan, seg, flat_inputs, cursor,
+                                      bool(agg_plans))
         d_pad = seg["live"].shape[0]
         eligible, total = _eligible_total(matches, seg, meta.num_docs,
                                           scores, min_score)
@@ -1270,8 +1282,9 @@ def build_query_phase(plan: Plan, meta: DeviceSegmentMeta, k: int,
             top_scores = scores[top_idx]
         agg_outs = []
         if agg_plans:
-            eval_aggs(list(agg_plans), seg, flat_inputs, cursor, eligible,
-                      agg_outs)
+            with _stage("agg_bins"):
+                eval_aggs(list(agg_plans), seg, flat_inputs, cursor,
+                          eligible, agg_outs)
         return top_keys, top_scores, top_idx.astype(jnp.int32), total, agg_outs
 
     return run
@@ -1389,6 +1402,19 @@ def _pack_row(top_scores, top_idx, total):
             jax.lax.bitcast_convert_type(top_scores, jnp.int32),
             top_idx.astype(jnp.int32),
             total[None].astype(jnp.int32)])
+
+
+def _eval_query(plan: Plan, seg, flat_inputs, cursor, aggregating: bool):
+    """`_eval_plan` of a program's query. In an aggregating program (the
+    agg envelope, the host loop's query phase with aggregations) it is
+    the stage `filter_mask`, as in the SPMD program: the match mask the
+    bins are counted under. A text clause inside keeps its own stages
+    (the innermost scope names an op). A program without aggregations
+    is left as it was."""
+    if not aggregating:
+        return _eval_plan(plan, seg, flat_inputs, cursor)
+    with _stage("filter_mask"):
+        return _eval_plan(plan, seg, flat_inputs, cursor)
 
 
 def _topk_or_empty(eligible, scores, k_eff: int):
@@ -1652,23 +1678,25 @@ def build_batched_agg_query_phase(plan: Plan, meta: DeviceSegmentMeta,
 
     def one(seg, flat_inputs, min_score):
         cursor = [0]
-        scores, matches = _eval_plan(plan, seg, flat_inputs, cursor)
+        scores, matches = _eval_query(plan, seg, flat_inputs, cursor, True)
         d_pad = seg["live"].shape[0]
         eligible, total = _eligible_total(matches, seg, meta.num_docs,
                                           scores, min_score)
         k_eff = min(k, d_pad)
         top_scores, top_idx = _topk_or_empty(eligible, scores, k_eff)
         agg_outs: List[dict] = []
-        eval_aggs(list(agg_plans), seg, flat_inputs, cursor, eligible,
-                  agg_outs)
+        with _stage("agg_bins"):
+            eval_aggs(list(agg_plans), seg, flat_inputs, cursor, eligible,
+                      agg_outs, batch=_layout_batch(layout))
         pieces = [_pack_row(top_scores, top_idx, total)]
-        for out in agg_outs:
-            for v in _flatten_agg_out(out):
-                v = v.reshape(-1)
-                pieces.append(
-                    jax.lax.bitcast_convert_type(v, jnp.int32)
-                    if v.dtype == jnp.float32 else v.astype(jnp.int32))
-        return jnp.concatenate(pieces)
+        with _stage("pack_row"):
+            for out in agg_outs:
+                for v in _flatten_agg_out(out):
+                    v = v.reshape(-1)
+                    pieces.append(
+                        jax.lax.bitcast_convert_type(v, jnp.int32)
+                        if v.dtype == jnp.float32 else v.astype(jnp.int32))
+            return jnp.concatenate(pieces)
 
     def run(seg, packed_buf):
         batched_flat, min_scores = _unpack_envelope(packed_buf, layout,
@@ -1751,7 +1779,8 @@ def _agg_envelope_runner(plan_sig, plan: Plan, meta: DeviceSegmentMeta,
             plan, meta, k, layout, treedef, axes, agg_plans), "agg_env")
         _JIT_CACHE[key] = (fn, out_layout, width)  # shared-state-ok: benign double-jit race; dict slot write is GIL-atomic
         wrapped = _timed_first_call(
-            fn, family="agg_env", shape=_env_shape(layout, k, meta),
+            fn, family="agg_env",
+            shape=_env_shape(layout, k, meta, agg_bins=width),
             key=key, cost=_plan_cost(plan, meta, _layout_batch(layout)))
         return (wrapped, out_layout, width)
     return hit
@@ -2268,7 +2297,8 @@ def _compare_candidates(specs):
 # request keys the batched envelope path fully renders; anything else
 # (highlight, collapse, rescore, ...) takes the general path
 _BATCHABLE_KEYS = frozenset({"query", "size", "from", "min_score", "sort",
-                             "_source", "aggs", "aggregations"})
+                             "_source", "aggs", "aggregations",
+                             "_request_cache"})
 
 
 def _contains_hybrid(query_spec) -> bool:
@@ -2307,6 +2337,9 @@ class SearchExecutor:
         # index.max_result_window (set by the owning IndexService; the
         # default matches the reference)
         self.max_result_window = 10000
+        # index.requests.cache.enable (likewise; indices/request_cache.py
+        # `admits` is the one reader)
+        self.request_cache_enabled = True
         # wave-pipeline staging: recycled host envelope buffers, released
         # only after the owning wave's collect (zero-copy-safe reuse)
         self._staging = _StagingPool()
@@ -2349,7 +2382,7 @@ class SearchExecutor:
                                               stats_override, trace,
                                               ledger_scope)
         rc = _request_cache()
-        if rc.cacheable(body):
+        if rc.admits(body, self.request_cache_enabled):
             base = rc.cache_key(self.reader.segments, body, k,
                                 extra_filter)
             key = ("shard", base) if base is not None else None
@@ -2463,6 +2496,7 @@ class SearchExecutor:
             compiler.filter_ctx = None
             agg_plans = compile_aggs(device_agg_nodes, self.reader.mapper, seg,
                                      meta, compiler) if agg_nodes else []
+            note_bin_sources(agg_plans)
             if rec:
                 plan_compile_ns += time.perf_counter_ns() - t0
             # always-on scan accounting (telemetry/scan.py, ISSUE 14):
@@ -3506,8 +3540,9 @@ class SearchExecutor:
         tpl = dsl.intern_query(body.get("query")) if TEMPLATE_INTERNING \
             else None
         rc = _request_cache()
-        if rc.cacheable(body, query_now_safe=tpl is not None) \
-                and not bypass_request_cache:
+        if not bypass_request_cache and rc.admits(
+                body, self.request_cache_enabled,
+                query_now_safe=tpl is not None):
             # shard request cache at QUERY-PHASE granularity: the
             # cached value is (total, decoded partials, agg nodes) —
             # live objects the renderers only read — and the response
@@ -4185,8 +4220,12 @@ class SearchExecutor:
                                 agg_sig is None
                                 and _blockmax_admitted(plan0, k_seg)))
                 from_clause |= agg_sig is None and _page_from_clause(plan0)
+                if agg_sig is not None:
+                    note_bin_sources(agg_by_i[idxs[0]][seg_i], len(idxs))
             if from_clause:
                 _KNN_PAGE_FROM_CLAUSE.inc(len(idxs))
+            if agg_sig is not None and not dead.issuperset(idxs):
+                _AGG_ENV_QUERIES.inc(len(idxs))
         _t_end = time.monotonic()
         ph["stack_pack_dispatch"] += _t_end - _t_pack
         if span is not None:

@@ -223,7 +223,7 @@ def spmd_query_phase(executors: List, body: dict, k: int,
     requires one signature; e.g. a per-segment `precomputed` host
     fallback)."""
     from opensearch_tpu.indices.request_cache import (
-        REQUEST_CACHE, cache_key, cacheable)
+        REQUEST_CACHE, admits, cache_key)
     from opensearch_tpu.search.executor import _Candidate
 
     if TELEMETRY.ledger.devices.enabled:
@@ -233,7 +233,7 @@ def spmd_query_phase(executors: List, body: dict, k: int,
         TELEMETRY.ledger.devices.take_last()
 
     key = None
-    if cacheable(body):
+    if admits(body, all(ex.request_cache_enabled for ex in executors)):
         all_segs = [executors[s].reader.segments[g] for s, g in rows]
         # "spmd"-tagged so it can never collide with the per-shard
         # executor cache entries (same segments/body/k, different shape)

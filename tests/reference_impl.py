@@ -351,3 +351,61 @@ def ref_window_ms(budgets_ms, service_ms, queue_depth, arrival_gap_ms,
     if arrival_gap_ms is None or arrival_gap_ms > cap:
         return 0.0
     return cap
+
+
+# ------------------------------------------- nyc_taxis aggregation oracle
+
+def ref_histogram_stats(docs: Sequence[Dict], bucket_field: str,
+                        interval: float, lo: float, hi: float,
+                        metric_field: str) -> Tuple[int, List[Tuple]]:
+    """`range bucket_field {gte lo, lt hi}` > `histogram(bucket_field,
+    interval)` > `stats(metric_field)` over plain documents, in float64:
+    (hits, [(key, doc_count, (count, min, max, avg, sum) or None)]) for
+    every bucket from the first non-empty one to the last, the empty
+    ones between them included (`min_doc_count` 0). Values are taken as
+    a `scaled_float` of factor 100 stores them: round(v * 100) / 100."""
+    def stored(v):
+        return round(v * 100) / 100
+    sel = [d for d in docs if lo <= stored(d[bucket_field]) < hi]
+    by: Dict[int, List[float]] = {}
+    for d in sel:
+        by.setdefault(math.floor(stored(d[bucket_field]) / interval),
+                      []).append(stored(d[metric_field]))
+    out = []
+    for b in range(min(by), max(by) + 1) if by else ():
+        vs = by.get(b)
+        out.append((b * interval, len(vs or ()),
+                    None if not vs else
+                    (len(vs), min(vs), max(vs), math.fsum(vs) / len(vs),
+                     math.fsum(vs))))
+    return len(sel), out
+
+
+def ref_day_counts(times_ms: Sequence[int], lo_ms: int,
+                   hi_ms: int) -> Tuple[int, List[Tuple[int, int]]]:
+    """`range {gte lo_ms, lte hi_ms}` > `date_histogram(day)` over epoch
+    milliseconds: (hits, [(UTC day key in ms, doc_count)]) from the
+    first non-empty day to the last."""
+    day = 86_400_000
+    sel = [t for t in times_ms if lo_ms <= t <= hi_ms]
+    by: Dict[int, int] = {}
+    for t in sel:
+        by[t // day] = by.get(t // day, 0) + 1
+    return len(sel), [(d * day, by.get(d, 0))
+                      for d in (range(min(by), max(by) + 1) if by else ())]
+
+
+def ref_terms_avg(docs: Sequence[Dict], keep, term_field: str,
+                  metric_field: str) -> Tuple[int, List[Tuple]]:
+    """`terms(term_field)` > `avg(metric_field)` over the documents
+    `keep` admits: (hits, [(key, doc_count, avg)]) by doc_count
+    descending, then key ascending."""
+    by: Dict[str, List[float]] = {}
+    sel = [d for d in docs if keep(d)]
+    for d in sel:
+        by.setdefault(d[term_field], []).append(
+            round(d[metric_field] * 100) / 100)
+    return len(sel), [(k, len(v), math.fsum(v) / len(v))
+                      for k, v in sorted(by.items(),
+                                         key=lambda kv: (-len(kv[1]),
+                                                         kv[0]))]
