@@ -24,7 +24,6 @@ from __future__ import annotations
 
 import threading
 import time
-from collections import OrderedDict
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -91,6 +90,7 @@ from opensearch_tpu.search.compile import Plan
 from opensearch_tpu.search.plan_eval import _eval_plan
 from opensearch_tpu.search.aggs.engine import (BINS_RANK, BINS_TABLE,
                                                eval_aggs, plan_bin_room)
+from opensearch_tpu.search.aggs.lane_bins import LaneBinsMemo, lane_bins_row
 from opensearch_tpu.telemetry import TELEMETRY
 from opensearch_tpu.telemetry.kernels import (jit_family, stage,
                                               timed_first_call)
@@ -229,17 +229,13 @@ def align_agg_plans(per_shard: Sequence[Sequence[Any]]) -> None:
                     and len({p.static[3] for p in group}) > 1:
                 # a row whose rank -> bucket table happened to be the
                 # identity beside rows whose is not: it takes its own
-                # table back (an entry a rank of ITS column; stacked rows
-                # grow to the widest), so that the rows keep one
-                # structure. Rows compiled for one chip carry their
-                # tables among their inputs, so it does too
-                carried = any("table" in p.inputs for p in group)
+                # table back (`table_of`: an entry a rank of ITS column;
+                # stacked rows grow to the widest), so that the rows
+                # keep one structure
                 for p in group:
                     if p.static[3] == BINS_RANK:
                         p.static = p.static[:3] + (BINS_TABLE,) \
                             + p.static[4:]
-                        if carried:
-                            p.inputs = dict(p.inputs, table=p.table_of())
             if kind in _CARD_KINDS:
                 card = max(p.static[1] for p in group)
                 for p in group:
@@ -263,28 +259,17 @@ def align_agg_plans(per_shard: Sequence[Sequence[Any]]) -> None:
     walk(list(per_shard))
 
 
-# The lane -> bin vector `table[val_ords]` of a `histogram` or
-# `date_histogram` level rests on nothing of the request: the table is a
-# function of the level's bucketing and the segment's sorted unique
-# values, the rank column is sealed, and the request's own part reaches
-# the bins through the mask alone. So the SPMD route derives it once a
-# (shard set, field, bucketing) and keeps it on the mesh, int32
-# `[R_pad, n_pad]` sharded like the image's own columns. A shard set
-# holds at most this many, least recently used out: one vector is 4 B a
-# lane a row, and a key is a panel's (field, interval, offset or time
-# zone), none of which moves from one request of a dashboard to the
-# next. (A `range` bucket's bounds can, `now`-relative ones with every
-# request: those stay out of the memo, on the table their request
-# brings.) Levels planned on the SPMD route, by where their bins came
-# from, and vectors dropped (the memo's own LRU, or with their shard
-# set): always on, in `_nodes/stats`.
-MAX_LANE_BINS = 4
-LANE_BINS_HIT = TELEMETRY.metrics.counter("search.agg_lane_bins.hit")
-LANE_BINS_MISS = TELEMETRY.metrics.counter("search.agg_lane_bins.miss")
+# The lane -> bin vector of a `histogram` or `date_histogram` level is
+# derived once a (shard set, field, bucketing) and kept on the mesh,
+# int32 `[R_pad, n_pad]` sharded like the image's own columns, in the
+# shard set's `LaneBinsMemo`: why, how many and what is counted are in
+# search/aggs/lane_bins.py, which the one-chip routes share
+# (`search.agg_lane_bins.hit`, `.miss`, `.evicted`). This route alone
+# counts a level whose table is the identity here, since it compiles
+# every row for every request (the one-chip routes count theirs as
+# `search.agg_bins.level.rank`).
 LANE_BINS_IDENTITY = TELEMETRY.metrics.counter(
     "search.agg_lane_bins.identity")
-LANE_BINS_EVICTED = TELEMETRY.metrics.counter(
-    "search.agg_lane_bins.evicted")
 
 
 def resident_lane_bins(searcher: "DistributedSearcher",
@@ -311,7 +296,7 @@ def resident_lane_bins(searcher: "DistributedSearcher",
                 LANE_BINS_IDENTITY.inc()
             elif p0.kind == "bucket_num" and p0.table_of is not None:
                 field = p0.static[0]
-                slots.append(shard_set.lane_bins_of(
+                slots.append(shard_set.lane_bins.get(
                     (field,) + p0.bins_key,
                     lambda: searcher.derive_lane_bins(
                         shard_set, field, [p.table_of() for p in group])))
@@ -383,12 +368,10 @@ class HbmShardSet:
             int(np.prod(v.shape)) * np.dtype(v.dtype).itemsize
             for _, v in jax.tree_util.tree_flatten_with_path(
                 self.seg_stack)[0])
-        # the resident lane -> bin vectors of this set's rows, by (field,
-        # bucketing scalars): `resident_lane_bins`. They live and die
-        # with the set, so a refresh's new set starts with none
-        self._lane_bins: "OrderedDict[tuple, Any]" = OrderedDict()
-        self._lane_bins_lock = threading.Lock()
-        self._released = False
+        # the resident lane -> bin vectors of this set's rows
+        # (`resident_lane_bins`): they live and die with the set, so a
+        # refresh's new set starts with none
+        self.lane_bins = LaneBinsMemo(on_change=self._register)
         self._register()
 
     def _register(self) -> None:
@@ -396,7 +379,7 @@ class HbmShardSet:
         the lane -> bin vectors beside it, by their exact per-device
         split, as ONE entry of the device-memory gauges, which the
         residency cache (search/spmd.py) releases at eviction."""
-        total = self.nbytes + sum(v.nbytes for v in self._lane_bins.values())
+        total = self.nbytes + self.lane_bins.nbytes
         TELEMETRY.device_memory.register(
             "spmd_shard_sets", id(self), total,
             devices=mesh_device_split(self.mesh, total))
@@ -405,32 +388,8 @@ class HbmShardSet:
         """The residency cache dropped this set: the image and its
         lane -> bin vectors leave the device-memory gauges together (a
         request still running on the set adds to them no more)."""
-        with self._lane_bins_lock:
-            self._released = True
-            LANE_BINS_EVICTED.inc(len(self._lane_bins))
-            self._lane_bins.clear()
+        self.lane_bins.release()
         TELEMETRY.device_memory.release("spmd_shard_sets", id(self))
-
-    def lane_bins_of(self, key: tuple, derive):
-        """The resident vector under `key`, least recently used last;
-        `derive()` makes it on a miss, under the set's lock, so that
-        concurrent requests of one panel derive it once."""
-        with self._lane_bins_lock:
-            bins = self._lane_bins.get(key)
-            if bins is not None:
-                self._lane_bins.move_to_end(key)
-                LANE_BINS_HIT.inc()
-                return bins
-            LANE_BINS_MISS.inc()
-            bins = derive()
-            if self._released:
-                return bins
-            while len(self._lane_bins) >= MAX_LANE_BINS:
-                self._lane_bins.popitem(last=False)
-                LANE_BINS_EVICTED.inc()
-            self._lane_bins[key] = bins
-            self._register()
-            return bins
 
 
 class DistributedSearcher:
@@ -601,11 +560,8 @@ class DistributedSearcher:
         key = ("lane_bins", stack.shape, tuple(col["val_ords"].shape))
         fn = self._cache.get(key)
         if fn is None:
-            def one_row(table, doc_ids, val_ords):
-                return jnp.where(doc_ids >= 0, table[val_ords], -1)
-
             spec = P(self.axis)
-            mapped = _shard_map(jax.vmap(one_row), mesh=self.mesh,
+            mapped = _shard_map(jax.vmap(lane_bins_row), mesh=self.mesh,
                                 in_specs=(spec, spec, spec), out_specs=spec)
 
             def agg_lane_bins(table, doc_ids, val_ords):

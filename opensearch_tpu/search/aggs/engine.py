@@ -23,19 +23,22 @@ composition.
 
 Where a numeric level's bins come from (kind `bucket_num`, `static[3]`;
 `_bucket_lookup_plan`). The lane -> bin vector `table[val_ords]` rests on
-nothing of the request, so no route should compute it a request:
+nothing of the request, so no route computes it a request:
 - a table that is the identity (`terms` on a numeric column) is never
-  gathered through, on any route: the rank column is the bin (BINS_RANK);
-- the SPMD route (search/spmd.py) keeps the vector of any other
-  `histogram`/`date_histogram` level resident on the mesh beside the
-  shard set's image, derived once a (shard set, field, bucketing):
-  parallel/distributed.py `resident_lane_bins`; the plan names its slot,
-  and its table is built on a miss alone (`AggPlan.table_of`);
-- the one-chip routes (host loop, agg envelope), and a `range` bucket on
-  every route (its bounds may move with every request), keep the table
-  among the request's inputs and gather a request (BINS_TABLE), except
-  for root leaves on one chip, whose lane bitmasks are precomputed and
-  closed over (`bucket_bits`).
+  gathered through: the rank column is the bin (BINS_RANK);
+- the vector of any other `histogram`/`date_histogram` level is resident
+  on the device beside the image it was derived from, once a (device
+  image, field, bucketing), in that image's `LaneBinsMemo`
+  (search/aggs/lane_bins.py): the shard set's on the SPMD route
+  (parallel/distributed.py `resident_lane_bins`), the segment's on the
+  one-chip routes (host loop, agg envelope: search/executor.py
+  `ShardReader.with_lane_bins`). The plan names its slot, carries no
+  table, and builds one on a miss alone (`AggPlan.table_of`);
+- a `range` bucket keeps its table among the request's inputs on every
+  route and gathers a request (BINS_TABLE): its bounds may move with
+  every request;
+- a root leaf within the popcount budget on one chip is none of these:
+  its lane bitmasks are precomputed and closed over (`bucket_bits`).
 
 Approximation policy: the reference uses TDigest percentiles and HLL++
 cardinality; here both are EXACT, computed from per-bucket value-rank
@@ -143,9 +146,9 @@ class AggPlan:
     # a `histogram`/`date_histogram` level of kind bucket_num: the scalars
     # that, with the field and the segment's sorted unique values, define
     # its rank -> bucket table (interval and shift, or the calendar unit),
-    # and the function that builds the table, padded, when asked. What the
-    # SPMD route keys a level's resident lane -> bin vector by and derives
-    # it from: a hit neither hashes nor builds a table
+    # and the function that builds the table, padded, when asked. What a
+    # level's resident lane -> bin vector is keyed by and derived from: a
+    # hit neither hashes nor builds a table
     bins_key: Optional[tuple] = None
     table_of: Optional[Callable[[], np.ndarray]] = None
     # segment-static arrays CLOSED OVER by the device program instead of
@@ -171,7 +174,11 @@ class AggPlan:
                         .hexdigest())
             return (k, v.shape, str(v.dtype))
 
+        # a level that reads a resident vector takes the place of its
+        # table's content with what the vector is keyed by: two queries
+        # share a program RUN only where they name the same vectors
         out = (self.kind, self.static,
+               self.bins_key if bins_slot(self) is not None else None,
                tuple(sorted(leaf_sig(k, v)
                             for k, v in self.inputs.items())),
                self.query_plan.sig() if self.query_plan is not None else None,
@@ -212,9 +219,8 @@ class _Ctx:
     # False for cross-row tracing paths (SPMD): fused kinds embed
     # segment-specific constants in the executable, which a single program
     # traced from row 0 would wrongly apply to every row. That route
-    # compiles every row for every request and keeps a numeric level's
-    # bins resident, so it also leaves the level's rank -> bucket table
-    # unbuilt (`_bucket_lookup_plan`)
+    # also names the slots of its resident lane -> bin vectors itself,
+    # once its rows agree on a structure (`compile_aggs`)
     fused: bool = True
 
 
@@ -241,30 +247,60 @@ def _register_const_bytes(plans: List[AggPlan], seg: Segment) -> None:
 def compile_aggs(nodes: List[AggNode], mapper: MapperService, seg: Segment,
                  meta, compiler: Compiler,
                  allow_fused: bool = True) -> List[AggPlan]:
+    """The plans of one program over one segment. `allow_fused` says the
+    program is this segment's own (the one-chip routes): root leaves may
+    close over the segment's bitmasks, and the levels that read a
+    resident lane -> bin vector name their slots here, in the order
+    `resident_levels` walks them. A cross-row compile (the SPMD route)
+    names them once its rows agree (parallel/distributed.py
+    `resident_lane_bins`)."""
     ctx = _Ctx(mapper, seg, meta, compiler, pad_bucket(max(seg.num_docs, 1)),
                fused=allow_fused)
     plans = [_compile_node(n, ctx, root=True) for n in nodes]
+    if allow_fused:
+        for slot, p in enumerate(resident_levels(plans)):
+            p.static = p.static[:3] + (slot,) + p.static[4:]
     _register_const_bytes(plans, seg)
     return plans
+
+
+def resident_levels(plans: List[AggPlan]):
+    """The levels of a program whose bins are a resident lane -> bin
+    vector (a `histogram`/`date_histogram` whose table is not the
+    identity), in the order of their slots: pre-order."""
+    for p in plans:
+        if p.kind == "bucket_num" and p.table_of is not None \
+                and p.static[3] != BINS_RANK:
+            yield p
+        yield from resident_levels(p.children)
+
+
+def bins_slot(plan: AggPlan) -> Optional[int]:
+    """The slot of the resident vector a level reads, if it reads one."""
+    if plan.kind == "bucket_num" and isinstance(plan.static[3], int):
+        return plan.static[3]
+    return None
 
 
 # Bucket levels the one-chip routes planned (host loop: once a segment
 # compiled; agg envelope: once an item and segment program dispatched, a
 # plan-memo hit included), by where the level's bins come from: the rank
-# column itself (BINS_RANK), a rank -> bucket table gathered through a
-# request (BINS_TABLE), lane bitmasks closed over by the executable
+# column itself (BINS_RANK), the segment's resident lane -> bin vector (a
+# slot), a rank -> bucket table gathered through a request (BINS_TABLE: a
+# `range` bucket), lane bitmasks closed over by the executable
 # (`bucket_bits`). The SPMD route counts its levels as
 # `search.agg_lane_bins.*` (parallel/distributed.py).
 _BIN_SOURCES = {src: TELEMETRY.metrics.counter(f"search.agg_bins.level.{src}")
-                for src in ("rank", "table", "bits")}
+                for src in ("rank", "resident", "table", "bits")}
 
 
 def note_bin_sources(plans: List["AggPlan"], times: int = 1) -> None:
     for p in plans:
         if p.kind == "bucket_bits":
             _BIN_SOURCES["bits"].inc(times)
-        elif p.kind == "bucket_num" and p.static[3] in (BINS_RANK,
-                                                        BINS_TABLE):
+        elif bins_slot(p) is not None:
+            _BIN_SOURCES["resident"].inc(times)
+        elif p.kind == "bucket_num":
             _BIN_SOURCES[p.static[3]].inc(times)
         if p.children:
             note_bin_sources(p.children, times)
@@ -283,15 +319,18 @@ def _ident_pairs(col) -> bool:
 # - BINS_RANK: the rank -> bucket table is the identity (`terms` on a
 #   numeric column; a histogram whose every unique value opens its own
 #   bucket), so the rank column `val_ords` IS the bin: no table input, no
-#   gather. Every route that runs `_eval_agg`.
+#   gather.
+# - an int slot: any other `histogram`/`date_histogram` level. The
+#   gather's result is segment-static, so it is derived once a (device
+#   image, field, `bins_key`), kept on the device beside that image
+#   (search/aggs/lane_bins.py) and handed to the program as
+#   `seg["lane_bins"][slot]`; the plan carries no table, and builds none
+#   on a hit. The one-chip routes' slots are named by `compile_aggs`, the
+#   SPMD route's by `resident_lane_bins` (until then such a level reads
+#   BINS_TABLE without a table).
 # - BINS_TABLE: the table rides the request's inputs and the program
-#   gathers `table[val_ords]` over every lane: the one-chip routes (host
-#   loop, agg envelope) for any other table, and every `range` bucket.
-# - an int slot: the gather's result is segment-static, so the SPMD route
-#   (parallel/distributed.py `resident_lane_bins`) derives it once a
-#   (shard set, field, `bins_key`), keeps it on the mesh beside the shard
-#   set's image and hands it to the program as `seg["lane_bins"][slot]`;
-#   the plan carries no table, and builds none on a hit.
+#   gathers `table[val_ords]` over every lane: a `range` bucket, on every
+#   route.
 BINS_RANK = "rank"
 BINS_TABLE = "table"
 
@@ -341,9 +380,9 @@ def _bucket_lookup_plan(node: AggNode, ctx: _Ctx, card: int, render: dict,
     `bucket_of_rank()` builds the bucket of every unique value of the
     segment's column, `card` is its last entry + 1. The table can be the
     identity only where `card` is the number of unique values, so it is
-    built here only then (to look), and on the one-chip routes, which
-    take it among the request's inputs; a cross-row compile (the SPMD
-    route, `ctx.fused` False) leaves it to `AggPlan.table_of`."""
+    built here only then (to look); any other level reads a resident
+    lane -> bin vector, and its table is `AggPlan.table_of`'s to build
+    when that vector is derived."""
     col = _num_col(ctx, node.field)
     n = len(col.unique)
     identity = card == n and np.array_equal(bucket_of_rank(), np.arange(n))
@@ -351,15 +390,12 @@ def _bucket_lookup_plan(node: AggNode, ctx: _Ctx, card: int, render: dict,
         ctx, node, (node.field,) + bins_key,
         lambda: np.bincount(bucket_of_rank()[col.value_ords],
                             minlength=card))
-    plan = AggPlan(name=node.name, kind="bucket_num",
+    return AggPlan(name=node.name, kind="bucket_num",
                    static=(node.field, card, _ident_pairs(col),
                            BINS_RANK if identity else BINS_TABLE, room),
                    children=[_compile_node(c, ctx) for c in node.children],
                    render=render, bins_key=bins_key,
                    table_of=lambda: _rank_table(bucket_of_rank()))
-    if not identity and ctx.fused:
-        plan.inputs["table"] = plan.table_of()
-    return plan
 
 
 # ------------------------------------------------- fused leaf bucketing

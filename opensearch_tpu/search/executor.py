@@ -57,7 +57,9 @@ from opensearch_tpu.search.compile import (Compiler, Plan, ShardStats,
                                            struct_fingerprint)
 from opensearch_tpu.search.plan_eval import _eval_plan, eval_knn_winners
 from opensearch_tpu.search.aggs.engine import (compile_aggs, eval_aggs,
-                                               note_bin_sources)
+                                               note_bin_sources,
+                                               resident_levels)
+from opensearch_tpu.search.aggs.lane_bins import LaneBinsMemo, lane_bins_row
 from opensearch_tpu.search.aggs.parse import parse_aggs
 from opensearch_tpu.search.aggs.reduce import decode_outputs, reduce_aggs
 from opensearch_tpu.telemetry import TELEMETRY
@@ -183,6 +185,13 @@ class ShardReader:
         self._publish_lock = threading.Lock()
         self._stats_cache: Optional[ShardStats] = None
         self._seg_bytes: Dict[str, int] = {}    # seg_id → device bytes
+        # seg_id → (the meta of the image now held, its resident
+        # lane -> bin vectors): `with_lane_bins`. They are derived from
+        # that image's rank columns, so they go when it does
+        # (`_hold_image_locked`, `remove_segment`); a delete moves `live`
+        # alone and leaves them
+        self._lane_bins: Dict[str, Tuple[DeviceSegmentMeta,
+                                         LaneBinsMemo]] = {}
         # segment-keyed memo carry (ISSUE 16 tentpole b, gate-lint row):
         # OFF by default — a publish drops the whole ShardStats memo
         # exactly as before; ON, _build_stats copies still-valid interned
@@ -240,9 +249,45 @@ class ShardReader:
 
     @property
     def device_bytes(self) -> int:
-        """Live device bytes held by this reader's segment images —
-        the corpus-columns slice of the device-memory stats."""
-        return sum(self._seg_bytes.values())
+        """Live device bytes held by this reader's segment images and
+        the lane -> bin vectors beside them — the corpus-columns slice
+        of the device-memory stats."""
+        return sum(self._seg_bytes.values()) \
+            + sum(memo.nbytes for _, memo in list(self._lane_bins.values()))
+
+    def _hold_image_locked(self, meta: DeviceSegmentMeta, nb: int) -> None:
+        """A segment's image was uploaded (anew): count its bytes, and
+        start its lane -> bin vectors from none, dropping those of the
+        image it replaces. Caller holds _publish_lock."""
+        self._seg_bytes[meta.seg_id] = nb
+        old = self._lane_bins.get(meta.seg_id)
+        if old is not None:
+            old[1].release()
+        self._lane_bins[meta.seg_id] = (meta, LaneBinsMemo())
+
+    def with_lane_bins(self, arrays: Dict, meta: DeviceSegmentMeta,
+                       agg_plans) -> Dict:
+        """The segment image as a program of `agg_plans` takes it: with
+        the resident lane -> bin vectors its levels name, in slot order,
+        under `lane_bins` (search/aggs/lane_bins.py). Each is found in
+        the image's memo or derived now from the level's table by one
+        program over the resident rank column. An image this reader no
+        longer holds (a request that outlived a merge, a pinned reader)
+        derives for itself and keeps nothing."""
+        levels = list(resident_levels(agg_plans))
+        if not levels:
+            return arrays
+        held = self._lane_bins.get(meta.seg_id)
+        if held is not None and held[0] is meta:
+            memo = held[1]
+        else:
+            memo = LaneBinsMemo()
+            memo.release()
+        return dict(arrays, lane_bins=[
+            memo.get((p.static[0],) + p.bins_key,
+                     lambda p=p: _derive_lane_bins(
+                         p.table_of(), arrays["numeric"][p.static[0]]))
+            for p in levels])
 
     # ------------------------------------------------- staged publish
 
@@ -313,7 +358,7 @@ class ShardReader:
         with self._publish_lock:
             segs, dev = self._cur_pair_locked()
             self._set_pair_locked((segs + [seg], dev + [(arrays, meta)]))
-            self._seg_bytes[seg.seg_id] = nb
+            self._hold_image_locked(meta, nb)
         if _devseg.DELTA_PUBLISH:
             self._live_sigs[seg.seg_id] = _live_sig(seg)
         if _LEDGER.enabled:
@@ -343,6 +388,9 @@ class ShardReader:
                     self._set_pair_locked((segs[:i] + segs[i + 1:],
                                            dev[:i] + dev[i + 1:]))
                     self._seg_bytes.pop(seg_id, None)
+                    held = self._lane_bins.pop(seg_id, None)
+                    if held is not None:
+                        held[1].release()
                     self._live_sigs.pop(seg_id, None)
                     return
 
@@ -428,7 +476,7 @@ class ShardReader:
                                 segs[:j] + [seg] + segs[j + 1:],
                                 dev[:j] + [(arrays, meta)]
                                 + dev[j + 1:]))
-                            self._seg_bytes[seg.seg_id] = nb
+                            self._hold_image_locked(meta, nb)
                             break
                 if _devseg.DELTA_PUBLISH:
                     self._live_sigs[seg.seg_id] = _live_sig(seg)
@@ -543,6 +591,9 @@ class PinnedReader:
         self.segments = list(segments)
         self.device = list(device)
         self._stats = ShardStats(self.segments)
+        # the pinned images are the source reader's: so are their
+        # resident lane -> bin vectors, for as long as it holds them
+        self.with_lane_bins = reader.with_lane_bins
 
     @property
     def num_docs(self) -> int:
@@ -601,6 +652,24 @@ _KNN_PAGE_FROM_CLAUSE = TELEMETRY.metrics.counter(
 # (`jit_agg_env`: build_batched_agg_query_phase), once an item whatever
 # its segments
 _AGG_ENV_QUERIES = TELEMETRY.metrics.counter("search.agg_env.queries")
+
+
+def _derive_lane_bins(table: np.ndarray, col: Dict):
+    """`table[val_ords]` over one segment's resident rank column, -1
+    where the table says no bucket or the lane is padding: int32
+    `[n_pad]` on the device. The gather the served program did a
+    request, once a (segment image, field, bucketing); the table
+    (`[u_pad]`) is uploaded for it and dropped."""
+    key = ("lane_bins", table.shape, tuple(col["val_ords"].shape))
+    fn = _JIT_CACHE.get(key)
+    if fn is None:
+        fn = jax.jit(lane_bins_row)     # module `jit_lane_bins_row`
+        _JIT_CACHE[key] = fn  # shared-state-ok: benign double-jit race; dict slot write is GIL-atomic
+        # a compile on the serving thread counts as one
+        # (search.xla_cache_miss); no census record: not a served
+        # program, and it runs once a (segment image, field, bucketing)
+        fn = _timed_first_call(fn)
+    return fn(jnp.asarray(table), col["doc_ids"], col["val_ords"])
 
 
 def _vector_leaf(plan: Plan) -> Optional[Plan]:
@@ -2533,8 +2602,9 @@ class SearchExecutor:
             if rec:
                 t0 = time.perf_counter_ns()
             flat = jax.tree_util.tree_map(jnp.asarray, flat)
+            seg_in = self.reader.with_lane_bins(arrays, meta, agg_plans)
 
-            def _dispatch(fn=fn, arrays=arrays, flat=flat,
+            def _dispatch(fn=fn, arrays=seg_in, flat=flat,
                           sort_key=sort_key):
                 # fault site + bounded transient retry around the device
                 # call: a transient dispatch blip costs a retry, not the
@@ -4151,6 +4221,10 @@ class SearchExecutor:
                 plan0 = compiled[idxs[0]][seg_i]
                 try:
                     if agg_sig is not None:
+                        # one vector a level serves the whole group:
+                        # its items agree on `agg_sig`, so on the keys
+                        arrays = self.reader.with_lane_bins(
+                            arrays, meta, agg_by_i[idxs[0]][seg_i])
                         fn, out_layout, agg_w = _agg_envelope_runner(
                             plan_struct(plan0), plan0, meta, k_seg,
                             layout, treedef, tuple(axes), agg_sig[seg_i],

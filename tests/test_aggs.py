@@ -5,6 +5,8 @@ counts, metric values, nesting via bucketOrd composition, two-level reduce
 across segments, pipeline aggs on the reduced tree.
 """
 
+import json
+
 import numpy as np
 import pytest
 
@@ -391,18 +393,110 @@ def test_terms_on_a_numeric_column_reads_the_rank_column(executor):
 def test_a_table_is_the_identity_by_what_it_holds(spec, identity):
     """Decided from the table the compiler just built, not from the
     aggregation's type: a histogram whose every unique value opens its
-    own bucket is the identity too, any other keeps its table (a
+    own bucket is the identity too; any other names the slot of its
+    segment's resident lane -> bin vector and carries no table either (a
     sub-aggregation keeps these off the fused root-leaf kinds)."""
-    from opensearch_tpu.search.aggs.engine import BINS_RANK, BINS_TABLE
+    from opensearch_tpu.search.aggs.engine import (BINS_RANK, bins_slot,
+                                                   resident_levels)
     ex = build_executor()
     spec = {"h": dict(spec, aggs={"p": {"sum": {"field": "price"}}})}
     for seg, arrays, (plan,) in _compiled(ex, spec):
         assert plan.kind == "bucket_num" and plan.bins_key is not None
-        assert plan.static[3] == (BINS_RANK if identity else BINS_TABLE)
-        assert ("table" in plan.inputs) == (not identity)
+        assert plan.static[3] == (BINS_RANK if identity else 0)
+        assert bins_slot(plan) == (None if identity else 0)
+        assert list(resident_levels([plan])) == ([] if identity else [plan])
+        assert plan.inputs == {}
     field = next(iter(spec["h"].values()))["field"]
     buckets = agg(ex, spec)["h"]["buckets"]
     assert sum(b["doc_count"] for b in buckets) \
         == sum(1 for d in DOCS if field in d)
     assert sum(b["p"]["value"] for b in buckets) \
         == sum(d.get("price", 0.0) for d in DOCS if field in d)
+
+
+RESIDENT_SPECS = {
+    "histogram>stats": {"h": {
+        "histogram": {"field": "qty", "interval": 2},
+        "aggs": {"p": {"stats": {"field": "price"}}}}},
+    "months>sum": {"h": {
+        "date_histogram": {"field": "day", "calendar_interval": "month"},
+        "aggs": {"p": {"sum": {"field": "price"}}}}},
+    "terms>histogram>avg+a-sibling": {
+        "t": {"terms": {"field": "cat"}, "aggs": {"h": {
+            "histogram": {"field": "qty", "interval": 3},
+            "aggs": {"p": {"avg": {"field": "price"}}}}}},
+        "m": {"date_histogram": {"field": "day", "fixed_interval": "2d"},
+              "aggs": {"q": {"max": {"field": "qty"}}}}},
+}
+
+
+@pytest.mark.parametrize("spec", list(RESIDENT_SPECS.values()),
+                         ids=list(RESIDENT_SPECS))
+def test_a_resident_level_feeds_the_bins_its_table_gathered(spec):
+    """ISSUE 36: the program that reads `seg["lane_bins"][slot]` returns,
+    bit for bit, what the same level gathered through its table a request
+    returns (the parent's program): same bins into the same reductions,
+    float sums included; slots in the order the plans are walked."""
+    import jax
+    import jax.numpy as jnp
+    from dataclasses import replace
+    from opensearch_tpu.search.aggs.engine import (BINS_TABLE, bins_slot,
+                                                   eval_aggs,
+                                                   resident_levels)
+    ex = build_executor()
+    reader = ex.reader
+
+    def through_tables(p):
+        kids = [through_tables(c) for c in p.children]
+        if bins_slot(p) is None:
+            return replace(p, children=kids)
+        return replace(p, children=kids, inputs={"table": p.table_of()},
+                       static=p.static[:3] + (BINS_TABLE,) + p.static[4:])
+
+    def run(plans, seg_in):
+        outs = []
+        flat = []
+        for p in plans:
+            p.flatten_inputs(flat)
+        flat = jax.tree_util.tree_map(jnp.asarray, flat)
+        eval_aggs(plans, seg_in, flat, [0], seg_in["live"], outs)
+        return outs
+    for (seg, arrays, plans), (_, meta) in zip(_compiled(ex, spec),
+                                               reader.device):
+        levels = list(resident_levels(plans))
+        assert [bins_slot(p) for p in levels] == list(range(len(levels)))
+        assert len(levels) == sum(
+            "histogram" in k for k in json.dumps(spec).split('"'))
+        seg_in = reader.with_lane_bins(arrays, meta, plans)
+        assert len(seg_in["lane_bins"]) == len(levels)
+        assert "lane_bins" not in arrays
+        mine = run(plans, seg_in)
+        theirs = run([through_tables(p) for p in plans], arrays)
+        assert jax.tree_util.tree_structure(mine) \
+            == jax.tree_util.tree_structure(theirs)
+        for a, b in zip(jax.tree_util.tree_leaves(mine),
+                        jax.tree_util.tree_leaves(theirs)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # and served: the REST answer of the same tree
+    out = agg(ex, spec)
+    for name in spec:
+        assert sum(b["doc_count"] for b in out[name]["buckets"]) > 0
+
+
+def test_two_bucketings_of_a_field_do_not_share_a_signature():
+    """A level that reads a resident vector carries no table whose
+    content could tell two bucketings of one `card` apart: its signature
+    holds what the vector is keyed by, so a `_msearch` group (one
+    program run, one vector a slot) never mixes them."""
+    ex = build_executor()
+
+    def sigs(interval, offset):
+        spec = {"h": {"histogram": {"field": "qty", "interval": interval,
+                                    "offset": offset},
+                      "aggs": {"p": {"sum": {"field": "price"}}}}}
+        return [(plan.static, plan.sig())
+                for _, _, (plan,) in _compiled(ex, spec)]
+    for (static_a, sig_a), (static_b, sig_b) in zip(sigs(2, 0), sigs(2, 1)):
+        assert static_a == static_b     # same card, same slot
+        assert sig_a != sig_b
+    assert sigs(2, 0) == sigs(2, 0)

@@ -6,7 +6,9 @@ tail and negative amounts; a `dd/MM/yyyy` range whose `lte` is the end
 of its day; BASELINE.json config 2's shape; the index's
 `index.requests.cache.enable: false` and `?request_cache=` honoured on
 the envelope, the host loop and the SPMD route; the stages of
-`jit_agg_env`, its `dispatch` span's shape and the counters."""
+`jit_agg_env`, its `dispatch` span's shape and the counters; and (ISSUE
+36) the lane -> bin vector of a `histogram`/`date_histogram` level kept
+resident beside its segment's image on the one-chip routes."""
 
 import calendar
 import http.client
@@ -302,7 +304,7 @@ HOST_LOOP = {**DISTANCE, "track_total_hits": True}  # not an envelope body
 
 @pytest.mark.parametrize("route,index,body,ran", [
     ("envelope", "nyc_taxis", DISTANCE, "search.agg_env.queries"),
-    ("host_loop", "nyc_taxis", HOST_LOOP, "search.agg_bins.level.table"),
+    ("host_loop", "nyc_taxis", HOST_LOOP, "search.agg_bins.level.resident"),
     ("spmd", "taxis_rows", DISTANCE, "search.spmd_queries"),
 ])
 def test_an_index_that_turns_its_request_cache_off_runs_every_request(
@@ -371,10 +373,14 @@ def test_the_agg_envelope_names_its_stages_its_shape_and_its_levels(
     post(server, "nyc_taxis", DISTANCE)
     post(server, "nyc_taxis", DATES)
     after = counters("search.agg_bins.level.")
-    # histogram > stats gathers through a table; a bare date_histogram
-    # of few buckets at this size closes over its lane bitmasks
-    assert after["search.agg_bins.level.table"] \
-        == before.get("search.agg_bins.level.table", 0) + 1
+    # histogram > stats reads its segment's resident lane -> bin vector
+    # (ISSUE 36: no table gathered through a request); a bare
+    # date_histogram of few buckets at this size closes over its lane
+    # bitmasks
+    assert after["search.agg_bins.level.resident"] \
+        == before.get("search.agg_bins.level.resident", 0) + 1
+    assert after.get("search.agg_bins.level.table", 0) \
+        == before.get("search.agg_bins.level.table", 0)
     assert after["search.agg_bins.level.bits"] \
         == before.get("search.agg_bins.level.bits", 0) + 1
     post(server, "nyc_taxis", {"size": 0, "aggs": {"p": {"terms": {
@@ -404,7 +410,422 @@ def test_the_agg_envelope_names_its_stages_its_shape_and_its_levels(
     stats = next(iter(node.request("GET", "/_nodes/stats")["nodes"]
                       .values()))["telemetry"]["metrics"]["counters"]
     for name in ("search.agg_env.queries", "search.agg_bins.level.rank",
-                 "search.agg_bins.level.table",
+                 "search.agg_bins.level.resident",
                  "search.agg_bins.level.bits",
                  "search.request_cache.bypassed"):
         assert stats[name] >= 1
+
+
+# ------------------- resident lane -> bin vectors on one chip (ISSUE 36)
+#
+# A `histogram`/`date_histogram` level's lane -> bin vector is derived
+# once a (segment image, field, bucketing), kept beside that image
+# (`ShardReader._lane_bins`, search/aggs/lane_bins.py) and read by the
+# envelope's and the host loop's programs as `seg["lane_bins"][slot]`;
+# no table is gathered through, built, packed or uploaded a request.
+
+LANES = ("search.agg_lane_bins.", "search.agg_bins.level.",
+         "search.agg_env.queries", "search.xla_cache_miss")
+
+
+def moved(after, before):
+    return {k.replace("search.", ""): after[k] - before.get(k, 0)
+            for k in after if after[k] != before.get(k, 0)}
+
+
+def distance(interval, **extra):
+    body = json.loads(json.dumps(DISTANCE))
+    body["aggs"]["distance_histo"]["histogram"]["interval"] = interval
+    return {**body, **extra}
+
+
+def by_day(unit="day", lte="21/01/2015"):
+    """`date_histogram_agg` with a sub-aggregation, which keeps the level
+    off the fused root-leaf kind at this size."""
+    body = json.loads(json.dumps(DATES))
+    body["query"]["range"]["dropoff_datetime"]["lte"] = lte
+    body["aggs"]["dropoffs_over_time"] = {
+        "date_histogram": {"field": "dropoff_datetime",
+                           "calendar_interval": unit},
+        "aggs": {"paid": {"sum": {"field": "total_amount"}}}}
+    return body
+
+
+def check_distance_at(resp, docs, interval):
+    total, want = ref_histogram_stats(docs, "trip_distance", float(interval),
+                                      0.0, 50.0, "total_amount")
+    assert resp["hits"]["total"] == {"value": total, "relation": "eq"}
+    got = resp["aggregations"]["distance_histo"]["buckets"]
+    assert [b["key"] for b in got] == [k for k, _, _ in want]
+    for b, (key, n, stats) in zip(got, want):
+        st = b["total_amount_stats"]
+        assert b["doc_count"] == n == st["count"]
+        for name, ref in zip(("min", "max", "avg", "sum"),
+                             stats[1:] if stats else ()):
+            assert close(st[name], ref), (key, name, st[name], ref)
+
+
+def check_by_day(resp, docs):
+    lo, hi = ms("2015-01-01 00:00:00"), ms("2015-01-21 23:59:59") + 999
+    total, want = ref_day_counts(
+        [ms(d["dropoff_datetime"]) for d in docs], lo, hi)
+    assert resp["hits"]["total"] == {"value": total, "relation": "eq"}
+    got = resp["aggregations"]["dropoffs_over_time"]["buckets"]
+    assert [(b["key"], b["doc_count"]) for b in got] == want
+    for b in got:
+        paid = math.fsum(round(d["total_amount"] * 100) / 100 for d in docs
+                         if b["key"] <= ms(d["dropoff_datetime"])
+                         < b["key"] + DAY
+                         and lo <= ms(d["dropoff_datetime"]) <= hi)
+        assert close(b["paid"]["value"], paid)
+
+
+def put_docs(node, index, docs, start=0):
+    lines = []
+    for i, doc in enumerate(docs, start):
+        lines += [json.dumps({"index": {"_index": index, "_id": f"r{i}"}}),
+                  json.dumps(doc)]
+    resp = node.request("POST", "/_bulk", "\n".join(lines) + "\n")
+    assert resp["errors"] is False
+    node.request("POST", f"/{index}/_refresh")
+
+
+@pytest.fixture
+def live():
+    """A node of its own with a writable one-shard index `t`: its
+    segments' memos start empty."""
+    from opensearch_tpu.node import Node
+    node = Node()
+    node.request("PUT", "/t", {"settings": SOURCE_SETTINGS,
+                               "mappings": MAPPING})
+    docs = rides(240, 36)
+    put_docs(node, "t", docs)
+    return node, docs
+
+
+def search(node, body, index="t"):
+    resp = node.request("POST", f"/{index}/_search", body)
+    assert resp["_status"] == 200 and resp["_shards"]["failed"] == 0
+    return resp
+
+
+def the_reader(node, index="t"):
+    (shard,) = node.indices.get(index).shards
+    return shard.reader
+
+
+def memos(reader):
+    return [memo for _, memo in reader._lane_bins.values()]
+
+
+def corpus_bytes():
+    import gc
+    gc.collect()        # readers of nodes other tests dropped
+    return TELEMETRY.device_memory.stats()["classes"]["corpus_columns"][
+        "live_bytes"]
+
+
+@pytest.mark.parametrize("route", ["envelope", "host_loop"])
+def test_a_level_is_derived_once_and_found_by_every_request_after(
+        live, route):
+    """A miss, then hits: every answer is the reference's, the level is
+    counted `resident` a request and `table` never, one vector is held
+    (int32, a lane a lane of the rank column, in the corpus-columns
+    gauge with the image), and the second request compiles nothing."""
+    node, docs = live
+    extra = {"track_total_hits": True} if route == "host_loop" else {}
+    body = distance(2, **extra)
+    reader = the_reader(node)
+    (memo,) = memos(reader)
+    image = corpus_bytes()
+    assert memo.nbytes == 0 and reader.device_bytes == sum(
+        reader._seg_bytes.values())
+    seen = []
+    for n in range(3):
+        before = counters(*LANES)
+        resp = search(node, body)
+        check_distance_at(resp, docs, 2)
+        got = moved(counters(*LANES), before)
+        compiles = got.pop("xla_cache_miss", 0)
+        want = {"agg_lane_bins.hit" if n else "agg_lane_bins.miss": 1,
+                "agg_bins.level.resident": 1}
+        if route == "envelope":
+            want["agg_env.queries"] = 1
+        assert got == want
+        # the derive program and the served one compile with the first
+        # request of a body (unless another test's index had their
+        # shapes); the served executable is the same on the miss and on
+        # the hit: the second request compiles nothing
+        assert compiles == 0 or n != 1
+        (vec,) = memo.vectors.values()
+        seen.append(vec)
+    assert all(v is seen[0] for v in seen)
+    (seg,), ((arrays, meta),) = reader.snapshot()
+    col = seg.numeric_dv["trip_distance"]
+    ranks = arrays["numeric"]["trip_distance"]["val_ords"]
+    assert seen[0].shape == ranks.shape and seen[0].dtype == np.int32
+    # the same integers in the same lanes as the gather a request made
+    want = np.floor(col.unique / 2).astype(np.int64)
+    want = (want - want[0])[col.value_ords]
+    got = np.asarray(seen[0])
+    assert (got[:len(want)] == want).all() and (got[len(want):] == -1).all()
+    assert memo.nbytes == seen[0].nbytes
+    assert corpus_bytes() == image + seen[0].nbytes
+    # the other route's program finds the same vector
+    before = counters(*LANES)
+    search(node, distance(2, **({} if extra else
+                                {"track_total_hits": True})))
+    assert moved(counters("search.agg_lane_bins."), before) \
+        == {"agg_lane_bins.hit": 1}
+
+
+@pytest.mark.parametrize("kind", ["interval", "calendar_interval"])
+def test_another_bucketing_is_another_vector_and_a_fifth_evicts_the_lru(
+        live, kind):
+    from opensearch_tpu.search.aggs.lane_bins import MAX_LANE_BINS
+    node, docs = live
+    if kind == "interval":
+        bodies = [distance(i) for i in (2, 3, 4, 5, 7)]
+    else:
+        bodies = [by_day(u) for u in ("day", "week", "month", "quarter",
+                                      "year")]
+    assert len(bodies) == MAX_LANE_BINS + 1
+    (memo,) = memos(the_reader(node))
+    image = corpus_bytes()
+    before = counters("search.agg_lane_bins.")
+    for n, body in enumerate(bodies[:MAX_LANE_BINS]):
+        for _ in range(2):                      # a miss, then a hit
+            resp = search(node, body)
+        (check_distance_at(resp, docs, (2, 3, 4, 5)[n])
+         if kind == "interval" else n or check_by_day(resp, docs))
+        assert len(memo.vectors) == n + 1
+    first, second = list(memo.vectors)[:2]
+    one = next(iter(memo.vectors.values())).nbytes
+    assert memo.nbytes == MAX_LANE_BINS * one
+    search(node, bodies[0])                     # the first is used again
+    search(node, bodies[-1])                    # a fifth: the second goes
+    assert moved(counters("search.agg_lane_bins."), before) == {
+        "agg_lane_bins.miss": MAX_LANE_BINS + 1,
+        "agg_lane_bins.hit": MAX_LANE_BINS + 1,
+        "agg_lane_bins.evicted": 1}
+    assert len(memo.vectors) == MAX_LANE_BINS
+    assert first in memo.vectors and second not in memo.vectors
+    assert memo.nbytes == MAX_LANE_BINS * one
+    assert corpus_bytes() == image + MAX_LANE_BINS * one
+
+
+def reupload(node, docs):
+    """A genuinely different segment under the id the reader holds."""
+    reader = the_reader(node)
+    svc = node.indices.get("t")
+    b = SegmentBuilder(svc.mapper, reader.segments[0].seg_id)
+    for i, doc in enumerate(docs[:100]):
+        b.add(svc.mapper.parse_document(f"r{i}", doc))
+    reader.update_segment(b.seal())
+    return docs[:100]
+
+
+def remove(node, docs):
+    reader = the_reader(node)
+    reader.remove_segment(reader.segments[0].seg_id)
+    return []
+
+
+def merge(node, docs):
+    more = rides(60, 37)
+    put_docs(node, "t", more, start=len(docs))
+    assert len(the_reader(node).segments) == 2
+    assert node.request("POST", "/t/_forcemerge")["_status"] == 200
+    assert len(the_reader(node).segments) == 1
+    return docs + more
+
+
+@pytest.mark.parametrize("drop", [remove, merge, reupload],
+                         ids=["remove_segment", "merge", "re-upload"])
+def test_the_vectors_leave_with_their_segments_image(live, drop):
+    """Derived from the image's rank column, so gone with it: out of the
+    memo, out of the device-memory gauge, counted evicted; the image
+    that takes its place starts with none and answers for its own
+    documents."""
+    node, docs = live
+    reader = the_reader(node)
+    search(node, distance(2))
+    search(node, by_day())
+    (old,) = memos(reader)
+    assert len(old.vectors) == 2 and old.nbytes > 0
+    assert reader.device_bytes \
+        == sum(reader._seg_bytes.values()) + old.nbytes
+    before = counters("search.agg_lane_bins.")
+    docs = drop(node, docs)
+    assert moved(counters("search.agg_lane_bins."), before) \
+        == {"agg_lane_bins.evicted": 2}
+    assert not old.vectors and old.nbytes == 0
+    assert all(memo is not old and not memo.vectors
+               for memo in memos(reader))
+    assert set(reader._lane_bins) == set(reader._seg_bytes) \
+        == {seg.seg_id for seg in reader.segments}
+    assert reader.device_bytes == sum(reader._seg_bytes.values())
+    if docs:
+        before = counters("search.agg_lane_bins.")
+        check_distance_at(search(node, distance(2)), docs, 2)
+        assert moved(counters("search.agg_lane_bins."), before) \
+            == {"agg_lane_bins.miss": 1}
+
+
+def test_a_delete_leaves_the_vector_and_changes_the_counts(live):
+    """A delete moves `live`, not the bins: the same vector, a hit, and
+    one ride fewer in its bucket."""
+    node, docs = live
+    first = search(node, distance(2))
+    (memo,) = memos(the_reader(node))
+    (vec,) = memo.vectors.values()
+    gone = next(i for i, d in enumerate(docs)
+                if 2 <= d["trip_distance"] < 4 and d["total_amount"] > 0)
+    assert node.request("DELETE", f"/t/_doc/r{gone}")["result"] == "deleted"
+    node.request("POST", "/t/_refresh")
+    before = counters("search.agg_lane_bins.")
+    resp = search(node, distance(2))
+    assert moved(counters("search.agg_lane_bins."), before) \
+        == {"agg_lane_bins.hit": 1}
+    assert list(memo.vectors.values()) == [vec] \
+        and memos(the_reader(node)) == [memo]
+    check_distance_at(resp, docs[:gone] + docs[gone + 1:], 2)
+
+    def counts(r):
+        return {b["key"]: b["doc_count"]
+                for b in r["aggregations"]["distance_histo"]["buckets"]}
+    was, now = counts(first), counts(resp)
+    assert now[2.0] == was[2.0] - 1
+    assert {k: v for k, v in now.items() if k != 2.0} \
+        == {k: v for k, v in was.items() if k != 2.0}
+
+
+@pytest.mark.parametrize("route", ["envelope", "host_loop"])
+def test_every_segment_of_an_index_keeps_its_own_vector(live, route):
+    node, docs = live
+    more = rides(90, 38)
+    put_docs(node, "t", more, start=len(docs))
+    reader = the_reader(node)
+    assert len(reader.segments) == 2
+    # two segments are two rows to the SPMD route, which would take a
+    # body the envelope does not
+    from contextlib import nullcontext
+    from opensearch_tpu.search import spmd
+    extra, way = ({"track_total_hits": True}, spmd.force_host_loop) \
+        if route == "host_loop" else ({}, nullcontext)
+    for n in range(2):
+        before = counters(*LANES)
+        for body, check in ((distance(3, **extra),
+                             lambda r: check_distance_at(r, docs + more, 3)),
+                            (dict(by_day(), **extra),
+                             lambda r: check_by_day(r, docs + more))):
+            with way():
+                check(search(node, body))
+        got = moved(counters(*LANES), before)
+        got.pop("xla_cache_miss", None)
+        got.pop("agg_env.queries", None)
+        # two bodies x two segments: a program, a level and a vector each
+        assert got == {"agg_lane_bins.hit" if n else "agg_lane_bins.miss": 4,
+                       "agg_bins.level.resident": 4}
+    assert [len(memo.vectors) for memo in memos(reader)] == [2, 2]
+    assert [v.shape for memo in memos(reader)
+            for v in memo.vectors.values()] \
+        == [arrays["numeric"][f]["val_ords"].shape
+            for arrays, _ in reader.device
+            for f in ("trip_distance", "dropoff_datetime")]
+
+
+def test_a_range_bucket_rides_its_table_and_an_identity_terms_its_ranks(
+        live):
+    """What the choice rests on is a static fact of the plan: a `range`
+    bucket's bounds may move with every request, so it brings its table
+    (BINS_TABLE) and is no entry of the memo; `terms` on a numeric
+    column reads the rank column itself (BINS_RANK)."""
+    node, docs = live
+    (memo,) = memos(the_reader(node))
+    ranges = [{"to": 2}, {"from": 2, "to": 10}, {"from": 10}]
+    for shift in (0, 1):
+        before = counters(*LANES)
+        resp = search(node, {"size": 0, "aggs": {
+            "far": {"range": {"field": "trip_distance", "ranges": [
+                {k: v + shift for k, v in r.items()} for r in ranges]},
+                "aggs": {"paid": {"sum": {"field": "total_amount"}}}},
+            "seats": {"terms": {"field": "passenger_count"},
+                      "aggs": {"paid": {"sum": {"field": "total_amount"}}}}}})
+        got = moved(counters(*LANES), before)
+        got.pop("xla_cache_miss", None)
+        assert got == {"agg_bins.level.table": 3, "agg_bins.level.rank": 1,
+                       "agg_env.queries": 1}
+        for b, r in zip(resp["aggregations"]["far"]["buckets"], ranges):
+            sel = [d for d in docs
+                   if r.get("from", -1) + shift * ("from" in r)
+                   <= round(d["trip_distance"] * 100) / 100
+                   < r.get("to", 1e9) + shift * ("to" in r)]
+            assert b["doc_count"] == len(sel)
+            assert close(b["paid"]["value"], math.fsum(
+                round(d["total_amount"] * 100) / 100 for d in sel))
+        seats = {b["key"]: b["doc_count"]
+                 for b in resp["aggregations"]["seats"]["buckets"]}
+        assert seats == {n: sum(d["passenger_count"] == n for d in docs)
+                         for n in seats} and sum(seats.values()) == len(docs)
+    assert not memo.vectors
+
+
+def test_a_batch_of_aggregations_shares_one_vector(live):
+    """A B>1 `_msearch` group is one program run over one vector a
+    level (unbatched under `vmap`): one lookup for the group, a level
+    counted an item, every item's answer its own `_search`'s."""
+    node, docs = live
+    bodies = []
+    for lt in (50, 40, 30, 20):
+        body = distance(2)
+        body["query"]["bool"]["filter"]["range"]["trip_distance"]["lt"] = lt
+        bodies.append(body)
+    singles = [search(node, b) for b in bodies]
+    lines = []
+    for b in bodies:
+        lines += [json.dumps({"index": "t"}), json.dumps(b)]
+    payload = "\n".join(lines) + "\n"
+    node.request("POST", "/_msearch", payload)      # whatever compiles
+    (memo,) = memos(the_reader(node))
+    before = counters(*LANES)
+    resp = node.request("POST", "/_msearch", payload)
+    assert moved(counters(*LANES), before) == {
+        "agg_lane_bins.hit": 1, "agg_bins.level.resident": 4,
+        "agg_env.queries": 4}
+    assert len(memo.vectors) == 1
+    for got, want in zip(resp["responses"], singles):
+        assert got["aggregations"] == want["aggregations"]
+        assert got["hits"]["total"] == want["hits"]["total"]
+    # another interval in the same batch is another group: its own
+    # vector, never the first item's
+    mixed = "\n".join([json.dumps({"index": "t"}), json.dumps(distance(2)),
+                       json.dumps({"index": "t"}), json.dumps(distance(5))])
+    resp = node.request("POST", "/_msearch", mixed + "\n")
+    check_distance_at(resp["responses"][0], docs, 2)
+    check_distance_at(resp["responses"][1], docs, 5)
+    assert len(memo.vectors) == 2
+
+
+def test_a_pinned_reader_reads_its_sources_vectors(live):
+    """A scroll or PIT context holds the reader's images, not copies:
+    its aggregating programs find the vectors the reader keeps."""
+    from opensearch_tpu.search.executor import PinnedReader, SearchExecutor
+    node, docs = live
+    want = search(node, distance(2, track_total_hits=True))
+    pinned = SearchExecutor(PinnedReader(the_reader(node)))
+    pinned.request_cache_enabled = False
+    before = counters("search.agg_lane_bins.")
+    got = pinned.search(distance(2, track_total_hits=True))
+    assert moved(counters("search.agg_lane_bins."), before) \
+        == {"agg_lane_bins.hit": 1}
+    assert got["aggregations"] == want["aggregations"]
+    # once the reader has let the image go, the pinned one derives for
+    # itself and keeps nothing
+    remove(node, docs)
+    before = counters("search.agg_lane_bins.")
+    got = pinned.search(distance(2, track_total_hits=True))
+    assert moved(counters("search.agg_lane_bins."), before) \
+        == {"agg_lane_bins.miss": 1}
+    assert got["aggregations"] == want["aggregations"]
+    assert the_reader(node).device_bytes == 0

@@ -405,13 +405,17 @@ def test_the_panel_on_the_miss_and_on_the_hit(served):
             == ref_date_histogram(
                 [d["@timestamp"] for d in docs
                  if r["gte"] <= d["@timestamp"] < r["lt"]], fixed_ms=HOUR)
-        with spmd.force_host_loop():
-            want = node.request("POST", "/logs/_search", body)
-        assert resp["aggregations"] == want["aggregations"]
-        assert resp["hits"]["total"] == want["hits"]["total"]
         assert delta(lane_bins(), before) == dict(
             {"miss": 1, "identity": n + 1}, **({"hit": n} if n else {}))
-        seen.append(the_shard_set()._lane_bins[
+        mark = lane_bins()
+        with spmd.force_host_loop():
+            want = node.request("POST", "/logs/_search", body)
+        # the host loop's rows find or derive their segments' own
+        # vectors, counted by the same class: not this route's
+        before = {k: v + lane_bins()[k] - mark[k] for k, v in before.items()}
+        assert resp["aggregations"] == want["aggregations"]
+        assert resp["hits"]["total"] == want["hits"]["total"]
+        seen.append(the_shard_set().lane_bins.vectors[
             ("@timestamp", "date_histogram", HOUR, 0)])
     # one vector, the same array on the miss and on every hit: int32, a
     # row a row of the image and a lane a lane of the rank column,
@@ -458,12 +462,12 @@ def test_a_hit_builds_no_table(served, monkeypatch):
 def test_another_bucketing_of_the_field_gets_its_own_vector(served):
     """Keyed by the bucketing's scalars, not by the field alone; and the
     memo is bounded: the least recently used vector goes, with its bytes."""
-    from opensearch_tpu.parallel.distributed import MAX_LANE_BINS
+    from opensearch_tpu.search.aggs.lane_bins import MAX_LANE_BINS
     node, server, docs = served
     drop_shard_sets()
     check_response(post(server, moved(BODY, 31)), docs, moved(BODY, 31))
     shard_set = the_shard_set()
-    one = next(iter(shard_set._lane_bins.values())).nbytes
+    one = next(iter(shard_set.lane_bins.vectors.values())).nbytes
     before = lane_bins()
     offsets = [10, 20, 30, 40][:MAX_LANE_BINS]
     for n, minutes in enumerate(offsets):
@@ -473,15 +477,15 @@ def test_another_bucketing_of_the_field_gets_its_own_vector(served):
             check_response(post(server, moved(body, 7 * again)), docs,
                            moved(body, 7 * again), shift=-minutes * 60000)
         assert ("@timestamp", "date_histogram", HOUR, -minutes * 60000) \
-            in shard_set._lane_bins
+            in shard_set.lane_bins.vectors
     # each offset missed once and hit once; the hourly vector without an
     # offset, the least recently used, made room for the last of them
     assert delta(lane_bins(), before) == {
         "miss": len(offsets), "hit": len(offsets), "evicted": 1,
         "identity": 2 * len(offsets)}
-    assert len(shard_set._lane_bins) == MAX_LANE_BINS
+    assert len(shard_set.lane_bins.vectors) == MAX_LANE_BINS
     assert ("@timestamp", "date_histogram", HOUR, 0) \
-        not in shard_set._lane_bins
+        not in shard_set.lane_bins.vectors
     assert resident_bytes() == shard_set.nbytes + MAX_LANE_BINS * one
 
 
@@ -494,20 +498,20 @@ def test_a_dropped_shard_set_takes_its_vectors_along(served, notes,
     drop_shard_sets()
     post(server, moved(BODY, 41))
     old = the_shard_set()
-    assert len(old._lane_bins) == 1 and resident_bytes() > old.nbytes
+    assert len(old.lane_bins.vectors) == 1 and resident_bytes() > old.nbytes
     monkeypatch.setattr(spmd, "_MAX_SHARD_SETS", 1)
     before = lane_bins()
     notes.request("POST", "/notes/_search",
                   {"query": {"match": {"body": "alpha"}}})
     new = the_shard_set()
-    assert new is not old and not old._lane_bins
+    assert new is not old and not old.lane_bins.vectors
     assert resident_bytes() == new.nbytes
     assert delta(lane_bins(), before) == {"evicted": 1}
     post(server, moved(BODY, 42))       # evicts `new`, builds the set again
     assert delta(lane_bins(), before) == {"evicted": 1, "miss": 1,
                                           "identity": 1}
     assert resident_bytes() == the_shard_set().nbytes \
-        + sum(v.nbytes for v in the_shard_set()._lane_bins.values())
+        + sum(v.nbytes for v in the_shard_set().lane_bins.vectors.values())
 
 
 DAY = 86400000
@@ -571,14 +575,18 @@ def test_rows_that_differ_in_whether_their_table_is_the_identity(stamps):
     with spmd.force_host_loop():
         want = node.request("POST", "/days/_search", BY_DAY)
     assert got["aggregations"] == want["aggregations"]
-    assert delta(lane_bins(), lanes) == {"miss": 1}
+    # the host loop's rows are programs of their own: the row whose
+    # table is not the identity derived its segment's vector, the other
+    # reads its rank column
+    assert delta(lane_bins(), lanes) == {"miss": 2}
 
 
 @pytest.mark.parametrize("stamps", list(MIXED_ROWS.values()),
                          ids=list(MIXED_ROWS))
-def test_aligned_rows_compiled_for_one_chip_carry_their_own_tables(stamps):
-    """`align_agg_plans` on rows that carry their tables among their
-    inputs (a compile for one chip): the identity row's is the identity
+def test_aligned_rows_build_their_own_tables_when_asked(stamps):
+    """`align_agg_plans` on rows compiled across rows: none carries a
+    table among its inputs, the identity row names BINS_TABLE like the
+    other, and the table it builds for the derivation is the identity
     over its own ranks, padded like any other."""
     from opensearch_tpu.parallel.distributed import align_agg_plans
     from opensearch_tpu.search.aggs.engine import BINS_TABLE, compile_aggs
@@ -591,14 +599,13 @@ def test_aligned_rows_compiled_for_one_chip_carry_their_own_tables(stamps):
         (seg,), ((_, meta),) = reader.segments, reader.device
         rows.append(compile_aggs(
             parse_aggs(BY_DAY["aggs"]), svc.mapper, seg, meta,
-            Compiler(svc.mapper, reader.stats())))
-    assert "table" not in rows[0][0].inputs
+            Compiler(svc.mapper, reader.stats()), allow_fused=False))
     align_agg_plans(rows)
     for (plan,), row in zip(rows, stamps):
-        assert plan.static[3] == BINS_TABLE
-        table = plan.inputs["table"]
+        assert plan.static[3] == BINS_TABLE and "table" not in plan.inputs
+        table = plan.table_of()
         assert table.shape == (max(8, 1 << (len(set(row)) - 1).bit_length()),)
-    identity = rows[0][0].inputs["table"]
+    identity = rows[0][0].table_of()
     n = len(stamps[0])
     assert (identity[:n] == np.arange(n)).all() and (identity[n:] == -1).all()
 
@@ -612,7 +619,7 @@ def test_a_range_bucket_keeps_its_table_and_the_memo_its_vectors(served):
     drop_shard_sets()
     post(server, moved(BODY, 61))
     shard_set = the_shard_set()
-    keys, before = list(shard_set._lane_bins), lane_bins()
+    keys, before = list(shard_set.lane_bins.vectors), lane_bins()
     for n in range(6):
         body = {"size": 0, "aggs": {"spans": {
             "date_range": {"field": "@timestamp", "ranges": [
@@ -628,7 +635,7 @@ def test_a_range_bucket_keeps_its_table_and_the_memo_its_vectors(served):
             want = node.request("POST", "/logs/_search", body)
         assert got["aggregations"] == want["aggregations"]
     assert the_shard_set() is shard_set
-    assert list(shard_set._lane_bins) == keys
+    assert list(shard_set.lane_bins.vectors) == keys
     assert delta(lane_bins(), before) == {}
 
 
