@@ -24,6 +24,7 @@ Layout:
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field as dc_field
 from typing import Dict, Optional, Tuple
 
@@ -33,6 +34,7 @@ import jax.numpy as jnp
 
 from opensearch_tpu.index.segment import (Segment, block_score_bounds,
                                           pad_bucket, posting_norms)
+from opensearch_tpu.telemetry import TELEMETRY
 
 INT32_MAX = np.int32(2 ** 31 - 1)
 _F32_MAX = float(np.finfo(np.float32).max)
@@ -105,7 +107,42 @@ class DeviceSegmentMeta:
 
 
 def upload_segment(seg: Segment, to_device: bool = True):
-    """Build the device pytree (dict of jnp arrays) + static meta for a segment."""
+    """Build the device pytree (dict of jnp arrays) + static meta for a
+    segment. An upload is an `install.upload_segment` of the span ring's
+    process track (`_note_install`); the host image alone (`to_device`
+    False: a row of an SPMD shard set, which records its own
+    `install.shard_set`) is none."""
+    t0 = time.monotonic()
+    arrays, meta = _host_image(seg)
+    if to_device:
+        t1 = time.monotonic()
+        arrays = _tree_to_jnp(arrays)
+        _note_install(meta, tree_nbytes(arrays), t0, t1, time.monotonic())
+    return arrays, meta
+
+
+def _note_install(meta: "DeviceSegmentMeta", nbytes: int, t0: float,
+                  t1: float, t2: float) -> None:
+    """One segment's image put on the device, on the process track of
+    the always-on span ring (telemetry/tracer.py; what the benchmark's
+    `install_*_s` read): `install.upload_segment` (`segment`, `d_pad`,
+    `nbytes`) from `t0` to `t2`, and below it `install.host_pad` (to
+    `t1`: the padded host copy of every column, with the passes that
+    derive `post_norm`, the block bounds and the rank extremes) and
+    `install.device_put` (from `t1`: the calls that hand the arrays to
+    the device; what the runtime still has in flight when they return
+    is in neither)."""
+    ring = TELEMETRY.tracer.spans
+    upload_id = ring.process(
+        "install.upload_segment", t0, t2,
+        {"segment": meta.seg_id, "d_pad": meta.d_pad, "nbytes": nbytes})
+    ring.process("install.host_pad", t0, t1, None, upload_id)
+    ring.process("install.device_put", t1, t2, None, upload_id)
+
+
+def _host_image(seg: Segment):
+    """The padded host arrays (numpy) of a segment's device pytree, and
+    its static meta."""
     d_pad = pad_bucket(max(seg.num_docs, 1))
     nb = seg.post_docs.shape[0]
     nb_pad = pad_bucket(nb, minimum=8)
@@ -236,9 +273,6 @@ def upload_segment(seg: Segment, to_device: bool = True):
             compression = "none"
         arrays["rank_vectors"][fname] = entry
         rank_vector_fields.append((fname, col.t_bucket, compression))
-
-    if to_device:
-        arrays = _tree_to_jnp(arrays)
 
     meta = DeviceSegmentMeta(
         seg_id=seg.seg_id,
@@ -393,10 +427,13 @@ def publish_segment(seg: Segment, to_device: bool = True):
     if not DELTA_PUBLISH or not to_device:
         arrays, meta = upload_segment(seg, to_device=to_device)
         return arrays, meta, tree_nbytes(arrays)
-    host, meta = upload_segment(seg, to_device=False)
+    t0 = time.monotonic()
+    host, meta = _host_image(seg)
     spec = _compact_spec(seg, meta)
     transferred = [0]
+    t1 = time.monotonic()
     arrays = _delta_tree(host, spec, transferred)
+    _note_install(meta, transferred[0], t0, t1, time.monotonic())
     return arrays, meta, transferred[0]
 
 

@@ -345,7 +345,8 @@ class HbmShardSet:
     """
 
     def __init__(self, searcher: "DistributedSearcher",
-                 shard_arrays: Sequence[Dict], metas: Sequence[Any]):
+                 shard_arrays: Sequence[Dict], metas: Sequence[Any],
+                 started: Optional[float] = None):
         if not shard_arrays or len(shard_arrays) != len(metas):
             raise ValueError(
                 f"{len(shard_arrays)} shard trees / {len(metas)} metas")
@@ -360,14 +361,30 @@ class HbmShardSet:
         self.rows_per_dev = rpd
         self.mesh = searcher.mesh
         self.meta = canonical_meta(metas)
+        t0 = time.monotonic()
         stack = pad_stack_trees(shard_arrays)
+        t1 = time.monotonic()
         self.seg_stack = _device_put_sharded_tree(
             stack, searcher.mesh, searcher.axis)
+        t2 = time.monotonic()
         self.shapes = _tree_shapes(self.seg_stack)
         self.nbytes = sum(
             int(np.prod(v.shape)) * np.dtype(v.dtype).itemsize
             for _, v in jax.tree_util.tree_flatten_with_path(
                 self.seg_stack)[0])
+        # the build on the process track of the always-on span ring
+        # (telemetry/tracer.py; the benchmark's `install_shard_set_*_s`):
+        # it runs inside the index's first request and belongs to the
+        # index. `started`: when the caller began the rows' host images
+        ring = TELEMETRY.tracer.spans
+        set_id = ring.process(
+            "install.shard_set", t0 if started is None else started, t2,
+            {"rows": self.n_rows, "devices": n, "nbytes": self.nbytes})
+        if started is not None:
+            ring.process("install.shard_set.host_images", started, t0,
+                         None, set_id)
+        ring.process("install.shard_set.stack", t0, t1, None, set_id)
+        ring.process("install.shard_set.device_put", t1, t2, None, set_id)
         # the resident lane -> bin vectors of this set's rows
         # (`resident_lane_bins`): they live and die with the set, so a
         # refresh's new set starts with none
@@ -578,9 +595,13 @@ class DistributedSearcher:
             return fn(stack, col["doc_ids"], col["val_ords"])
 
     def build_shard_set(self, shard_arrays: Sequence[Dict],
-                        metas: Sequence[Any]) -> HbmShardSet:
-        """Upload the shard segments to HBM once; reuse across queries."""
-        return HbmShardSet(self, shard_arrays, metas)
+                        metas: Sequence[Any],
+                        started: Optional[float] = None) -> HbmShardSet:
+        """Upload the shard segments to HBM once; reuse across queries.
+        `started`: the `time.monotonic()` at which the caller began to
+        build `shard_arrays` (the set's `install.shard_set` span then
+        holds that part too)."""
+        return HbmShardSet(self, shard_arrays, metas, started)
 
     def search(self, shard_payloads: List[Tuple[Dict, List[Dict], Any]],
                plan: Plan, k: int, min_score: float = float(NEG_INF),
@@ -634,10 +655,11 @@ class DistributedSearcher:
         all zeros unless block-max pruning was admitted — ISSUE 20).
 
         `marks`, where given, gets the clock reads the caller's spans
-        are made of (`time.monotonic()`): `dispatch` = (first literal
-        upload, the jit call's return, bytes uploaded, the executable's
-        `exec_info`) and `device_wait` = (start, end, bytes) of the
-        blocking pull of the result page.
+        are made of (`time.monotonic()`): `stacked` (the literals
+        stacked on the host, before the wait for the dispatch lock),
+        `dispatch` = (first literal upload, the jit call's return, bytes
+        uploaded, the executable's `exec_info`) and `device_wait` =
+        (start, end, bytes) of the blocking pull of the result page.
 
         `lane_bins`: what `resident_lane_bins` returned for these
         `agg_plans`; already on the mesh, the program reads them as
@@ -671,6 +693,7 @@ class DistributedSearcher:
         ledger = TELEMETRY.ledger
         scope = ledger.current()
         accounting = ledger.enabled or scope is not None
+        t_stacked = time.monotonic()
         with ledger.attributed():
             with _DISPATCH_LOCK:
                 t_dispatch = time.monotonic()
@@ -763,6 +786,7 @@ class DistributedSearcher:
             + pruned_rows.nbytes + sum(
             a.nbytes for a in jax.tree_util.tree_leaves(agg_outs))
         if marks is not None:
+            marks["stacked"] = t_stacked
             marks["dispatch"] = (t_dispatch, t_enqueued, literal_bytes,
                                  getattr(fn, "exec_info", None))
             marks["device_wait"] = (t_enqueued, time.monotonic(), nb)

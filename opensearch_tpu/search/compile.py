@@ -16,6 +16,7 @@ from __future__ import annotations
 import fnmatch
 import math
 import re
+import time
 from dataclasses import dataclass, field as dc_field, fields as dc_fields
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -608,12 +609,29 @@ def _match_all(boost: float) -> Plan:
     return Plan("match_all", inputs={"boost": _f32(boost)})
 
 
+# `compile.bundle` and `compile.text_clause` spans one wave's compiler
+# records: a B=1 request's bundle and its clauses, and the first few of
+# a cold `_msearch` of hundreds of bodies, whose every miss is counted
+# all the same (`msearch.template.bundle_misses`)
+COMPILE_SPANS_A_WAVE = 8
+
+
 class Compiler:
     """Compiles one parsed query for one segment of a shard."""
 
-    def __init__(self, mapper: MapperService, stats: ShardStats):
+    def __init__(self, mapper: MapperService, stats: ShardStats,
+                 spans=None):
         self.mapper = mapper
         self.stats = stats
+        # the always-on span ring (telemetry/tracer.py SpanRing), given
+        # by a caller that compiles under an open span of its own (the
+        # envelope's `compile.bundle`): a text clause planned here, not
+        # taken from the clause memo, is a `compile.text_clause` below
+        # it, while the wave's `COMPILE_SPANS_A_WAVE` last (`span_ring`).
+        # None: nothing is recorded (the host loop, the SPMD rows, whose
+        # open span is `rest.search`, read for its self time)
+        self.spans = spans
+        self.spans_left = COMPILE_SPANS_A_WAVE
         # per-query memo for cross-segment parent-join scans (one Compiler
         # instance serves all segment compiles of one request)
         self._join_cache: Dict[Any, Any] = {}
@@ -621,6 +639,15 @@ class Compiler:
         # per segment by the executor; None = no caching (percolator,
         # validate, SPMD batch path)
         self.filter_ctx = None
+
+    def span_ring(self):
+        """The span ring, for one more compile span of this wave; None
+        where none was given or the wave has recorded its
+        `COMPILE_SPANS_A_WAVE`."""
+        if self.spans is None or self.spans_left <= 0:
+            return None
+        self.spans_left -= 1
+        return self.spans
 
     # ------------------------------------------------------------ entry
     def compile(self, node: dsl.QueryNode, seg: Segment,
@@ -807,6 +834,8 @@ class Compiler:
         cached = self.stats.memo.get(memo_key)
         if cached is not None:
             return _counted_text_plan(cached)
+        ring = self.span_ring()
+        t_clause = time.monotonic() if ring is not None else 0.0
         ft = self.mapper.get_field(field)
         has_norms = ft is not None and ft.is_text \
             and meta.norm_row(field) is not None
@@ -862,6 +891,9 @@ class Compiler:
                                     score_only),
                     inputs=inputs, scan_blocks=len(ids))
         self.stats.memo[memo_key] = plan    # RotatingMemo bounds itself
+        if ring is not None:
+            ring.child("compile.text_clause", t_clause, time.monotonic(),
+                       {"blocks": len(ids), "terms": len(weighted_terms)})
         return _counted_text_plan(plan)
 
     def _blockmax_scale(self, seg: Segment, field: str, k1: float,

@@ -865,13 +865,16 @@ def _execute_search_impl(executors: List, body: Optional[dict],
         "_shards": shards_block,
         "hits": hits_block,
     }
+    t_aggs0 = t_aggs1 = 0.0     # the reads of `spmd.reduce.reduce_aggs`
     if agg_nodes:
         with _PhaseTimer(trace, phases, "reduce", op="aggs"):
             try:
                 if faults.ENABLED:
                     faults.fire("reduce.aggs")
+                t_aggs0 = time.monotonic()
                 aggregations = reduce_aggs(decoded_partials)
                 apply_pipelines(agg_nodes, aggregations)
+                t_aggs1 = time.monotonic()
             except OpenSearchTpuError:
                 raise               # already a clean typed error
             except Exception as e:  # except-ok: wraps into typed SearchPhaseExecutionError -- never a raw 500
@@ -961,7 +964,11 @@ def _execute_search_impl(executors: List, body: Optional[dict],
         # reduce_aggs over the rows' partials) and `respond` (the rest
         # of the response, to this return)
         ring = TELEMETRY.tracer.spans
-        ring.child("spmd.reduce", spmd_served_box[0], t_reduced)
+        reduce_id = ring.child("spmd.reduce", spmd_served_box[0],
+                               t_reduced)
+        if reduce_id and t_aggs1:
+            ring.child("spmd.reduce.reduce_aggs", t_aggs0, t_aggs1,
+                       {"rows": len(decoded_partials)}, reduce_id)
         ring.child("respond", t_reduced, time.monotonic())
     return resp
 

@@ -1260,6 +1260,39 @@ def _bundle_nbytes(flats) -> int:
                for d in f for v in d.values())
 
 
+def _rendered_buckets(reduced: Dict[int, dict]) -> int:
+    """Top-level buckets in a wave's reduced aggregations
+    (`respond.reduce_aggs`' `buckets`)."""
+    return sum(len(agg["buckets"]) for aggs in reduced.values()
+               for agg in aggs.values()
+               if isinstance(agg, dict) and "buckets" in agg)
+
+
+def _timed_bundle(compiler, memo: str, build):
+    """`build()`, a bundle compile, under its `compile.bundle` span in
+    the always-on ring (`memo`: `miss`, or `extend` for a carried
+    bundle's tail; `nbytes`: the flattened inputs it made): the open
+    span while it runs, so that the compiler's `compile.text_clause`
+    hangs below it, closed on every exit. Where the wave's compiler
+    has no ring (no wave span: a direct library caller) or has
+    recorded its `COMPILE_SPANS_A_WAVE`: `build()` alone."""
+    ring = compiler.span_ring()
+    if ring is None:
+        return build()
+    trace, sid, parent = ring.enter()
+    t0 = time.monotonic()
+    nbytes = 0
+    try:
+        bundle = build()
+        nbytes = _bundle_nbytes(bundle[1])
+        return bundle
+    finally:
+        trace.spans.append((sid, parent, "compile.bundle", t0,
+                            time.monotonic(),
+                            {"memo": memo, "nbytes": nbytes}))
+        ring.leave(trace, parent)
+
+
 def _item_error_untyped(e: Exception) -> dict:
     """Per-item wrapper for exceptions with no OpenSearchTpuError typing:
     reported as the 500-class failure it is (not relabeled 400 — a raw
@@ -4028,7 +4061,10 @@ class SearchExecutor:
         # -> finish): a concurrent refresh publishing mid-wave must not
         # re-pair seg_i between the compiled flats and the device arrays
         stats, segments, device = self.reader.stats_snapshot()
-        compiler = Compiler(self.reader.mapper, stats)
+        # a traced wave's compiler records `compile.text_clause` under
+        # the `compile.bundle` open when it plans one
+        compiler = Compiler(self.reader.mapper, stats,
+                            spans=_SPANS if span is not None else None)
         mapper_version = getattr(self.reader.mapper, "version", 0)
 
         def _general_fallback(i, body):
@@ -4038,127 +4074,150 @@ class SearchExecutor:
             _run_item_isolated(responses, i, raise_item_errors,
                                lambda: self.search(body, _direct=True))
 
-        for entry in batchable:
-            i, body, node, size, from_, min_score = entry
-            tpl = node if isinstance(node, dsl.QueryTemplate) else None
-            agg_spec = body.get("aggs") or body.get("aggregations")
-            bundle = bkey = agg_json = None
-            if tpl is not None:
-                try:
-                    agg_json = (json.dumps(agg_spec, sort_keys=True,
-                                           default=str) if agg_spec
-                                else None)
-                except Exception:  # except-ok: per-item isolation -- e.g. mixed-type agg keys; the general path owns the typed error
-                    # e.g. mixed-type agg keys breaking sort_keys: the
-                    # general path owns the proper error, per item
-                    _general_fallback(i, body)
-                    continue
-                # gate in the key: bundles hold compiled plans, and a
-                # blockmax flip changes plan inputs (tid/bscale) — a
-                # stale-gate bundle would prune (or not) the wrong way
-                bkey = ("qenv", mapper_version, tpl.sig, tpl.literals,
-                        agg_json, _bm25.BLOCKMAX)
-                bundle = stats.memo.get(bkey)
-                if isinstance(bundle, _PartialBundle):
-                    # pure-append carry (ISSUE 16): compile only the
-                    # appended tail segments, re-store the completed
-                    # bundle (two threads racing here duplicate one
-                    # tail compile, harmlessly — last store wins)
+        # `envelope.compile_group` is the open span of the compile loop
+        # (the ring's own enter / leave): what the loop records names it
+        # as parent, `compile.bundle` for a body the bundle memo does not
+        # hold, under that the compiler's `compile.text_clause`, and
+        # `compile.scan_note`; it closes on every exit, with the read
+        # `ph["compile_group"]` ends on
+        cg = _SPANS.enter() if span is not None else None
+        try:
+            for entry in batchable:
+                i, body, node, size, from_, min_score = entry
+                tpl = node if isinstance(node, dsl.QueryTemplate) else None
+                agg_spec = body.get("aggs") or body.get("aggregations")
+                bundle = bkey = agg_json = None
+                if tpl is not None:
                     try:
-                        bundle = self._extend_msearch_bundle(
-                            compiler, stats, tpl, body, agg_spec,
-                            agg_json, bundle, (segments, device))
-                    except Exception:  # except-ok: per-item isolation -- tail-compile failure falls back to the general path per item
+                        agg_json = (json.dumps(agg_spec, sort_keys=True,
+                                               default=str) if agg_spec
+                                    else None)
+                    except Exception:  # except-ok: per-item isolation -- e.g. mixed-type agg keys; the general path owns the typed error
+                        # e.g. mixed-type agg keys breaking sort_keys: the
+                        # general path owns the proper error, per item
                         _general_fallback(i, body)
                         continue
-                    cost = _bundle_nbytes(bundle[1])
-                    if cost <= _BUNDLE_MEMO_MAX_ENTRY_BYTES:
-                        stats.memo.set(bkey, bundle, cost=cost)
-            bundle_hit = bundle is not None
-            if bundle is None:
-                if tpl is not None:
-                    _BUNDLE_MISSES.inc()
-                try:
-                    bundle = self._compile_msearch_bundle(
-                        compiler, stats, tpl,
-                        None if tpl is not None else node, body, agg_spec,
-                        agg_json, snapshot=(segments, device))
-                except Exception:  # except-ok: per-item isolation -- compile failure falls back to the general path per item
-                    _general_fallback(i, body)
-                    continue
-                if bkey is not None:
-                    # bundles hold flattened device inputs — charge their
-                    # bytes against the memo's byte budget, and keep
-                    # outliers (a single huge high-cardinality filter)
-                    # out entirely rather than letting one entry evict a
-                    # whole generation's working set
-                    cost = _bundle_nbytes(bundle[1])
-                    if cost <= _BUNDLE_MEMO_MAX_ENTRY_BYTES:
-                        stats.memo.set(bkey, bundle, cost=cost)
-            else:
-                _BUNDLE_HITS.inc()
-            (plans, flats, struct, shape_sig, agg_sig, agg_plans_per_seg,
-             agg_nodes, all_none) = bundle
-            # no tie overfetch needed: per-segment top-k by score with
-            # doc-asc tie-break (lax.top_k picks the lowest index) merges
-            # to the exact global page for score-sorted queries; size=0
-            # (agg/count-only) requests skip hit selection entirely
-            k = 0 if from_ + size == 0 else max(from_ + size, 10)
-            if all_none:
-                if agg_nodes:
-                    # empty-match WITH aggs still owes fully-shaped empty
-                    # agg structures — the general path builds those
-                    _general_fallback(i, body)
+                    # gate in the key: bundles hold compiled plans, and a
+                    # blockmax flip changes plan inputs (tid/bscale) — a
+                    # stale-gate bundle would prune (or not) the wrong way
+                    bkey = ("qenv", mapper_version, tpl.sig, tpl.literals,
+                            agg_json, _bm25.BLOCKMAX)
+                    bundle = stats.memo.get(bkey)
+                    if isinstance(bundle, _PartialBundle):
+                        # pure-append carry (ISSUE 16): compile only the
+                        # appended tail segments, re-store the completed
+                        # bundle (two threads racing here duplicate one
+                        # tail compile, harmlessly — last store wins)
+                        try:
+                            bundle = _timed_bundle(
+                                compiler, "extend",
+                                lambda: self._extend_msearch_bundle(
+                                    compiler, stats, tpl, body, agg_spec,
+                                    agg_json, bundle, (segments, device)))
+                        except Exception:  # except-ok: per-item isolation -- tail-compile failure falls back to the general path per item
+                            _general_fallback(i, body)
+                            continue
+                        cost = _bundle_nbytes(bundle[1])
+                        if cost <= _BUNDLE_MEMO_MAX_ENTRY_BYTES:
+                            stats.memo.set(bkey, bundle, cost=cost)
+                bundle_hit = bundle is not None
+                if bundle is None:
+                    if tpl is not None:
+                        _BUNDLE_MISSES.inc()
+                    try:
+                        bundle = _timed_bundle(
+                            compiler, "miss",
+                            lambda: self._compile_msearch_bundle(
+                                compiler, stats, tpl,
+                                None if tpl is not None else node, body,
+                                agg_spec, agg_json,
+                                snapshot=(segments, device)))
+                    except Exception:  # except-ok: per-item isolation -- compile failure falls back to the general path per item
+                        _general_fallback(i, body)
+                        continue
+                    if bkey is not None:
+                        # bundles hold flattened device inputs — charge their
+                        # bytes against the memo's byte budget, and keep
+                        # outliers (a single huge high-cardinality filter)
+                        # out entirely rather than letting one entry evict a
+                        # whole generation's working set
+                        cost = _bundle_nbytes(bundle[1])
+                        if cost <= _BUNDLE_MEMO_MAX_ENTRY_BYTES:
+                            stats.memo.set(bkey, bundle, cost=cost)
                 else:
-                    # no term matched any segment: answer host-side, zero
-                    # device work (the can-match pre-filter analog)
-                    responses[i] = _base_response(
-                        int((time.monotonic() - start) * 1000), 0, None,
-                        [])
-                    if ins_items is not None:
-                        label, kind = _item_shape(node, body)
-                        ins_items[i] = {
-                            "label": label, "kind": kind, "posting": 0,
-                            "dense": 0, "grouped": False,
-                            "warm": bundle_hit,
-                            "interned": tpl is not None}
-                continue
-            compiled[i] = plans
-            flats_by_i[i] = flats
-            if agg_nodes:
-                agg_by_i[i] = agg_plans_per_seg
-                agg_nodes_by_i[i] = agg_nodes
-            groups.setdefault((struct, agg_sig, shape_sig,
-                               min(k, 1 << 16)), []).append(i)
-            # per-item posting/dense bytes from the compiled plans —
-            # the kernel split mirrors _envelope_runner's decision
-            # (candidate-buffer for plain text clauses within the lane
-            # budget, dense otherwise), so the heat map's kernel mix
-            # reflects what actually dispatches. One attribute read
-            # per warm (memoized) plan, no per-lane work, no lock.
-            n_scan0 = len(_scan_per_query)
-            _scan_accumulate_item(device, plans, _scan_rows,
-                                  _scan_per_query)
-            _scan_posting_by_i[i] = _scan_per_query[-1][0] \
-                if len(_scan_per_query) > n_scan0 else 0
-            if ins_items is not None:
-                # the per-item scan join (ISSUE 15): the SAME tuple the
-                # always-on heat map just accumulated, so per-shape
-                # totals conserve byte-exactly against telemetry.scan
-                sp, sd = _scan_per_query[-1] \
-                    if len(_scan_per_query) > n_scan0 else (0, 0)
-                label, kind = _item_shape(node, body)
-                ins_items[i] = {"label": label, "kind": kind,
-                                "posting": sp, "dense": sd,
-                                "grouped": True, "warm": bundle_hit,
+                    _BUNDLE_HITS.inc()
+                (plans, flats, struct, shape_sig, agg_sig, agg_plans_per_seg,
+                 agg_nodes, all_none) = bundle
+                # no tie overfetch needed: per-segment top-k by score with
+                # doc-asc tie-break (lax.top_k picks the lowest index) merges
+                # to the exact global page for score-sorted queries; size=0
+                # (agg/count-only) requests skip hit selection entirely
+                k = 0 if from_ + size == 0 else max(from_ + size, 10)
+                if all_none:
+                    if agg_nodes:
+                        # empty-match WITH aggs still owes fully-shaped empty
+                        # agg structures — the general path builds those
+                        _general_fallback(i, body)
+                    else:
+                        # no term matched any segment: answer host-side, zero
+                        # device work (the can-match pre-filter analog)
+                        responses[i] = _base_response(
+                            int((time.monotonic() - start) * 1000), 0, None,
+                            [])
+                        if ins_items is not None:
+                            label, kind = _item_shape(node, body)
+                            ins_items[i] = {
+                                "label": label, "kind": kind, "posting": 0,
+                                "dense": 0, "grouped": False,
+                                "warm": bundle_hit,
                                 "interned": tpl is not None}
+                    continue
+                compiled[i] = plans
+                flats_by_i[i] = flats
+                if agg_nodes:
+                    agg_by_i[i] = agg_plans_per_seg
+                    agg_nodes_by_i[i] = agg_nodes
+                groups.setdefault((struct, agg_sig, shape_sig,
+                                   min(k, 1 << 16)), []).append(i)
+                # per-item posting/dense bytes from the compiled plans —
+                # the kernel split mirrors _envelope_runner's decision
+                # (candidate-buffer for plain text clauses within the lane
+                # budget, dense otherwise), so the heat map's kernel mix
+                # reflects what actually dispatches. One attribute read
+                # per warm (memoized) plan, no per-lane work, no lock.
+                n_scan0 = len(_scan_per_query)
+                _scan_accumulate_item(device, plans, _scan_rows,
+                                      _scan_per_query)
+                _scan_posting_by_i[i] = _scan_per_query[-1][0] \
+                    if len(_scan_per_query) > n_scan0 else 0
+                if ins_items is not None:
+                    # the per-item scan join (ISSUE 15): the SAME tuple the
+                    # always-on heat map just accumulated, so per-shape
+                    # totals conserve byte-exactly against telemetry.scan
+                    sp, sd = _scan_per_query[-1] \
+                        if len(_scan_per_query) > n_scan0 else (0, 0)
+                    label, kind = _item_shape(node, body)
+                    ins_items[i] = {"label": label, "kind": kind,
+                                    "posting": sp, "dense": sd,
+                                    "grouped": True, "warm": bundle_hit,
+                                    "interned": tpl is not None}
 
-        from opensearch_tpu.telemetry.scan import SCAN
-        SCAN.note_batch(self.reader.index_name,
-                        str(getattr(self.reader, "shard_id", 0)),
-                        _scan_rows, _scan_per_query)
+            from opensearch_tpu.telemetry.scan import SCAN
+            _t_scan = time.monotonic()
+            SCAN.note_batch(self.reader.index_name,
+                            str(getattr(self.reader, "shard_id", 0)),
+                            _scan_rows, _scan_per_query)
+            if cg is not None:
+                _SPANS.child("compile.scan_note", _t_scan,
+                             time.monotonic())
+        finally:
+            _t_pack = time.monotonic()
+            if cg is not None:
+                cg[0].spans.append((
+                    cg[1], cg[2], "envelope.compile_group", _t, _t_pack,
+                    (_wave_attrs, span[3], span[4])))
+                _SPANS.leave(cg[0], cg[2])
         entry_by_i = {e[0]: e for e in batchable}
-        _t_pack = time.monotonic()
         ph["compile_group"] += _t_pack - _t
         # `dispatch` in the ring: its id is the open span for the loop
         # below, so a compile that a first call pays lands under it
@@ -4308,9 +4367,7 @@ class SearchExecutor:
             trace, eid, did, wave_idx, wave_tids = span
             trace.top = span_top
             attrs = (_wave_attrs, wave_idx, wave_tids)
-            spans = [(did + 1, eid, "envelope.compile_group", _t,
-                      _t_pack, attrs),
-                     (did + 2, eid, "envelope.pack", _t_pack,
+            spans = [(did + 2, eid, "envelope.pack", _t_pack,
                       _t_dispatch or _t_end, attrs)]
             if _t_dispatch:
                 spans.append((did, eid, "dispatch", _t_dispatch, _t_end,
@@ -4321,6 +4378,7 @@ class SearchExecutor:
                 "span": span,
                 "pending": pending, "agg_by_i": agg_by_i,
                 "agg_nodes_by_i": agg_nodes_by_i, "dead": dead,
+                "raise_item_errors": raise_item_errors,
                 "staging": staging,
                 "wave_buffer_bytes": wave_buffer_bytes,
                 # per-item shape meta for the insights note pass
@@ -4421,6 +4479,13 @@ class SearchExecutor:
                 (_wait_attrs, span[3], span[4], fetch_stats[0],
                  int(len(pending) > 1))))
         _t = _t_got
+        # `respond`'s children in the ring, each from two clock reads:
+        # `respond.unpack` and `respond.decode_aggs` a fetched program,
+        # then one `respond.reduce_aggs` and one `respond.render` a
+        # wave. This half may run on the collector's thread, so they
+        # are put under `respond`'s id (`span[2] + 4`) here, not under
+        # a thread's open span
+        kids = [] if span is not None else None
         _release_wave_gauges(state)
         if scope is not None:
             _ledger_packed_rows(scope, pending, fetched, fetch_stats[0],
@@ -4435,6 +4500,7 @@ class SearchExecutor:
                                                                    fetched):
             if packed is None:
                 continue            # this program's items are dead
+            _t_unpack = time.monotonic()
             packed = np.asarray(packed)
             scores_b, idx_b, total_b = unpack_batched_result(
                 packed[:, :2 * k_seg + 1], k_seg)
@@ -4457,11 +4523,22 @@ class SearchExecutor:
             for row, i in enumerate(idxs):
                 per_query_total[i] += totals[row]
                 per_query_segs[i].append((seg_i, scores_b[row], idx_b[row]))
-                if out_layout is not None:
+            _t_decode = time.monotonic()
+            if kids is not None:
+                kids.append(("respond.unpack", _t_unpack, _t_decode, None))
+            if out_layout is not None:
+                for row, i in enumerate(idxs):
                     outs = _decode_agg_row(packed[row, 2 * k_seg + 1:],
                                            out_layout)
                     per_query_decoded[i].append(
                         decode_outputs(agg_by_i[i][seg_i], outs))
+                if kids is not None:
+                    # `buckets`: the bins of every partial array a row
+                    # carries back (the `bins` of the dispatch's shape)
+                    kids.append(("respond.decode_aggs", _t_decode,
+                                 time.monotonic(),
+                                 {"buckets": int(packed.shape[1])
+                                  - 2 * k_seg - 1}))
         if bm_items:
             from opensearch_tpu.telemetry.scan import SCAN
             scan_posting = state.get("scan_posting") or {}
@@ -4485,6 +4562,32 @@ class SearchExecutor:
             segments = self.reader.segments
         index_name = self.reader.index_name
         resp_cache_keys = state.get("resp_cache_keys", {})
+        # the wave's aggregations first, then its pages: two intervals
+        reduced: Dict[int, dict] = {}
+        if agg_by_i:
+            from opensearch_tpu.search.aggs.pipeline import apply_pipelines
+            _t_reduce = time.monotonic()
+            raise_item_errors = state.get("raise_item_errors", False)
+
+            def _reduce_item(i):
+                aggregations = reduce_aggs(per_query_decoded[i])
+                apply_pipelines(agg_nodes_by_i[i], aggregations)
+                reduced[i] = aggregations
+
+            for i in agg_by_i:
+                if i in dead:
+                    continue        # already answered (error/timeout item)
+                # per item: one body's reduce failing answers that item
+                # with its error object, and its siblings keep theirs
+                _run_item_isolated(responses, i, raise_item_errors,
+                                   lambda: _reduce_item(i))
+                if i not in reduced:
+                    dead.add(i)
+            if kids is not None:
+                kids.append(("respond.reduce_aggs", _t_reduce,
+                             time.monotonic(),
+                             {"buckets": _rendered_buckets(reduced)}))
+        _t_render = time.monotonic()
         for i, seg_results in per_query_segs.items():
             if i in dead:
                 continue        # already answered (error/timeout item)
@@ -4565,12 +4668,8 @@ class SearchExecutor:
                 # BMW reports under track_total_hits. The top-k page
                 # itself stays byte-identical (rank-exact pruning).
                 responses[i]["hits"]["total"]["relation"] = "gte"
-            if i in agg_by_i:
-                from opensearch_tpu.search.aggs.pipeline import \
-                    apply_pipelines
-                aggregations = reduce_aggs(per_query_decoded[i])
-                apply_pipelines(agg_nodes_by_i[i], aggregations)
-                responses[i]["aggregations"] = aggregations
+            if i in reduced:
+                responses[i]["aggregations"] = reduced[i]
             key = resp_cache_keys.get(i)
             if key is not None:
                 # cached at query-phase granularity (totals + decoded agg
@@ -4583,9 +4682,13 @@ class SearchExecutor:
         _t_end = time.monotonic()
         ph["respond"] += _t_end - _t
         if span is not None:
+            kids.append(("respond.render", _t_render, _t_end, None))
             span[0].spans.append((
                 span[2] + 4, span[1], "respond", _t, _t_end,
                 (_wave_attrs, span[3], span[4])))
+            span[0].spans.extend(
+                (next(_SPANS.ids), span[2] + 4, name, t0, t1, attrs)
+                for name, t0, t1, attrs in kids)
 
     def _render_cached_msearch(self, cached, start: float) -> dict:
         """Build a fresh response from a cached (total, decoded partials,

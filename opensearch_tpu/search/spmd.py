@@ -300,6 +300,8 @@ def _spmd_query_phase_raw(executors: List, body: dict, k: int,
             if agg_nodes else ()
         plans.append(plan)
         agg_plans_rows.append(aps)
+    # the reads `spmd.plan`'s children are made of (`_note_device_spans`)
+    t_rows = time.monotonic()
 
     if agg_nodes:
         from opensearch_tpu.parallel.distributed import align_agg_plans
@@ -322,6 +324,7 @@ def _spmd_query_phase_raw(executors: List, body: dict, k: int,
     sort_spec = _spmd_sort_spec(executors, sort_specs)
     if sort_spec is False:
         return note_fallback("sort")
+    t_aligned = time.monotonic()
 
     # sharded-serving observability (ISSUE 14): the per-device phase
     # capture rides two gates — the device ledger (node-wide per-chip
@@ -345,6 +348,7 @@ def _spmd_query_phase_raw(executors: List, body: dict, k: int,
     marks: dict = {}
     try:
         shard_set = _resident_shard_set(searcher, executors, rows)
+        t_resident = time.monotonic()
         # the static side of every `bucket_num` level leaves the request
         # before its inputs are flattened: the plans name the shard
         # set's resident lane -> bin vectors, and carry no table
@@ -366,7 +370,8 @@ def _spmd_query_phase_raw(executors: List, body: dict, k: int,
         # e.g. a cross-index search whose rows have mismatched field
         # layouts (canonical_meta rejects them) — host loop handles it
         return note_fallback("searcher")
-    wave = _note_device_spans(t_plan, marks)
+    wave = _note_device_spans(
+        (t_plan, t_rows, t_aligned, t_resident), len(rows), marks)
 
     # always-on scan accounting (telemetry/scan.py): every row of the
     # SPMD program gathers its plan's posting blocks and evaluates the
@@ -439,6 +444,7 @@ def _spmd_query_phase_raw(executors: List, body: dict, k: int,
         if devscope is not None:
             devledger.note_query(devscope)
 
+    t_noted = time.monotonic()
     cand_tuples = []
     for score, row_i, ord_ in zip(scores, row_idx, ords):
         shard_i, seg_i = rows[int(row_i)]
@@ -454,6 +460,7 @@ def _spmd_query_phase_raw(executors: List, body: dict, k: int,
         cand_tuples.append((float(score), seg_i, int(ord_),
                             sort_values, shard_i))
 
+    t_cands = time.monotonic()
     decoded = []
     if agg_nodes:
         for r, (shard_i, seg_i) in enumerate(rows):
@@ -461,16 +468,27 @@ def _spmd_query_phase_raw(executors: List, body: dict, k: int,
             decoded.append(decode_outputs(list(agg_plans_rows[r]),
                                           row_outs))
     # everything since the result page arrived: scan accounting, the
-    # candidates, each row's partials decoded with its own plans
-    _SPANS.child("spmd.reduce", marks["device_wait"][1], time.monotonic(),
-                 {"wave": wave})
+    # candidates, each row's partials decoded with its own plans; and
+    # those three below it (the scan, insights and device-ledger notes
+    # are `scan_note`)
+    t_pulled, t_end = marks["device_wait"][1], time.monotonic()
+    reduce_id = _SPANS.child("spmd.reduce", t_pulled, t_end,
+                             {"wave": wave})
+    if reduce_id:
+        _SPANS.child("spmd.reduce.scan_note", t_pulled, t_noted, None,
+                     reduce_id)
+        _SPANS.child("spmd.reduce.candidates", t_noted, t_cands, None,
+                     reduce_id)
+        if agg_nodes:
+            _SPANS.child("spmd.reduce.decode_aggs", t_cands, t_end,
+                         {"rows": len(rows)}, reduce_id)
     # q_pruned > 0 makes `total` a lower bound (pruned blocks' docs were
     # never counted): the caller renders hits.total.relation = "gte",
     # the same contract Lucene's BMW path keeps via track_total_hits
     return cand_tuples, decoded, int(total), q_pruned
 
 
-def _note_device_spans(t_plan: float, marks: dict) -> int:
+def _note_device_spans(planned: tuple, n_rows: int, marks: dict) -> int:
     """The SPMD route's spans in the always-on ring, under the span open
     on this thread (`rest.search`), from the clock reads
     `search_resident` made: `spmd.plan` (parse, per-row compile, align,
@@ -479,7 +497,17 @@ def _note_device_spans(t_plan: float, marks: dict) -> int:
     what the envelope's `dispatch` carries: wave, programs, nbytes,
     family, fingerprint, shape) and `device_wait` (the blocking pull of
     the result page). Returns the wave: how many times this request
-    dispatched before (a k-growth retry runs the phase again)."""
+    dispatched before (a k-growth retry runs the phase again).
+
+    Below `spmd.plan`, from `planned` (the plan's start, its rows
+    compiled, their structure checked, the shard set resident) and
+    `marks["stacked"]`: `spmd.plan.compile_rows` (parse and the per-row
+    compile), `spmd.plan.align` (`align_agg_plans`, the structure
+    check, the sort key), `spmd.plan.stack` (the lane -> bin lookup,
+    flatten, `pad_stack_trees`). Its self time is what lies between:
+    the shard set looked up (built, on an index's first request:
+    `install.shard_set` on the process track) and the wait for the
+    dispatch lock."""
     from opensearch_tpu.search.executor import (_dispatch_attrs,
                                                 _wait_attrs, _wave_attrs)
     trace = _SPANS.current()
@@ -488,7 +516,15 @@ def _note_device_spans(t_plan: float, marks: dict) -> int:
     wave = sum(1 for s in trace.spans
                if s[2] == "dispatch" and s[1] == trace.top)
     t0, t1, nbytes, info = marks["dispatch"]
-    _SPANS.child("spmd.plan", t_plan, t0, (_wave_attrs, wave, None))
+    t_plan, t_rows, t_aligned, t_resident = planned
+    plan_id = _SPANS.child("spmd.plan", t_plan, t0,
+                           (_wave_attrs, wave, None))
+    if plan_id:
+        _SPANS.child("spmd.plan.compile_rows", t_plan, t_rows,
+                     {"rows": n_rows}, plan_id)
+        _SPANS.child("spmd.plan.align", t_rows, t_aligned, None, plan_id)
+        _SPANS.child("spmd.plan.stack", t_resident, marks["stacked"],
+                     None, plan_id)
     _SPANS.child("dispatch", t0, t1,
                  (_dispatch_attrs, wave, None, 1, nbytes,
                   [info] if info is not None else []))
@@ -522,6 +558,7 @@ def _resident_shard_set(searcher, executors, rows):
     # from the device — a full index download per rebuild. Built OUTSIDE
     # the lock: a racing builder costs one duplicate upload (last insert
     # wins), never a convoy of queries behind a segment upload.
+    t_build = time.monotonic()
     arrays, metas = [], []
     for s, g in rows:
         a, m = upload_segment(executors[s].reader.segments[g],
@@ -529,7 +566,9 @@ def _resident_shard_set(searcher, executors, rows):
         # adopt the reader's live mask state (deletes since seal)
         arrays.append(a)
         metas.append(m)
-    shard_set = searcher.build_shard_set(arrays, metas)
+    # `install.shard_set` of the span ring's process track, from
+    # `t_build` on: the rows' host images are part of the install
+    shard_set = searcher.build_shard_set(arrays, metas, started=t_build)
     SPMD_UPLOADS.inc()
     evicted = None
     with _SPMD_LOCK:

@@ -71,6 +71,13 @@ class TelemetryService:
     def __init__(self):
         self.tracer = Tracer()
         self.metrics = MetricsRegistry()
+        # the collections `tracer.gc` counts as plain ints (its callback
+        # may take no lock), among the counters of `_nodes/stats`
+        gc_spans = self.tracer.gc
+        self.metrics.publish("process.gc.collections.gen2",
+                             lambda: gc_spans.full)
+        self.metrics.publish("process.gc.pause_us",
+                             lambda: gc_spans.pause_ns // 1000)
         self.ledger = TransferLedger()
         self.device_memory = DeviceMemoryAccounting()
         self.flight = FlightRecorder()
@@ -126,6 +133,9 @@ class TelemetryService:
         if kernels_peak_bw is not None:
             self.kernels.peak_bw = float(kernels_peak_bw)
         self.tracer.resize(ring_size)
+        # the heap's collections go onto the span ring's process track
+        # from here on (one `gc.callbacks` entry a process)
+        self.tracer.gc.install()
         self.tracer.jsonl_path = None
         self.flight.jsonl_path = None
         if jsonl and data_path is not None:

@@ -16,7 +16,7 @@ enough to sit under the query path whether or not tracing is enabled.
 from __future__ import annotations
 
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 from opensearch_tpu.telemetry.rolling import RollingEstimator
 
@@ -136,7 +136,15 @@ class MetricsRegistry:
     def __init__(self):
         self._counters: Dict[str, Counter] = {}
         self._histograms: Dict[str, Histogram] = {}
+        self._published: Dict[str, Callable[[], int]] = {}
         self._lock = threading.Lock()
+
+    def publish(self, name: str, read: Callable[[], int]) -> None:
+        """A count that its writer keeps as a plain int because it may
+        take no lock (the `gc` callback, telemetry/tracer.py `GcSpans`):
+        `read()` is called when the snapshot is rendered, and the value
+        stands among the counters under `name`. `reset` leaves it."""
+        self._published[name] = read
 
     def counter(self, name: str) -> Counter:
         c = self._counters.get(name)
@@ -155,9 +163,11 @@ class MetricsRegistry:
         return h
 
     def to_dict(self) -> dict:
+        counters = {name: c.value for name, c in self._counters.items()}
+        counters.update((name, read())
+                        for name, read in self._published.items())
         return {
-            "counters": {name: c.value
-                         for name, c in sorted(self._counters.items())},
+            "counters": dict(sorted(counters.items())),
             "histograms": {name: h.to_dict()
                            for name, h in sorted(self._histograms.items())},
         }

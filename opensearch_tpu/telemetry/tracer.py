@@ -19,18 +19,39 @@ pays a couple of attribute loads, nothing else.
 
 Beside the verbose tree sits the ALWAYS-ON span ring (`SpanRing`,
 `Tracer.spans`): flat completed records `(trace_id, span_id, parent_id,
-name, start_ns, end_ns, attributes)` at the layer boundaries of the
-served path (http.request -> rest.search -> envelope -> envelope.parse /
-compile_group / pack, dispatch, device_wait, respond). It is fed from
-the clock reads the always-on histograms already make, so a request
-costs a handful of tuple appends, one row in the ring and no lock; it
-is what
-`GET /_telemetry/spans` serves and what the benchmark's per-layer
-metrics read. Both forms are on `time.monotonic_ns()`, the clock of
-`time.monotonic()` on Linux, so a span lies on a client's samples
-without conversion; the export carries one `(monotonic_ns, time_ns)`
-pair for wall time. `telemetry.tracing.enabled` gates only the verbose
-tree.
+name, start_ns, end_ns, attributes)`. A request's row holds the layer
+boundaries of the served path (http.request -> rest.search -> envelope
+-> envelope.parse / compile_group / pack, dispatch, device_wait,
+respond; on the SPMD route rest.search -> spmd.plan, dispatch,
+device_wait, spmd.reduce x 2, respond), each fed from the clock reads
+the always-on histograms already make, and BELOW the four boundaries
+that are wide on the host their children: `compile.bundle` >
+`compile.text_clause` and `compile.scan_note` under
+`envelope.compile_group`; `respond.unpack` / `.decode_aggs` /
+`.reduce_aggs` / `.render` under the envelope's `respond`;
+`spmd.plan.compile_rows` / `.align` / `.stack` under `spmd.plan`;
+`spmd.reduce.scan_note` / `.candidates` / `.decode_aggs` /
+`.reduce_aggs` under the two `spmd.reduce`. No child lies directly
+under `http.request`, `rest.*` or `envelope`: their SELF time is what
+the benchmark reads of them. A warm B=1 BM25 request is sixteen
+records, an aggregating one and an SPMD dashboard request seventeen
+(two fewer where the bundle memo holds the body): a clock read and a
+tuple append each, one row in the ring and no lock. A wave keeps the
+first `COMPILE_SPANS_A_WAVE` (search/compile.py) of its compile spans,
+so a cold `_msearch` of hundreds of bodies stays a short row.
+
+What no request owns lies on the ring's PROCESS TRACK
+(`SpanRing.process`, `trace_id` 0, the same clock): the interpreter's
+collections (`gc.collect`, from `gc.callbacks`: `GcSpans`) and what is
+built once an index (`install.upload_segment` > `install.host_pad`,
+`install.device_put`; `install.shard_set` > `.host_images`, `.stack`,
+`.device_put`). `GET /_telemetry/spans`
+serves both, under `"spans"` and `"process"`, and the benchmark's
+per-layer metrics read them. Both forms are on `time.monotonic_ns()`,
+the clock of `time.monotonic()` on Linux, so a span lies on a client's
+samples without conversion; the export carries one `(monotonic_ns,
+time_ns)` pair for wall time. `telemetry.tracing.enabled` gates only
+the verbose tree.
 
 Completed root spans land in a bounded in-memory ring buffer served by
 `GET /_telemetry/traces` and, when configured with a data dir, are
@@ -40,6 +61,7 @@ appended as JSONL under `_state/traces.jsonl` for offline analysis
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import threading
@@ -48,11 +70,24 @@ from collections import deque
 from typing import Any, Dict, List, Optional
 
 DEFAULT_RING_SIZE = 256
-# requests kept. A served request is at least eight spans (http.request
-# and its two halves, rest.*, envelope, and five a wave), so the ring
-# holds 65,536 spans and more; the 30 s window of the busiest queued
-# benchmark cell is 140 requests/s = 4,200 requests
+# requests kept. A served request is eleven boundary spans
+# (http.request and its two halves, rest.*, envelope and its parse, and
+# five a wave) and four to seven children below them (sixteen records a
+# B=1 BM25 request, seventeen an aggregating or an SPMD one; a wave of
+# an `_msearch` at most search/compile.py's `COMPILE_SPANS_A_WAVE` more),
+# so the ring holds 131,072 spans and more; the 30 s window of the
+# busiest queued benchmark cell is 140 requests/s = 4,200 requests
 SPAN_RING_SIZE = 8192
+# process-track spans kept: three a segment installed, four a shard set,
+# every full collection of the heap and a younger one that took 1 ms or
+# more. A 30 s window of the k-NN cell (3,000 requests) adds ten to
+# twenty (two or three full collections, eight to twelve younger ones
+# of 1.0-1.7 ms: PERF.md, section 6), so the install spans outlive
+# hundreds of windows
+PROCESS_RING_SIZE = 4096
+# a collection of generation 0 or 1 is kept as a span only from here
+# up; every one is counted
+GC_SPAN_MIN_NS = 1_000_000
 
 
 class Trace:
@@ -99,10 +134,17 @@ class SpanRing:
     sub-requests in one program) does not apply.
 
     Ids come eight apart (`ids`): a span that closes with leaf children
-    names them `its id + 1 .. + 7` without another draw."""
+    names them `its id + 1 .. + 7` without another draw.
 
-    def __init__(self, size: int = SPAN_RING_SIZE):
+    Beside the requests sits the process track (`process`): completed
+    spans of what no request owns, `trace_id` 0, in a bounded deque of
+    their own that drops its oldest; the same clock, ids from the same
+    counter."""
+
+    def __init__(self, size: int = SPAN_RING_SIZE,
+                 process_size: int = PROCESS_RING_SIZE):
         self._ring: "deque[Trace]" = deque(maxlen=size)
+        self._process: "deque[tuple]" = deque(maxlen=process_size)
         self.ids = itertools.count(8, 8)
         self._put = 0               # requests completed since `clear`
         self._local = threading.local()
@@ -138,13 +180,31 @@ class SpanRing:
             self._put += 1
             self._ring.append(trace)
 
-    def child(self, name: str, start, end, attributes=None) -> None:
-        """A completed leaf span under the span open on this thread;
-        nothing when no request is open (a direct library caller)."""
+    def child(self, name: str, start, end, attributes=None,
+              parent_id: int = 0) -> int:
+        """A completed span under the span open on this thread, or under
+        `parent_id` (a span of this request recorded after the fact,
+        whose id an earlier `child` returned); returns its id. Nothing,
+        and 0, when no request is open (a direct library caller)."""
         trace = getattr(self._local, "trace", None)
-        if trace is not None:
-            trace.spans.append((next(self.ids), trace.top, name, start,
-                                end, attributes))
+        if trace is None:
+            return 0
+        span_id = next(self.ids)
+        trace.spans.append((span_id, parent_id or trace.top, name, start,
+                            end, attributes))
+        return span_id
+
+    def process(self, name: str, start, end, attributes=None,
+                parent_id: int = 0) -> int:
+        """A completed span of the process track: what no request owns
+        (a collection of the heap, an index's install). Times and
+        attributes as a request's spans; `parent_id` is a process span
+        recorded before (0: none). One deque append, no lock: callable
+        from a `gc` callback. Returns the span's id."""
+        span_id = next(self.ids)
+        self._process.append((span_id, parent_id, name, start, end,
+                              attributes))
+        return span_id
 
     # ------------------------------------------------------------- reading
 
@@ -155,35 +215,26 @@ class SpanRing:
 
     def export(self, since_ns: Optional[int] = None,
                until_ns: Optional[int] = None) -> dict:
-        """The `GET /_telemetry/spans` body: every span of a completed
-        request that ends at or after `since_ns` and starts at or
-        before `until_ns`."""
+        """The `GET /_telemetry/spans` body: under `spans` every span of
+        a completed request, under `process` every span of the process
+        track, that ends at or after `since_ns` and starts at or before
+        `until_ns`."""
         traces = self._ring.copy()  # one C call: atomic under the GIL
         out = []
         for trace in traces:
-            for span_id, parent_id, name, t0, t1, attrs in \
-                    trace.spans.copy():
-                if type(t0) is not int:
-                    t0 = int(t0 * 1e9)
-                if type(t1) is not int:
-                    t1 = int(t1 * 1e9)
-                if (since_ns is not None and t1 < since_ns) \
-                        or (until_ns is not None and t0 > until_ns):
-                    continue
-                row = {"trace_id": trace.trace_id, "span_id": span_id,
-                       "parent_id": parent_id, "name": name,
-                       "start_ns": t0, "end_ns": t1}
-                if type(attrs) is tuple:
-                    attrs = attrs[0](*attrs[1:])
-                if attrs:
-                    row["attributes"] = attrs
-                out.append(row)
+            _rows(out, trace.trace_id, trace.spans.copy(), since_ns,
+                  until_ns)
+        process = []
+        _rows(process, 0, self._process.copy(), since_ns, until_ns)
         return {"clock": "monotonic_ns",
                 "anchor": {"monotonic_ns": time.monotonic_ns(),
                            "time_ns": time.time_ns()},
-                "dropped": self.dropped, "spans": out}
+                "dropped": self.dropped, "spans": out,
+                "process": process}
 
     def clear(self) -> None:
+        """Forget the requests. The process track stays: what it holds
+        of an index's install is recorded once."""
         self._ring.clear()
         self._put = 0
 
@@ -191,7 +242,77 @@ class SpanRing:
         retained = len(self._ring)
         return {"size": self._ring.maxlen, "retained": retained,
                 "recorded": max(self._put, retained),
-                "dropped": self.dropped}
+                "dropped": self.dropped, "process": len(self._process)}
+
+
+def _rows(out: list, trace_id: int, spans, since_ns, until_ns) -> None:
+    """The export's rows of one request's (or the process track's)
+    span tuples: times to integer nanoseconds, attributes built."""
+    for span_id, parent_id, name, t0, t1, attrs in spans:
+        if type(t0) is not int:
+            t0 = int(t0 * 1e9)
+        if type(t1) is not int:
+            t1 = int(t1 * 1e9)
+        if (since_ns is not None and t1 < since_ns) \
+                or (until_ns is not None and t0 > until_ns):
+            continue
+        row = {"trace_id": trace_id, "span_id": span_id,
+               "parent_id": parent_id, "name": name,
+               "start_ns": t0, "end_ns": t1}
+        if type(attrs) is tuple:
+            attrs = attrs[0](*attrs[1:])
+        if attrs:
+            row["attributes"] = attrs
+        out.append(row)
+
+
+def _gc_attrs(generation: int, collected: int, uncollectable: int) -> dict:
+    return {"generation": generation, "collected": collected,
+            "uncollectable": uncollectable}
+
+
+class GcSpans:
+    """The interpreter's collections on the ring's process track: a
+    `gc.callbacks` entry (`install`, where the node's telemetry starts)
+    that reads the ring's clock when a collection starts and when it
+    stops. Every collection of generation 2 becomes a `gc.collect` span
+    (`generation`, `collected`, `uncollectable`), a younger one only if
+    it took `GC_SPAN_MIN_NS` or more; the full ones are counted
+    (`full`), and the time all of them took (`pause_ns`).
+
+    The callback runs inside whatever allocation set the collection off,
+    on that thread, with any lock that thread holds: it takes none
+    (plain ints, one deque append; one collection runs at a time, so
+    the ints have one writer) and raises nothing. The counts reach
+    `_nodes/stats` as counters read when the stats are
+    (`MetricsRegistry.publish`)."""
+
+    def __init__(self, ring: SpanRing):
+        self.ring = ring
+        self.full = 0           # collections of generation 2
+        self.pause_ns = 0       # every generation
+        self._start = 0
+
+    def __call__(self, phase: str, info: dict) -> None:
+        now = time.monotonic_ns()
+        if phase == "start":
+            self._start = now
+            return
+        start, self._start = self._start, 0
+        if not start:
+            return      # installed while this collection was running
+        generation = info.get("generation", 2)
+        self.full += generation == 2
+        self.pause_ns += now - start
+        if generation == 2 or now - start >= GC_SPAN_MIN_NS:
+            self.ring.process(
+                "gc.collect", start, now,
+                (_gc_attrs, generation, info.get("collected", 0),
+                 info.get("uncollectable", 0)))
+
+    def install(self) -> None:
+        if self not in gc.callbacks:
+            gc.callbacks.append(self)
 
 
 class Span:
@@ -309,8 +430,10 @@ class Tracer:
 
     def __init__(self, ring_size: int = DEFAULT_RING_SIZE):
         self.enabled = False
-        # the always-on flat span ring (not gated by `enabled`)
+        # the always-on flat span ring (not gated by `enabled`), and the
+        # collections of the heap on its process track
         self.spans = SpanRing()
+        self.gc = GcSpans(self.spans)
         self._ring: "deque[dict]" = deque(maxlen=ring_size)
         self._lock = threading.Lock()
         # separate lock for file appends: a slow disk must not block

@@ -372,34 +372,6 @@ def test_scaling_cli_end_to_end(tmp_path):
     assert bench_compare.main(["bench_compare.py", old_p, bad_p]) == 1
 
 
-# ----------------------------------------------- scaling_report (ISSUE 14)
-
-def test_scaling_report_smoke(tmp_path, capsys):
-    import scaling_report
-
-    recs = list(SCALE_OLD.values())
-    for r in recs:
-        r["collective_ici_bytes_per_query"] = 1440.0
-        r["scanned_bytes_per_query_p50"] = 3072.0
-        r["per_device"] = {"0": {"queries": 10, "partial_ms": 55.0,
-                                 "straggler_hits": 3, "h2d_bytes": 123}}
-    path = _write(tmp_path / "mc.json", recs)
-    assert scaling_report.main(["scaling_report.py", path]) == 0
-    out = capsys.readouterr().out
-    assert "efficiency" in out and "per-chip breakdown" in out
-    # the efficiency floor check
-    assert scaling_report.main(
-        ["scaling_report.py", "--assert-efficiency", "0.4", path]) == 0
-    assert scaling_report.main(
-        ["scaling_report.py", "--assert-efficiency", "0.9", path]) == 1
-
-
-def test_scaling_report_empty_input(tmp_path):
-    import scaling_report
-    path = _write(tmp_path / "empty.json", [])
-    assert scaling_report.main(["scaling_report.py", path]) == 1
-
-
 # ----------------------------------------------- query insights (ISSUE 15)
 
 def _insights_rec(p99_by_shape, count=50):
@@ -583,77 +555,10 @@ def test_maxsim_cli_end_to_end(tmp_path):
     assert bench_compare.main(["bench_compare.py", old_p, bad_p]) == 1
 
 
-# ----------------------------------------- kernel profiler (ISSUE 19)
-
-def _kernels_rec(family="bm25_dense", bench="bm25", p50=0.5, calls=64,
-                 **over):
-    rec = {"mode": f"kernels_{bench}_{family}", "bench": bench,
-           "family": family, "calls": calls,
-           "device_ms": (p50 or 0.0) * calls,
-           "p50_ms": p50, "p99_ms": p50 * 1.4 if p50 else None,
-           "compiles": 1, "compile_ms": 120.0, "flops": 1.0e9,
-           "bytes": 1.0e8, "arithmetic_intensity": 10.0,
-           "bound": "compute"}
-    rec.update(over)
-    return rec
-
+# ------------------------------------------------ block-max A/B (ISSUE 20) --
 
 def _keyed(*recs):
     return {r["mode"]: r for r in recs}
-
-
-def test_kernels_within_bound_ok():
-    old = _keyed(_kernels_rec(p50=0.50))
-    new = _keyed(_kernels_rec(p50=0.55))   # +10% < 15% bound
-    rows, failures = bench_compare.compare_kernels(old, new, 10.0)
-    assert not failures and rows[0]["status"] == "ok"
-    assert rows[0]["p50_delta_pct"] == 10.0
-
-
-def test_kernels_p50_regression_fails_at_equal_key():
-    old = _keyed(_kernels_rec(p50=0.50))
-    new = _keyed(_kernels_rec(p50=0.60))   # +20% > 15% bound
-    rows, failures = bench_compare.compare_kernels(old, new, 10.0)
-    assert failures and "KERNEL-REGRESSION" in rows[0]["status"]
-    assert "kernels_bm25_bm25_dense" in failures[0]
-
-
-def test_kernels_census_only_reports_never_fails():
-    # compiled-but-never-dispatched families carry roofline data, no
-    # timing — a 0-call side must never trip the latency gate
-    old = _keyed(_kernels_rec(calls=0, p50=None))
-    new = _keyed(_kernels_rec(p50=99.0))
-    rows, failures = bench_compare.compare_kernels(old, new, 10.0)
-    assert not failures and rows[0]["status"] == "census-only"
-
-
-def test_kernels_one_sided_families_never_fail():
-    old = _keyed(_kernels_rec())
-    new = _keyed(_kernels_rec(),
-                 _kernels_rec(family="maxsim_adc", bench="maxsim",
-                              p50=50.0))
-    rows, failures = bench_compare.compare_kernels(old, new, 10.0)
-    assert not failures
-    assert any(r["status"] == "new-only" for r in rows)
-
-
-def test_kernels_records_skip_generic_gate():
-    # a kernel row's p50_ms is a device EXEC wall, not a warm request
-    # latency — the generic warm gate must not judge it
-    old = _keyed(_kernels_rec(p50=0.5))
-    new = _keyed(_kernels_rec(p50=50.0))
-    rows, failures = bench_compare.compare(old, new, 10.0)
-    assert not rows and not failures
-
-
-def test_kernels_cli_end_to_end(tmp_path):
-    old_p = _write(tmp_path / "k_old.json", [_kernels_rec(p50=0.5)])
-    bad_p = _write(tmp_path / "k_bad.json", [_kernels_rec(p50=5.0)])
-    assert bench_compare.main(["bench_compare.py", old_p, old_p]) == 0
-    assert bench_compare.main(["bench_compare.py", old_p, bad_p]) == 1
-
-
-# ------------------------------------------------ block-max A/B (ISSUE 20) --
 
 
 def _bmx_pair(base="spmd_1000k_d8", docs=1_000_000, off_p50=12.0,

@@ -74,13 +74,6 @@ MAXSIM_PQ_RECALL_FLOOR = 0.95
 BLOCKMAX_P50_PCT = 15.0
 BLOCKMAX_P50_MAX_DOCS = 1_000_000
 
-# the kernel-profiler gate (ISSUE 19): at EQUAL bench+family key, a
-# kernel family's sampled device-wall p50 may not regress by more than
-# this between two BENCH_KERNELS rounds — "this executable family got
-# slower on device" fails the run even when the end-to-end warm
-# latency absorbed it elsewhere
-KERNELS_P50_PCT = 15.0
-
 
 def load_records(path: str) -> Dict[str, dict]:
     """file of JSON lines (or one JSON array) → {config key: record}."""
@@ -167,13 +160,6 @@ def compare(old: Dict[str, dict], new: Dict[str, dict],
             # per-shape warm p99 at equal shape key): their aggregate
             # p99 moves with the shape MIX, which shifts legitimately
             # round over round
-            continue
-        if any(r is not None and isinstance(r.get("family"), str)
-               and "device_ms" in r for r in (o, n)):
-            # BENCH_KERNELS rows have their own gate (compare_kernels,
-            # per-family device p50 at equal bench+family key): their
-            # p50_ms is a sampled device EXEC wall, not a warm request
-            # latency — the generic warm gate would misread it
             continue
         row = {"config": key}
         if o is None or n is None:
@@ -632,63 +618,6 @@ def compare_maxsim(old: Dict[str, dict], new: Dict[str, dict],
     return rows, failures
 
 
-def _kernels_records(recs: Dict[str, dict]) -> Dict[str, dict]:
-    """The BENCH_KERNELS shape: per-(bench, family) rows carrying a
-    kernel `family` next to a `device_ms` total (rows nothing writes
-    since PR 31 took the sampled timer out: ROADMAP D5b)."""
-    return {k: r for k, r in recs.items()
-            if isinstance(r.get("family"), str) and "device_ms" in r}
-
-
-def compare_kernels(old: Dict[str, dict], new: Dict[str, dict],
-                    threshold_pct: float) -> Tuple[List[dict], List[str]]:
-    """Gate two kernel-profiler rounds row-by-row at EQUAL bench+family
-    key: fail when a family's sampled device-wall p50 regresses by more
-    than KERNELS_P50_PCT (that executable family got slower on device).
-    Census-only rows (calls == 0 on either side — the family compiled
-    but never dispatched in the measured window, so it carries
-    compile/roofline data and no timing) report but never fail, as do
-    rows present in only one round (the family set grows with the
-    feature set). `threshold_pct` is accepted for signature parity with
-    the other comparers; the per-family bound is the class constant."""
-    del threshold_pct
-    o_recs, n_recs = _kernels_records(old), _kernels_records(new)
-    rows, failures = [], []
-    if not o_recs or not n_recs:
-        return rows, failures
-    for key in sorted(set(o_recs) | set(n_recs)):
-        o, n = o_recs.get(key), n_recs.get(key)
-        row = {"config": key, "family": (o or n)["family"]}
-        if o is None or n is None:
-            row["status"] = "old-only" if n is None else "new-only"
-            rows.append(row)
-            continue
-        status = "ok"
-        row["old_calls"] = o.get("calls", 0)
-        row["new_calls"] = n.get("calls", 0)
-        o50, n50 = o.get("p50_ms"), n.get("p50_ms")
-        row["old_p50_ms"] = o50
-        row["new_p50_ms"] = n50
-        row["bound"] = n.get("bound")
-        if not row["old_calls"] or not row["new_calls"]:
-            status = "census-only"
-        elif isinstance(o50, (int, float)) and o50 > 0 \
-                and isinstance(n50, (int, float)):
-            d50 = 100.0 * (n50 - o50) / o50
-            row["p50_delta_pct"] = round(d50, 1)
-            if d50 > KERNELS_P50_PCT:
-                status = "KERNEL-REGRESSION"
-                failures.append(
-                    f"{key}: device p50 {o50}ms -> {n50}ms "
-                    f"(+{d50:.1f}% > {KERNELS_P50_PCT:g}% at equal "
-                    f"bench+family key)")
-        else:
-            status = "no-latency-field"
-        row["status"] = status
-        rows.append(row)
-    return rows, failures
-
-
 def _blockmax_pairs(recs: Dict[str, dict]) -> List[Tuple[str, Optional[dict], dict]]:
     """(base key, unpruned record or None, pruned record) for every
     pruned-arm record (`blockmax: true`, mode suffixed `_bmx`) in the
@@ -762,18 +691,6 @@ def render_blockmax(rows: List[dict]) -> str:
     headers = ["config", "docs", "pruned_fraction", "digest_match",
                "unpruned_warm_p50_ms", "pruned_warm_p50_ms",
                "p50_delta_pct", "status"]
-    table = [headers] + [[str(r.get(h, "-")) for h in headers]
-                         for r in rows]
-    widths = [max(len(row[i]) for row in table)
-              for i in range(len(headers))]
-    return "\n".join(
-        "  ".join(cell.ljust(w) for cell, w in zip(row, widths)).rstrip()
-        for row in table)
-
-
-def render_kernels(rows: List[dict]) -> str:
-    headers = ["config", "old_calls", "new_calls", "old_p50_ms",
-               "new_p50_ms", "p50_delta_pct", "bound", "status"]
     table = [headers] + [[str(r.get(h, "-")) for h in headers]
                          for r in rows]
     widths = [max(len(row[i]) for row in table)
@@ -928,12 +845,6 @@ def main(argv: List[str]) -> int:
               "key / PQ recall-vs-exact floor):")
         print(render_maxsim(mx_rows))
         failures += mx_failures
-    kr_rows, kr_failures = compare_kernels(old, new, threshold)
-    if kr_rows:
-        print("\nkernel profiler (per-family device p50 at equal "
-              "bench+family key):")
-        print(render_kernels(kr_rows))
-        failures += kr_failures
     bm_rows, bm_failures = compare_blockmax(old, new, threshold)
     if bm_rows:
         print("\nblock-max A/B (pruned vs unpruned arm at equal "
