@@ -846,34 +846,48 @@ class Compiler:
         # both compile work and the msearch envelope bytes that cross the
         # host↔device link per query. The field's norms travel with its
         # posting blocks (device image `post_norm`), so no norms row is named
-        ids, ws, tids = [], [], []
-        weightless = False
+        runs, total, weightless = [], 0, False
         for t_i, (term, w) in enumerate(weighted_terms):
             tm = seg.get_term(field, term)
             if tm is None:
                 continue
             weightless |= w == 0.0
-            for blk_i in range(tm.start_block, tm.start_block + tm.num_blocks):
-                ids.append(blk_i)
-                ws.append(w)
-                tids.append(t_i)
-        qb = pad_bucket(max(len(ids), 1), minimum=8)
-        pad = qb - len(ids)
+            runs.append((tm.start_block, tm.num_blocks, w, t_i))
+            total += tm.num_blocks
+        qb = pad_bucket(max(total, 1), minimum=8)
+        # a term's blocks are contiguous: its lanes are written a run at a
+        # time (one array store a term, none a block); -1 = padding lane
+        # (no hit), under a weight of 0.0
+        ids = np.full(qb, -1, np.int32)
+        ws = np.zeros(qb, np.float32)
+        tids = np.zeros(qb, np.int32) if _bm25.BLOCKMAX else None
+        o = 0
+        for start, n, w, t_i in runs:
+            if n == 1:          # one lane: scalar stores, no array built
+                lanes, blocks = o, start
+            else:
+                lanes = slice(o, o + n)
+                blocks = np.arange(start, start + n, dtype=np.int32)
+            ids[lanes] = blocks
+            ws[lanes] = w
+            if tids is not None:
+                tids[lanes] = t_i
+            o += n
         avgdl_eff = avgdl if avgdl > 0 else 1.0
         inputs = {
-            "ids": _i32(ids + [-1] * pad),    # -1 = padding lane (no hit)
-            "w": _f32(ws + [0.0] * pad),
+            "ids": ids,
+            "w": ws,
             "avgdl": _f32(avgdl_eff),
             "b": _f32(b_eff),
             "k1": _f32(k1),
             "min_hits": _i32(min_hits),
             "boost": _f32(boost),
         }
-        if _bm25.BLOCKMAX:
+        if tids is not None:
             # phase-A extras ride as traced inputs, NOT in the compile key:
             # bscale is a per-segment float and must not fracture the
             # executable sharing the churn pin depends on
-            inputs["tid"] = _i32(tids + [0] * pad)
+            inputs["tid"] = tids
             inputs["bscale"] = _f32(
                 self._blockmax_scale(seg, field, k1, b_eff, avgdl))
         # static records the distinct-term count: the candidate-buffer
@@ -889,11 +903,11 @@ class Compiler:
             b_eff, avgdl_eff)
         plan = Plan("text", static=(bool(constant), len(weighted_terms),
                                     score_only),
-                    inputs=inputs, scan_blocks=len(ids))
+                    inputs=inputs, scan_blocks=total)
         self.stats.memo[memo_key] = plan    # RotatingMemo bounds itself
         if ring is not None:
             ring.child("compile.text_clause", t_clause, time.monotonic(),
-                       {"blocks": len(ids), "terms": len(weighted_terms)})
+                       {"blocks": total, "terms": len(weighted_terms)})
         return _counted_text_plan(plan)
 
     def _blockmax_scale(self, seg: Segment, field: str, k1: float,
