@@ -14,9 +14,11 @@ HNSW/IVF via native faiss/nmslib. Here both are TPU-native:
   PERF.md §5): XLA lowers the one-column product to a VPU
   multiply-reduce that reads the column once, 8.5 ms a query (758 GB/s),
   and to no MXU op; the clause's k=100 selection (`knn_select`) takes
-  1.3 ms more, and a clause that is the whole query takes its page from
-  those k winners (`knn_page`), not from a second selection over
-  `d_pad` lanes (another 1.4 ms until PR 34).
+  the maxima of 16,384 blocks of 128 lanes, the 100 best of them, and
+  one sort of those blocks' 12,800 lanes: 0.17 ms more, where one
+  `TopK` over the 2,097,152 lanes took 1.29. A clause that is the whole
+  query takes its page from those k winners (`knn_page`), not from a
+  second selection over `d_pad` lanes (another 1.4 ms).
 - **IVF**: k-means centroids (built at seal time, Lloyd's on device),
   inverted lists as a padded [nlist, max_len] int32 matrix. A query scores
   centroids, takes the top-nprobe lists, gathers their candidates, and
@@ -78,16 +80,62 @@ def exact_knn_scores(vectors: jnp.ndarray, query: jnp.ndarray,
         return space_score(raw_similarity(vectors, query, space), space)
 
 
+SELECT_BLOCK = 128          # lanes a block of the blocked selection
+SELECT_MIN_LANES = 1 << 15  # below it XLA's TopK is one cheap pass
+
+
+def blocked_select_width(d_pad: int, k: int) -> int:
+    """The block width `knn_select` selects through for a `[d_pad]` score
+    vector and k winners, 0 where it runs one plain `top_k`. Plain,
+    XLA's `TopK` costs ~1 ns a lane on a v5e from 2^15 lanes up and
+    ~10 us below; blocked, it takes one max-reduce, a `top_k` over the
+    `d_pad / w` block maxima and a sort of the `k * w` lanes of the k
+    winning blocks, whose cost does not grow with `d_pad` (PERF.md §6).
+    Blocks are one vreg row (128 f32 lanes) wide, and the route wants
+    the candidates to be at most a quarter of the lanes."""
+    w = SELECT_BLOCK
+    if d_pad < SELECT_MIN_LANES or 4 * k * w > d_pad:
+        return 0
+    return w
+
+
+def _blocked_top_k(masked: jnp.ndarray, k: int, w: int):
+    """`jax.lax.top_k(masked, k)`, the same values and the same indices,
+    from the lanes of the k contiguous `w`-lane blocks with the largest
+    maxima (ties to the lower block). Exact, ties included: with T the
+    k-th of those maxima, every lane above T is in a chosen block. Each
+    chosen block whose maximum is above T holds a lane above T, so no
+    more lanes at T win than there are chosen blocks whose maximum is T;
+    each of those holds a lane at T and lies below every passed-over
+    block whose maximum is T, so the lowest lanes at T are all chosen.
+    The candidates are sorted by score descending, then doc ascending:
+    `top_k`'s lowest-index rule. The docs are distinct, so the sort need
+    not be stable (a stable one takes half again as long); and it is a sort,
+    not a second `top_k`, because the sorts XLA rewrites a `top_k` into
+    carry no stage of their own."""
+    blocks = masked.reshape(masked.shape[0] // w, w)
+    _, blk = jax.lax.top_k(jnp.max(blocks, axis=1), k)
+    docs = blk[:, None] * w + jnp.arange(w, dtype=blk.dtype)
+    neg, docs = jax.lax.sort((-blocks[blk].reshape(k * w),
+                              docs.reshape(k * w)), num_keys=2,
+                             is_stable=False)
+    return -neg[:k], docs[:k]
+
+
 def knn_select(scores: jnp.ndarray, eligible: jnp.ndarray, k: int):
     """The clause's selection: the k best eligible docs of a dense score
     vector, as (values, doc ordinals, valid), score-desc with ties by
     lowest doc (`top_k`'s lowest-index rule). Fewer than k eligible docs
-    leave the tail slots `-inf` and not `valid`. The ONE `top_k` over
-    `[d_pad]` lanes a k-NN clause runs, whoever reads its winners."""
+    leave the tail slots `-inf` and not `valid`. The one selection over
+    `[d_pad]` lanes a k-NN clause runs, whoever reads its winners:
+    blocked where `blocked_select_width` says so, else one `top_k`."""
     with stage("top_k"):
         masked = jnp.where(eligible, scores, -jnp.inf)
-        top_vals, top_idx = jax.lax.top_k(
-            masked, min(int(k), int(scores.shape[0])))
+        d = int(scores.shape[0])
+        k = min(int(k), d)
+        w = blocked_select_width(d, k)
+        top_vals, top_idx = _blocked_top_k(masked, k, w) if w \
+            else jax.lax.top_k(masked, k)
         return top_vals, top_idx, top_vals > -jnp.inf
 
 

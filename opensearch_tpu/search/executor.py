@@ -48,7 +48,7 @@ from opensearch_tpu.ops.bm25 import (
 from opensearch_tpu.ops import device_segment as _devseg
 from opensearch_tpu.ops.device_segment import (
     DeviceSegmentMeta, refresh_live, tree_nbytes, upload_segment)
-from opensearch_tpu.ops.knn import knn_page
+from opensearch_tpu.ops.knn import blocked_select_width, knn_page
 from opensearch_tpu.ops.topk import (NEG_INF, f32_sortable, single_valued,
                                      value_merge_key)
 from opensearch_tpu.search import dsl
@@ -648,6 +648,10 @@ _SEARCH_PHASE_HISTS = {
 # search.knn_clause.exact / .ivf / .filtered (search/compile.py)
 _KNN_PAGE_FROM_CLAUSE = TELEMETRY.metrics.counter(
     "search.knn_clause.page_from_clause")
+# query items dispatched through an envelope program whose k-NN selection
+# (ops/knn.py `knn_select`) reads block maxima (`_blocked_select`)
+_KNN_BLOCKED_SELECT = TELEMETRY.metrics.counter(
+    "search.knn_clause.blocked_select")
 # query items dispatched through the aggregating envelope program
 # (`jit_agg_env`: build_batched_agg_query_phase), once an item whatever
 # its segments
@@ -1708,6 +1712,17 @@ def _page_from_clause(plan: Plan) -> bool:
     clause's own k winners: the clause is the whole query. Read off the
     plan's root alone, which the JIT key's plan signature holds."""
     return plan.kind == "knn"
+
+
+def _blocked_select(plan: Plan, d_pad: int) -> bool:
+    """Whether a `knn` or `maxsim` clause of the plan, at its root or
+    under a parent, selects its k winners of `d_pad` lanes through block
+    maxima (ops/knn.py `blocked_select_width`, the rule `knn_select`
+    itself asks)."""
+    if plan.kind in ("knn", "maxsim") and blocked_select_width(
+            d_pad, min(int(plan.static[1]), d_pad)):
+        return True
+    return any(_blocked_select(c, d_pad) for c in plan.children)
 
 
 def _winners_total(valid, idx, seg, num_docs: int, scores, min_score):
@@ -4265,6 +4280,7 @@ class SearchExecutor:
                 + [np.inf] * pad_rows, dtype=np.float32)
             from_clause = False     # a program of this group pages from
             # its k-NN clause's winners (counted once an item, below)
+            blocked = False         # ... or selects them by block maxima
             for seg_i, (seg, (arrays, meta)) in enumerate(
                     zip(segments, device)):
                 if seg.num_docs == 0:
@@ -4353,10 +4369,13 @@ class SearchExecutor:
                                 agg_sig is None
                                 and _blockmax_admitted(plan0, k_seg)))
                 from_clause |= agg_sig is None and _page_from_clause(plan0)
+                blocked |= _blocked_select(plan0, meta.d_pad)
                 if agg_sig is not None:
                     note_bin_sources(agg_by_i[idxs[0]][seg_i], len(idxs))
             if from_clause:
                 _KNN_PAGE_FROM_CLAUSE.inc(len(idxs))
+            if blocked:
+                _KNN_BLOCKED_SELECT.inc(len(idxs))
             if agg_sig is not None and not dead.issuperset(idxs):
                 _AGG_ENV_QUERIES.inc(len(idxs))
         _t_end = time.monotonic()

@@ -33,7 +33,7 @@ def knn_clause_counters(node):
         "telemetry"]["metrics"]["counters"]
     return {m: c.get(f"search.knn_clause.{m}", 0) for m in
             ("exact", "ivf", "filtered", "scanned_bytes",
-             "page_from_clause")}
+             "page_from_clause", "blocked_select")}
 
 
 def msearch(node, index, bodies):
