@@ -613,11 +613,16 @@ class DistributedSearcher:
         a full segment upload per call."""
         shard_set = self.build_shard_set([p[0] for p in shard_payloads],
                                          [p[2] for p in shard_payloads])
-        return self.search_resident(shard_set,
-                                    [p[1] for p in shard_payloads],
-                                    plan, k, min_score=min_score,
-                                    agg_plans=agg_plans,
-                                    sort_spec=sort_spec)
+        try:
+            return self.search_resident(shard_set,
+                                        [p[1] for p in shard_payloads],
+                                        plan, k, min_score=min_score,
+                                        agg_plans=agg_plans,
+                                        sort_spec=sort_spec)
+        finally:
+            # no residency cache owns a one-shot set: its device-memory
+            # gauge leaves with the call, or `spmd_shard_sets` keeps it
+            shard_set.release()
 
     def search_resident(self, shard_set: HbmShardSet,
                         flat_inputs: Sequence[List[Dict]], plan: Plan,

@@ -953,6 +953,28 @@ def _hybrid_dispatch_attrs(wave: int, trace_ids, programs: int) -> dict:
     return out
 
 
+def _note_hybrid_spans(marks: tuple, programs: int, nbytes: int, infos,
+                       fetched) -> None:
+    """A B=1 hybrid query phase's spans in the always-on ring, under the
+    span open on this thread, from its clock reads `marks` (start, first
+    upload, last jit call's return, fetch start, rows fetched):
+    `hybrid.compile`, `dispatch` and `device_wait`, the last two named
+    and paired (`wave`) as the envelope's are."""
+    trace = _SPANS.current()
+    if trace is None:
+        return
+    t_start, t_dispatch, t_dispatched, t_wait, t_got = marks
+    fetched_bytes = sum(rows.nbytes for rows in fetched)
+    wave = sum(1 for s in trace.spans
+               if s[2] == "dispatch" and s[1] == trace.top)
+    _SPANS.child("hybrid.compile", t_start, t_dispatch,
+                 (_wave_attrs, wave, None))
+    _SPANS.child("dispatch", t_dispatch, t_dispatched,
+                 (_dispatch_attrs, wave, None, programs, nbytes, infos))
+    _SPANS.child("device_wait", t_wait, t_got,
+                 (_wait_attrs, wave, None, fetched_bytes, 0))
+
+
 def _wait_attrs(wave: int, trace_ids, nbytes: int, programs: int) -> dict:
     """`device_wait`: bytes fetched, and whether a program of its own
     (concat_rows) ran inside it."""
@@ -2120,10 +2142,28 @@ def _batched_hybrid_runner(plans, meta: DeviceSegmentMeta, k: int,
         cost = [_plan_cost(p, meta, _layout_batch(layout))
                 for p in plans]
         return _timed_first_call(
-            fn, family="hybrid_env", shape=_env_shape(layout, k, meta),
-            key=key, cost=(sum(c[0] for c in cost),
-                           sum(c[1] for c in cost)))
+            fn, family="hybrid_env",
+            shape=_hybrid_shape(layout, k, meta, plans), key=key,
+            cost=(sum(c[0] for c in cost), sum(c[1] for c in cost)))
     return fn
+
+
+def _vector_width(plan: Plan) -> int:
+    """The width of the vectors a `knn` clause of the plan scans, at its
+    root or under a parent; 0 where it has none."""
+    if plan.kind == "knn":
+        return int(plan.inputs["query"].shape[-1])
+    return max((_vector_width(c) for c in plan.children), default=0)
+
+
+def _hybrid_shape(layout, k: int, meta, plans) -> str:
+    """Shape string of a `hybrid_env` executable: the padded batch, the
+    segment's padded doc axis, each sub-query's window k, how many
+    sub-queries, and the width of the vectors a `knn` sub-query scans
+    (`dim0`: none) — with the text clauses' lanes, what a run costs."""
+    dims = max((_vector_width(p) for p in plans), default=0)
+    return (f"b{_layout_batch(layout)}/d{meta.d_pad}/k{k}"
+            f"/sub{len(plans)}/dim{dims}")
 
 
 def _decode_hybrid_row(row: np.ndarray, k_seg: int, n_sub: int):
@@ -2896,7 +2936,17 @@ class SearchExecutor:
         per-sub-query candidates + score bounds for the coordinator's
         normalization merge (searchpipeline/hybrid.py). `ledger_scope`
         (telemetry/ledger.py) accumulates this shard's transfer
-        attribution for the caller's span / slow log."""
+        attribution for the caller's span / slow log.
+
+        In the always-on span ring, under the span open on this thread
+        (`rest.search`), from five clock reads: `hybrid.compile` (parse,
+        both sub-queries' plans, flatten, stack and pack, up to the
+        first upload), `dispatch` (the first upload to the last jit
+        call's return: `family` hybrid_env, `fingerprint`, `shape`,
+        `programs`, `nbytes`) and `device_wait` (the blocking
+        `device_get` of the rows); `wave` counts the shards this
+        request dispatched for before, as the SPMD route's does."""
+        t_start = time.monotonic()
         node = dsl.parse_query(body.get("query"))
         if not isinstance(node, dsl.HybridQuery):
             raise IllegalArgumentError(
@@ -2924,6 +2974,9 @@ class SearchExecutor:
         scope = ledger_scope if ledger_scope is not None \
             else _LEDGER.scope()
         launched = []
+        infos: List[Any] = []
+        sent_bytes = 0
+        t_dispatch = 0.0
         struct_parts: List[Any] = []
         shape_parts: List[Any] = []
         for seg_i, (seg, (arrays, meta)) in enumerate(
@@ -2955,8 +3008,14 @@ class SearchExecutor:
                 if faults.ENABLED:
                     faults.fire("query.dispatch")
                 return fn(arrays, jnp.asarray(buf))
+            if not launched:
+                t_dispatch = time.monotonic()
             launched.append((seg_i, k_seg, retry.call_with_retry(
                 _dispatch, label="query.dispatch")))
+            sent_bytes += buf.nbytes
+            info = getattr(fn, "exec_info", None)
+            if info is not None:
+                infos.append(info)
             if scope is not None:
                 # after the dispatch: a failed one must not count h2d
                 # bytes that never crossed
@@ -2975,19 +3034,25 @@ class SearchExecutor:
 
         result = _empty_hybrid_result(n_sub)
         if launched:
+            t_dispatched = time.monotonic()
+
             def _collect():
                 if faults.ENABLED:
                     faults.fire("fetch.gather")
                 return jax.device_get([out for _, _, out in launched])
-            t0c = time.monotonic() if scope is not None else 0.0
+            t0c = time.monotonic()
             with _LEDGER.attributed(scope):
                 fetched = retry.call_with_retry(_collect,
                                                 label="fetch.gather")
+            t_got = time.monotonic()
             if scope is not None:
                 _ledger_hybrid_rows(
                     scope, [(1, 1, k_seg, n_sub)
                             for _seg_i, k_seg, _ in launched],
-                    (time.monotonic() - t0c) * 1000)
+                    (t_got - t0c) * 1000)
+            _note_hybrid_spans(
+                (t_start, t_dispatch, t_dispatched, t0c, t_got),
+                len(launched), sent_bytes, infos, fetched)
             for (seg_i, k_seg, _), rows in zip(launched, fetched):
                 _accumulate_hybrid_row(result, np.asarray(rows)[0], seg_i,
                                        k_seg, n_sub)
@@ -3916,8 +3981,10 @@ class SearchExecutor:
                 continue
             body, n_sub = prepared[i][0], prepared[i][1]
             result.bounds = [tuple(b) for b in result.bounds]
-            responses[i] = hyb.merge_and_render(
-                [self], body, [result], hyb.DEFAULT_SPEC, start, n_sub)
+            combined, _ = hyb.merge_hybrid([result], hyb.DEFAULT_SPEC,
+                                           n_sub)
+            responses[i] = hyb.render_hybrid([self], body, [result],
+                                             combined, start)
 
     def _compile_msearch_bundle(self, compiler: Compiler, stats, tpl,
                                 node, body: dict, agg_spec,

@@ -35,6 +35,13 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from opensearch_tpu.common.errors import IllegalArgumentError
 from opensearch_tpu.search import dsl
+from opensearch_tpu.telemetry import TELEMETRY
+
+# hybrid requests rendered from the fused program's rows (B=1 `_search`
+# and each item of `_msearch`'s hybrid waves), once a request; and the
+# candidates their normalization read, every sub-query's window summed
+_HYBRID_QUERIES = TELEMETRY.metrics.counter("search.hybrid.queries")
+_HYBRID_CANDIDATES = TELEMETRY.metrics.counter("search.hybrid.candidates")
 
 # neural-search MinMaxScoreNormalizationTechnique constants
 MIN_SCORE = 0.001
@@ -155,24 +162,22 @@ def validate_hybrid_request(body: dict, n_sub: int, spec: dict,
     return size, from_, max(from_ + size, 10)
 
 
-def merge_and_render(executors: List, body: dict, shard_results: List,
-                     spec: dict, start: float, n_sub: int,
-                     total_shards: Optional[int] = None,
-                     failed_shards: int = 0,
-                     failures: Optional[List[dict]] = None) -> dict:
+def merge_hybrid(shard_results: List, spec: dict, n_sub: int
+                 ) -> Tuple[List[Tuple[float, Tuple[int, int, int]]], int]:
     """The hybrid reduce: global bounds (the collective-merge analog) →
-    normalize every candidate → weighted combine → page render. Shared
-    by execute_hybrid_search and the batched _msearch hybrid envelope."""
+    normalize every candidate → weighted combine → the combined order,
+    (score, (shard, seg, ord)) best first, and the candidates it read.
+    Counts the request and its candidates. Shared by
+    execute_hybrid_search and the batched _msearch hybrid envelope,
+    each of which then calls render_hybrid."""
     from opensearch_tpu.search import spmd
 
-    size = int(body.get("size", 10))
-    from_ = int(body.get("from", 0))
     global_bounds = spmd.merge_hybrid_bounds(
         [r.bounds for r in shard_results], n_sub)
-    total = sum(r.total for r in shard_results)
 
     # doc key = (shard, seg, ord); values = per-sub normalized scores
     docs: Dict[Tuple[int, int, int], List[Optional[float]]] = {}
+    candidates = 0
     for i in range(n_sub):
         raw: List[float] = []
         keys: List[Tuple[int, int, int]] = []
@@ -180,6 +185,7 @@ def merge_and_render(executors: List, body: dict, shard_results: List,
             for score, seg_i, ord_ in r.per_sub[i]:
                 raw.append(score)
                 keys.append((shard_i, seg_i, ord_))
+        candidates += len(raw)
         for key, ns in zip(keys, normalize_scores(
                 raw, global_bounds[i], spec["normalization"])):
             docs.setdefault(key, [None] * n_sub)[i] = ns
@@ -190,7 +196,21 @@ def merge_and_render(executors: List, body: dict, shard_results: List,
     # combined-score desc; (shard, seg, doc) asc tie-break — the same
     # final order mergeTopDocs uses for equal scores
     combined.sort(key=lambda e: (-e[0], e[1]))
+    _HYBRID_QUERIES.inc()
+    _HYBRID_CANDIDATES.inc(candidates)
+    return combined, candidates
 
+
+def render_hybrid(executors: List, body: dict, shard_results: List,
+                  combined: List, start: float,
+                  total_shards: Optional[int] = None,
+                  failed_shards: int = 0,
+                  failures: Optional[List[dict]] = None) -> dict:
+    """The page of `merge_hybrid`'s combined order: fetch, hits.total
+    and the `_shards` block."""
+    size = int(body.get("size", 10))
+    from_ = int(body.get("from", 0))
+    total = sum(r.total for r in shard_results)
     page = combined[from_:from_ + size]
     max_score = combined[0][0] if combined else None
 
@@ -295,7 +315,18 @@ def execute_hybrid_search(executors: List, body: dict,
         raise SearchPhaseExecutionError(
             "Partial shards failure", phase="query", grouped=True,
             failed_shards=failures)
-    return merge_and_render(executors, body, shard_results, spec, start,
-                            n_sub, total_shards=total_shards,
-                            failed_shards=failed_shards,
-                            failures=failures)
+    # the route's last two spans in the always-on ring, beside each
+    # shard's `hybrid.compile`, `dispatch` and `device_wait`:
+    # `hybrid.merge` (bounds merge, normalization, combination, order)
+    # and `respond` (the page render)
+    t_merge = time.monotonic()
+    combined, candidates = merge_hybrid(shard_results, spec, n_sub)
+    t_render = time.monotonic()
+    res = render_hybrid(executors, body, shard_results, combined, start,
+                        total_shards=total_shards,
+                        failed_shards=failed_shards, failures=failures)
+    ring = TELEMETRY.tracer.spans
+    ring.child("hybrid.merge", t_merge, t_render,
+               {"candidates": candidates})
+    ring.child("respond", t_render, time.monotonic())
+    return res
