@@ -13,12 +13,13 @@ HNSW/IVF via native faiss/nmslib. Here both are TPU-native:
   (`d_pad` 2,097,152, 6.44 GB resident; `vectorsearch-knn-closed-8`,
   PERF.md §5): XLA lowers the one-column product to a VPU
   multiply-reduce that reads the column once, 8.5 ms a query (758 GB/s),
-  and to no MXU op; the clause's k=100 selection (`knn_select`) takes
-  the maxima of 16,384 blocks of 128 lanes, the 100 best of them, and
-  one sort of those blocks' 12,800 lanes: 0.17 ms more, where one
-  `TopK` over the 2,097,152 lanes took 1.29. A clause that is the whole
-  query takes its page from those k winners (`knn_page`), not from a
-  second selection over `d_pad` lanes (another 1.4 ms).
+  and to no MXU op; the clause's k=100 selection (ops/select.py
+  `select_top_k`) takes the maxima of 16,384 blocks of 128 lanes, the
+  100 best of them, and one sort of those blocks' 12,800 lanes: 0.17 ms
+  more, where one `TopK` over the 2,097,152 lanes took 1.29. A clause
+  that is the whole query, or the whole of a hybrid sub-query, takes
+  its page or window from those k winners (`knn_page`), not from a
+  second selection over `d_pad` lanes (another 1.2 to 1.4 ms).
 - **IVF**: k-means centroids (built at seal time, Lloyd's on device),
   inverted lists as a padded [nlist, max_len] int32 matrix. A query scores
   centroids, takes the top-nprobe lists, gathers their candidates, and
@@ -39,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from opensearch_tpu.ops import F32_MATMUL
+from opensearch_tpu.ops.select import select_top_k
 from opensearch_tpu.telemetry.kernels import stage
 
 SPACES = ("l2", "cosinesimil", "innerproduct")
@@ -80,80 +82,22 @@ def exact_knn_scores(vectors: jnp.ndarray, query: jnp.ndarray,
         return space_score(raw_similarity(vectors, query, space), space)
 
 
-SELECT_BLOCK = 128          # lanes a block of the blocked selection
-SELECT_MIN_LANES = 1 << 15  # below it XLA's TopK is one cheap pass
-
-
-def blocked_select_width(d_pad: int, k: int) -> int:
-    """The block width `knn_select` selects through for a `[d_pad]` score
-    vector and k winners, 0 where it runs one plain `top_k`. Plain,
-    XLA's `TopK` costs ~1 ns a lane on a v5e from 2^15 lanes up and
-    ~10 us below; blocked, it takes one max-reduce, a `top_k` over the
-    `d_pad / w` block maxima and a sort of the `k * w` lanes of the k
-    winning blocks, whose cost does not grow with `d_pad` (PERF.md §6).
-    Blocks are one vreg row (128 f32 lanes) wide, and the route wants
-    the candidates to be at most a quarter of the lanes."""
-    w = SELECT_BLOCK
-    if d_pad < SELECT_MIN_LANES or 4 * k * w > d_pad:
-        return 0
-    return w
-
-
-def _blocked_top_k(masked: jnp.ndarray, k: int, w: int):
-    """`jax.lax.top_k(masked, k)`, the same values and the same indices,
-    from the lanes of the k contiguous `w`-lane blocks with the largest
-    maxima (ties to the lower block). Exact, ties included: with T the
-    k-th of those maxima, every lane above T is in a chosen block. Each
-    chosen block whose maximum is above T holds a lane above T, so no
-    more lanes at T win than there are chosen blocks whose maximum is T;
-    each of those holds a lane at T and lies below every passed-over
-    block whose maximum is T, so the lowest lanes at T are all chosen.
-    The candidates are sorted by score descending, then doc ascending:
-    `top_k`'s lowest-index rule. The docs are distinct, so the sort need
-    not be stable (a stable one takes half again as long); and it is a sort,
-    not a second `top_k`, because the sorts XLA rewrites a `top_k` into
-    carry no stage of their own."""
-    blocks = masked.reshape(masked.shape[0] // w, w)
-    _, blk = jax.lax.top_k(jnp.max(blocks, axis=1), k)
-    docs = blk[:, None] * w + jnp.arange(w, dtype=blk.dtype)
-    neg, docs = jax.lax.sort((-blocks[blk].reshape(k * w),
-                              docs.reshape(k * w)), num_keys=2,
-                             is_stable=False)
-    return -neg[:k], docs[:k]
-
-
-def knn_select(scores: jnp.ndarray, eligible: jnp.ndarray, k: int):
-    """The clause's selection: the k best eligible docs of a dense score
-    vector, as (values, doc ordinals, valid), score-desc with ties by
-    lowest doc (`top_k`'s lowest-index rule). Fewer than k eligible docs
-    leave the tail slots `-inf` and not `valid`. The one selection over
-    `[d_pad]` lanes a k-NN clause runs, whoever reads its winners:
-    blocked where `blocked_select_width` says so, else one `top_k`."""
-    with stage("top_k"):
-        masked = jnp.where(eligible, scores, -jnp.inf)
-        d = int(scores.shape[0])
-        k = min(int(k), d)
-        w = blocked_select_width(d, k)
-        top_vals, top_idx = _blocked_top_k(masked, k, w) if w \
-            else jax.lax.top_k(masked, k)
-        return top_vals, top_idx, top_vals > -jnp.inf
-
-
 def knn_match_topk(scores: jnp.ndarray, eligible: jnp.ndarray,
                    k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Restrict a dense score vector to its top-k eligible docs.
 
     Returns (scores, matches): matches true only for the k best eligible
     docs (score-desc, doc-asc tie-break via top_k's lowest-index rule).
-    `knn_select` densified again, for every caller that reads `[d_pad]`
-    vectors: a clause under a `bool`/`hybrid`/`function_score`/`nested`
-    parent, the aggregating and sorting query phases, the hybrid and
-    SPMD programs. A plan whose ROOT is the clause, served score-sorted
-    and aggregation-free (search/executor.py build_batched_query_phase),
-    takes its page from the k winners instead (`knn_page`) and never
+    `select_top_k` densified again, for every caller that reads `[d_pad]`
+    vectors: a clause under a `bool`/`function_score`/`nested` parent,
+    the aggregating and sorting query phases, the SPMD program. A plan
+    whose ROOT is the clause, served score-sorted and aggregation-free
+    (search/executor.py build_batched_query_phase), or a hybrid
+    sub-query that is the clause (build_hybrid_query_phase), takes its
+    page or window from the k winners instead (`knn_page`) and never
     builds this pair."""
     d = scores.shape[0]
-    top_vals, top_idx, valid = knn_select(scores, eligible, k)
+    top_vals, top_idx, valid = select_top_k(scores, eligible, k)
     with stage("top_k"):
         # invalid slots scatter out of bounds and are dropped — routing
         # them to index 0 would clobber a real winner at doc ord 0

@@ -48,7 +48,8 @@ from opensearch_tpu.ops.bm25 import (
 from opensearch_tpu.ops import device_segment as _devseg
 from opensearch_tpu.ops.device_segment import (
     DeviceSegmentMeta, refresh_live, tree_nbytes, upload_segment)
-from opensearch_tpu.ops.knn import blocked_select_width, knn_page
+from opensearch_tpu.ops.knn import knn_page
+from opensearch_tpu.ops.select import blocked_select_width, select_top_k
 from opensearch_tpu.ops.topk import (NEG_INF, f32_sortable, single_valued,
                                      value_merge_key)
 from opensearch_tpu.search import dsl
@@ -649,9 +650,14 @@ _SEARCH_PHASE_HISTS = {
 _KNN_PAGE_FROM_CLAUSE = TELEMETRY.metrics.counter(
     "search.knn_clause.page_from_clause")
 # query items dispatched through an envelope program whose k-NN selection
-# (ops/knn.py `knn_select`) reads block maxima (`_blocked_select`)
+# (ops/select.py `select_top_k`) reads block maxima (`_blocked_select`)
 _KNN_BLOCKED_SELECT = TELEMETRY.metrics.counter(
     "search.knn_clause.blocked_select")
+# hybrid sub-queries dispatched, by how the fused program selects each
+# one's window (`_hybrid_window_route`): once a sub-query an item a shard
+_HYBRID_WINDOWS = {
+    route: TELEMETRY.metrics.counter(f"search.hybrid.windows.{route}")
+    for route in ("from_clause", "blocked", "plain")}
 # query items dispatched through the aggregating envelope program
 # (`jit_agg_env`: build_batched_agg_query_phase), once an item whatever
 # its segments
@@ -1730,17 +1736,18 @@ def _blockmax_admitted(plan, k: int) -> bool:
 
 
 def _page_from_clause(plan: Plan) -> bool:
-    """Whether build_batched_query_phase takes the page from a k-NN
-    clause's own k winners: the clause is the whole query. Read off the
-    plan's root alone, which the JIT key's plan signature holds."""
+    """Whether build_batched_query_phase takes the page, or
+    build_hybrid_query_phase a sub-query's window, from a k-NN clause's
+    own k winners: the clause is the whole query or sub-query. Read off
+    the plan's root alone, which the JIT key's plan signature holds."""
     return plan.kind == "knn"
 
 
 def _blocked_select(plan: Plan, d_pad: int) -> bool:
     """Whether a `knn` or `maxsim` clause of the plan, at its root or
     under a parent, selects its k winners of `d_pad` lanes through block
-    maxima (ops/knn.py `blocked_select_width`, the rule `knn_select`
-    itself asks)."""
+    maxima (ops/select.py `blocked_select_width`, the rule
+    `select_top_k` itself asks)."""
     if plan.kind in ("knn", "maxsim") and blocked_select_width(
             d_pad, min(int(plan.static[1]), d_pad)):
         return True
@@ -1777,7 +1784,8 @@ def build_batched_query_phase(plan: Plan, meta: DeviceSegmentMeta, k: int,
     def one(seg, flat_inputs, min_score):
         k_eff = min(k, seg["live"].shape[0])
         if _page_from_clause(plan):
-            scores, idx, valid = eval_knn_winners(plan, seg, flat_inputs)
+            scores, idx, valid = eval_knn_winners(plan, seg, flat_inputs,
+                                                  [0])
             returnable, total = _winners_total(
                 valid, idx, seg, meta.num_docs, scores, min_score)
             top_scores, top_idx = knn_page(scores, idx, returnable, k_eff)
@@ -2061,6 +2069,51 @@ def _runner(plan_sig, plan: Plan, meta: DeviceSegmentMeta, k: int, sort_mode: st
         cost=_plan_cost(plan, meta))
 
 
+def _hybrid_window_route(plan: Plan, d_pad: int, k: int) -> str:
+    """How build_hybrid_query_phase selects a sub-query's window of k of
+    `d_pad` lanes, decided at trace time: `from_clause` where the
+    sub-plan's root is a `knn` clause (`_page_from_clause`: the window
+    is the clause's own k winners, `knn_page`); else over the sub-plan's
+    dense pair by `select_top_k`, `blocked` where it reads block maxima
+    (`blocked_select_width`) and `plain` where it runs one `top_k`."""
+    if _page_from_clause(plan):
+        return "from_clause"
+    return "blocked" if blocked_select_width(d_pad, min(k, d_pad)) \
+        else "plain"
+
+
+def _note_hybrid_windows(dispatched, n_items: int) -> None:
+    """Count each sub-query of `n_items` hybrid items once under its
+    window's route (`_hybrid_window_route`) in the largest segment they
+    were dispatched to; `dispatched` holds (d_pad, plans, k) a
+    segment."""
+    if not dispatched:
+        return
+    d_pad, plans, k = max(dispatched, key=lambda seg: seg[0])
+    for plan in plans:
+        _HYBRID_WINDOWS[_hybrid_window_route(plan, d_pad, k)].inc(n_items)
+
+
+def _hybrid_union_total(union, winners):
+    """How many docs any sub-query may return, each once: the dense
+    sub-queries' eligible docs (`union`, `[d_pad]`, None where every
+    sub-query took its window from a clause), then each from-clause
+    sub-query's returnable winners that lie in none of them and in no
+    earlier winner list, compared pairwise, k × k. `winners` holds
+    (doc ordinals, returnable) a from-clause sub-query."""
+    with _stage("eligible_total"):
+        total = jnp.int32(0) if union is None \
+            else jnp.sum(union.astype(jnp.int32))
+        for j, (idx, returnable) in enumerate(winners):
+            new = returnable if union is None \
+                else returnable & ~union[idx]
+            for idx_i, returnable_i in winners[:j]:
+                new = new & ~jnp.any((idx[:, None] == idx_i[None, :])
+                                     & returnable_i[None, :], axis=1)
+            total = total + jnp.sum(new.astype(jnp.int32))
+        return total
+
+
 def build_hybrid_query_phase(plans, meta: DeviceSegmentMeta, k: int):
     """The FUSED hybrid query phase for one segment: every sub-query of a
     `hybrid` clause evaluates inside ONE jitted program (one plan-signature
@@ -2075,7 +2128,15 @@ def build_hybrid_query_phase(plans, meta: DeviceSegmentMeta, k: int):
     merge can reconstruct GLOBAL min/max (min-of-mins / max-of-maxs) and
     the global L2 norm (sum of per-shard sums) without a second pass over
     candidate lists, mirroring the reference's per-shard TopDocs bounds
-    (neural-search NormalizationProcessorWorkflow over CompoundTopDocs)."""
+    (neural-search NormalizationProcessorWorkflow over CompoundTopDocs).
+
+    The window is `top_k`'s over the sub-query's eligible docs, by the
+    route `_hybrid_window_route` picks: a sub-query that is a `knn`
+    clause takes it from the clause's own k winners (`eval_knn_winners`,
+    `knn_page`), with no `[d_pad]` pair and no second selection; every
+    other one selects it from its dense pair by `select_top_k`. The ords
+    of a window's padding slots (past its count, never read) are the
+    route's own."""
 
     n_sub = len(plans)
 
@@ -2084,16 +2145,28 @@ def build_hybrid_query_phase(plans, meta: DeviceSegmentMeta, k: int):
         d_pad = seg["live"].shape[0]
         in_seg = jnp.arange(d_pad, dtype=jnp.int32) < meta.num_docs
         base = seg["live"] & seg["root"] & in_seg
-        union = jnp.zeros(d_pad, jnp.bool_)
+        union = None
+        winners = []
         pieces = []
         k_eff = min(k, d_pad)
         for i in range(n_sub):
-            scores, matches = _eval_plan(plans[i], seg, flat_inputs, cursor)
-            eligible = matches & base & (scores >= min_score)
-            union = union | eligible
-            with _stage("top_k"):
-                masked = jnp.where(eligible, scores, NEG_INF)
-                top_scores, top_idx = jax.lax.top_k(masked, k_eff)
+            if _hybrid_window_route(plans[i], d_pad, k_eff) \
+                    == "from_clause":
+                scores, idx, valid = eval_knn_winners(plans[i], seg,
+                                                      flat_inputs, cursor)
+                returnable, _ = _winners_total(valid, idx, seg,
+                                               meta.num_docs, scores,
+                                               min_score)
+                winners.append((idx, returnable))
+                top_scores, top_idx = knn_page(scores, idx, returnable,
+                                               k_eff)
+            else:
+                scores, matches = _eval_plan(plans[i], seg, flat_inputs,
+                                             cursor)
+                eligible = matches & base & (scores >= min_score)
+                union = eligible if union is None else union | eligible
+                top_scores, top_idx, _ = select_top_k(scores, eligible,
+                                                      k_eff)
             valid = top_scores > NEG_INF
             cnt = jnp.sum(valid.astype(jnp.int32))
             mn = jnp.min(jnp.where(valid, top_scores, jnp.inf))
@@ -2106,8 +2179,7 @@ def build_hybrid_query_phase(plans, meta: DeviceSegmentMeta, k: int):
                 top_idx.astype(jnp.int32), cnt[None],
                 jax.lax.bitcast_convert_type(
                     jnp.stack([mn, mx, ssq]), jnp.int32)]))
-        total = jnp.sum(union.astype(jnp.int32))
-        pieces.append(total[None])
+        pieces.append(_hybrid_union_total(union, winners)[None])
         return jnp.concatenate(pieces)
 
     return run
@@ -2974,6 +3046,7 @@ class SearchExecutor:
         scope = ledger_scope if ledger_scope is not None \
             else _LEDGER.scope()
         launched = []
+        dispatched = []
         infos: List[Any] = []
         sent_bytes = 0
         t_dispatch = 0.0
@@ -3012,6 +3085,7 @@ class SearchExecutor:
                 t_dispatch = time.monotonic()
             launched.append((seg_i, k_seg, retry.call_with_retry(
                 _dispatch, label="query.dispatch")))
+            dispatched.append((meta.d_pad, plans, k_seg))
             sent_bytes += buf.nbytes
             info = getattr(fn, "exec_info", None)
             if info is not None:
@@ -3021,6 +3095,7 @@ class SearchExecutor:
                 # bytes that never crossed
                 _LEDGER.record("upload.literals", "h2d", buf.nbytes,
                                scope=scope)
+        _note_hybrid_windows(dispatched, 1)
         if extra_filter is None:
             # register the fused executable's (plan-struct, shape-bucket)
             # signature so index-open / node-start warmup AOT-compiles the
@@ -3883,6 +3958,7 @@ class SearchExecutor:
             min_scores = np.asarray(
                 [prepared[i][2] for i in idxs] + [np.inf] * pad_rows,
                 dtype=np.float32)
+            dispatched = []
             for seg_i, (seg, (arrays, meta)) in enumerate(
                     zip(segments, device)):
                 if seg.num_docs == 0:
@@ -3922,6 +3998,9 @@ class SearchExecutor:
                 staging.append(buf)
                 wave_buffer_bytes += buf.nbytes
                 pending.append((idxs, seg_i, k_seg, len(plans0), out))
+                dispatched.append((meta.d_pad, plans0, k_seg))
+            if not dead.issuperset(idxs):
+                _note_hybrid_windows(dispatched, len(idxs))
         return {"prepared": prepared, "pending": pending, "dead": dead,
                 "raise_item_errors": raise_item_errors,
                 "staging": staging,

@@ -16,7 +16,8 @@ from opensearch_tpu.ops.topk import NEG_INF
 from opensearch_tpu.ops.bm25 import (
     ordinal_terms_match, range_match_on_ranks, score_text_clause)
 from opensearch_tpu.ops.knn import (
-    exact_knn_scores, ivf_knn_scores, knn_match_topk, knn_select)
+    exact_knn_scores, ivf_knn_scores, knn_match_topk)
+from opensearch_tpu.ops.select import select_top_k
 from opensearch_tpu.search.compile import Plan
 
 def _identity(score_mode: str) -> float:
@@ -93,15 +94,18 @@ def _knn_scores(plan: Plan, seg: Dict, inputs: List[Dict],
     return exact_knn_scores(col["vectors"], my["query"], space), eligible
 
 
-def eval_knn_winners(plan: Plan, seg: Dict, inputs: List[Dict]):
+def eval_knn_winners(plan: Plan, seg: Dict, inputs: List[Dict],
+                     cursor: List[int]):
     """A plan whose root is a `knn` clause, as the clause's k winners
     alone: (boosted scores, doc ordinals, valid), in the clause's order
     (raw score descending, ties by lowest doc). No `[d_pad]` pair is
     built: the caller takes its page from these k (ops/knn.py
-    `knn_page`)."""
-    my = inputs[0]          # the root's own inputs; its filter's follow
-    scores, eligible = _knn_scores(plan, seg, inputs, [1], my)
-    top_vals, top_idx, valid = knn_select(scores, eligible, plan.static[1])
+    `knn_page`). `cursor` walks `inputs` as `_eval_plan`'s does."""
+    my = inputs[cursor[0]]  # the root's own inputs; its filter's follow
+    cursor[0] += 1
+    scores, eligible = _knn_scores(plan, seg, inputs, cursor, my)
+    top_vals, top_idx, valid = select_top_k(scores, eligible,
+                                            plan.static[1])
     return top_vals * my["boost"], top_idx, valid
 
 

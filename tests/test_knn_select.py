@@ -1,5 +1,5 @@
-"""The k-NN clause's selection from block maxima (ops/knn.py
-`knn_select`, `_blocked_top_k`, `blocked_select_width`).
+"""The k-NN clause's selection from block maxima (ops/select.py
+`select_top_k`, `_blocked_top_k`, `blocked_select_width`).
 
 Where the shape qualifies, the clause's k winners of `d_pad` lanes come
 from the maxima of 128-lane blocks and the lanes of the k winning
@@ -14,8 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from opensearch_tpu.ops.knn import (SELECT_BLOCK, _blocked_top_k,
-                                    blocked_select_width, knn_select)
+from opensearch_tpu.ops.select import (SELECT_BLOCK, _blocked_top_k,
+                                       blocked_select_width, select_top_k)
 
 W = SELECT_BLOCK
 
@@ -31,11 +31,11 @@ def plain_select(scores, eligible, k):
 
 
 def assert_same_selection(scores, eligible, k):
-    """`knn_select` (blocked at this shape) equals one plain `top_k`:
+    """`select_top_k` (blocked at this shape) equals one plain `top_k`:
     values, ordinals and `valid`, slot by slot."""
     d = scores.shape[-1]
     assert blocked_select_width(d, k) == W
-    vals, idx, valid = jax.jit(knn_select, static_argnums=2)(
+    vals, idx, valid = jax.jit(select_top_k, static_argnums=2)(
         jnp.asarray(scores), jnp.asarray(eligible), k)
     want_vals, want_idx = plain_select(jnp.asarray(scores),
                                        jnp.asarray(eligible), k)
@@ -150,7 +150,7 @@ def test_a_batch_of_queries_under_vmap():
     scores = rng.integers(0, 40, (b, d)).astype(np.float32)
     eligible = rng.random((b, d)) < 0.9
     vals, idx, _ = jax.jit(jax.vmap(
-        lambda s, e: knn_select(s, e, k)))(scores, eligible)
+        lambda s, e: select_top_k(s, e, k)))(scores, eligible)
     for r in range(b):
         want_vals, want_idx = plain_select(jnp.asarray(scores[r]),
                                            jnp.asarray(eligible[r]), k)
